@@ -23,7 +23,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, "src")
 
-from repro.compat import ensure_virtual_devices
+from repro.launch.mesh import ensure_virtual_devices
 
 ensure_virtual_devices(8)
 
@@ -381,7 +381,7 @@ def serve_exec() -> list[str]:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.fabric import MeasuredFabric
     from repro.launch.specs import param_specs
@@ -525,7 +525,7 @@ def wire_layout() -> list[str]:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core import (
         AllReduceModel,
         SyncConfig,
@@ -571,7 +571,7 @@ def wire_layout() -> list[str]:
                 return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
 
             f = jax.jit(
-                shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                           axis_names={"data"}, check_vma=False)
             )
             # lowered (stablehlo) text: the wire dtype is truthful there
@@ -638,7 +638,7 @@ def overlap() -> list[str]:
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.comm_model import AllReduceModel
     from repro.core.profiler import TraceRecorder, overlap_report
@@ -685,7 +685,7 @@ def overlap() -> list[str]:
             p0 = jax.tree.map(jnp.array, params)
             return step(p0, opt.init(p0), batch)
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             hlo = step.lower(params, opt.init(params), batch).compile().as_text()
             n_ar = len(_re.findall(r" all-reduce\(", hlo))
             # steady-state trace: drop the compile step's spans

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.configs import get_reduced
 from repro.core import tpu_psum_model
 from repro.core.trainer import MGWFBPEngine
@@ -79,7 +78,7 @@ def main():
 
     def do_step(state, step):
         batch = jax.tree.map(jnp.asarray, data.batch_at(step))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, o, m = step16(state.params, state.opt_state, batch)
         return RunState(step=state.step, params=p, opt_state=o, restarts=state.restarts)
 
@@ -114,7 +113,7 @@ def main():
     tree, _ = restore(CKPT, ck, {"params": fresh.params, "opt_state": fresh.opt_state})
     step64 = eng64.make_train_step(opt, mesh, lr=1e-3)
     params, opt_state = tree["params"], tree["opt_state"]
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for s in range(ck, ck + 5):
             batch = jax.tree.map(jnp.asarray, data.batch_at(s))
             params, opt_state, m = step64(params, opt_state, batch)

@@ -26,7 +26,7 @@ sys.path.insert(0, "src")
 
 # the sharded half of the demo wants a few virtual CPU devices; the flag
 # must land before jax initializes its backend
-from repro.compat import ensure_virtual_devices
+from repro.launch.mesh import ensure_virtual_devices
 
 ensure_virtual_devices(4)
 
@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.launch.specs import param_specs
 from repro.models.transformer import init_params

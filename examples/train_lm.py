@@ -22,7 +22,6 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs import get_reduced
 from repro.core import tpu_psum_model
 from repro.core.trainer import MGWFBPEngine
@@ -114,7 +113,7 @@ def main():
 
     t0 = time.time()
     first_loss = None
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             batch = jax.tree.map(jnp.asarray, data.batch_at(step))
             params, opt_state, metrics = step_fn(params, opt_state, batch)

@@ -48,6 +48,28 @@ class Hardware:
 #: TPU v5e, bf16 — constants from the project brief.
 TPU_V5E = Hardware(name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9)
 
+#: Presets by the ``device_kind`` JAX reports for the chip they describe.
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> Hardware:
+    """The preset that prices plans for ``device``.
+
+    A CPU device is a rehearsal of the TPU program and is priced as the
+    v5e.  An accelerator must be one a preset describes: pricing it with
+    another chip's constants would plan for the wrong machine.
+    """
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware preset describes {device.platform} device "
+            f"{device.device_kind!r} (known: {sorted(HARDWARE_BY_DEVICE_KIND)}); "
+            "add one to core/cost_model.py before planning for it"
+        ) from None
+
 #: Nvidia K80 (one GK210 die), fp32 — the paper's GPU.  ~4.37 TFLOP/s fp32
 #: boost, 240 GB/s.  mxu_eff=0.33 is a typical K80-era cuDNN CNN efficiency.
 NVIDIA_K80 = Hardware(

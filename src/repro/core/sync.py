@@ -33,9 +33,10 @@ Three wire layouts:
                   is paid for with a full extra round-trip of gradient
                   memory traffic (concatenate in, split out).
   ``variadic``  — one ``psum`` over the tuple of leaves (zero-copy);
-                  newer XLA lowers this to a single variadic all-reduce
-                  (``compat.variadic_psum_is_single_op``), older versions
-                  emit one op per leaf and rely on the combiner.
+                  whether XLA lowers this to a single variadic
+                  all-reduce is probed once
+                  (``variadic_psum_is_single_op``); otherwise it emits
+                  one op per leaf and relies on the combiner.
   ``arena``     — the merged buffer without the merge tax: each group's
                   leaves are packed into a preallocated flat arena by the
                   ``kernels/comm_pack`` pack kernel (wire-dtype cast and
@@ -59,13 +60,13 @@ sync is stateful: ``sync(grads, residual) -> (grads, residual)``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import axis_size, variadic_psum_is_single_op
 # submodule imports (not the fabric package) — core and fabric import each
 # other's leaves, and the package __init__s would cycle
 from ..fabric.model import Collective
@@ -130,7 +131,7 @@ def device_index(dp_axes: tuple[str, ...]):
     per-device span attribution key.  Must run inside shard_map."""
     idx = 0
     for ax in dp_axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -197,7 +198,7 @@ def make_gradient_sync(
         lo, hi = group_spans[gi]
         world = 1.0
         for ax in dp_axes:
-            world *= axis_size(ax)
+            world *= jax.lax.axis_size(ax)
         name = f"wfbp_group{gi}_l{lo}_{hi}"
         with jax.named_scope(name):
             if config.fuse == "arena":
@@ -325,6 +326,28 @@ def _encode(g: jax.Array, config: SyncConfig) -> jax.Array:
     return g.astype(config.wire_dtype)
 
 
+@functools.cache
+def variadic_psum_is_single_op() -> bool:
+    """Whether ``psum`` over a tuple lowers to ONE variadic all-reduce op.
+
+    Answered by lowering ``psum((a, b), axis)`` on a one-device mesh once
+    and counting the all-reduce ops; cached, so the cost is one tiny
+    lowering per process.
+    """
+    mesh = jax.make_mesh((1,), ("_probe",), axis_types=(jax.sharding.AxisType.Auto,))
+    P = jax.sharding.PartitionSpec
+
+    def body(x, y):
+        return jax.lax.psum((x, y), "_probe")
+
+    f = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+        axis_names={"_probe"}, check_vma=False,
+    )
+    text = jax.jit(f).lower(jnp.zeros((8,)), jnp.zeros((4,))).as_text()
+    return text.count("all_reduce") + text.count("all-reduce") <= 1
+
+
 def count_expected_allreduces(
     schedule: Schedule,
     config: SyncConfig = SyncConfig(),
@@ -333,9 +356,9 @@ def count_expected_allreduces(
     """Gradient all-reduce ops the sync lowers to.
 
     'concat' and 'arena' reduce one flat buffer per group — exactly one
-    op per group on every jax version.  'variadic' issues one psum per
-    group: modern XLA lowers that to a single variadic op per group too,
-    while 0.4.x emits one op per operand — the honest expectation there
+    op per group.  'variadic' issues one psum per group, which lowers to
+    one variadic op per group where ``variadic_psum_is_single_op`` holds
+    and to one op per operand otherwise — the honest expectation there
     needs the layout (wire-leaf count per group).
     """
     if (

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import shard_map
 from ..models import loss_fn, staged_loss_fns
 from ..models.common import ArchConfig
 from ..optim.optimizers import Optimizer
@@ -402,7 +401,7 @@ class MGWFBPEngine:
                 l = jax.lax.pmean(l, self.dp_axes)
                 return new_params, new_opt, new_residual, {"loss": l}
 
-            smapped = shard_map(
+            smapped = jax.shard_map(
                 body_ef,
                 mesh=mesh,
                 in_specs=(P(), P(), res_spec, batch_spec),
@@ -419,7 +418,7 @@ class MGWFBPEngine:
             l = jax.lax.pmean(l, self.dp_axes)
             return new_params, new_opt, {"loss": l}
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), batch_spec),
@@ -534,7 +533,7 @@ class MGWFBPEngine:
                 l = jax.lax.pmean(l, self.dp_axes)
                 return new_params, new_opt, new_residual, {"loss": l}
 
-            smapped = shard_map(
+            smapped = jax.shard_map(
                 body_ef,
                 mesh=mesh,
                 in_specs=(P(), P(), res_spec, batch_spec),
@@ -550,7 +549,7 @@ class MGWFBPEngine:
             l = jax.lax.pmean(l, self.dp_axes)
             return new_params, new_opt, {"loss": l}
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), batch_spec),

@@ -2,7 +2,7 @@
 
 One ``pallas_call`` per schedule group, both directions.  The gradient
 parts and the arena live in ``ANY`` (compiler-placed, HBM at these
-sizes); the kernel streams each part through a small VMEM staging buffer
+sizes); the kernel streams each part through small VMEM staging buffers
 with explicit async copies:
 
     pack    part[c:c+m] ──DMA──► VMEM ──cast(+EF)──► VMEM ──DMA──► arena[off+c:]
@@ -12,147 +12,184 @@ so the bf16 (or any wire-dtype) cast — and optionally the
 error-feedback residual add/update of ``runtime/compression.py`` — costs
 zero extra HBM round-trips: exactly one read of the gradients and one
 write of the arena, where XLA's concatenate layout pays a full extra
-copy each way.  Slot offsets are exact-packed (element granularity; the
-wire buffer is byte-identical in size to the concat layout) — TPU DMAs
-take arbitrary element offsets, trading a little engine efficiency on
-odd tails for never shipping padding over the wire.
+copy each way.
 
-The chunk loop is unrolled at trace time (sizes are static) and the
-staging copies are double-buffered (the DMA-pipeline pattern from
-flash_attention): every VMEM staging buffer has two slots and a
-two-entry DMA semaphore array, the first inbound copy is warmed up
-before the loop, and at chunk ``k`` the kernel starts the inbound copy
-for chunk ``k+1`` into slot ``(k+1) % 2`` before waiting on chunk
-``k``'s — so the next HBM read is in flight while the current chunk is
-cast (and the previous chunk's arena write drains).  Slot reuse is
+Slot offsets are exact-packed (element granularity; the wire buffer is
+byte-identical in size to the concat layout).  A TPU DMA, however, only
+moves whole HBM tiles of a 1-D array (``ALIGN`` elements, offset and
+length alike), so the kernel takes each part's *aligned body*: the part
+must start on a tile boundary of the arena, and the kernel moves its
+leading ``(n // ALIGN) * ALIGN`` elements.  What is left — a part's
+ragged tail, or a whole part that starts mid-tile — goes through the
+``ref.py`` encode/decode and is written into the kernel's output in
+place (``dynamic_update_slice``).  Real models are tile-aligned
+throughout (every tinyllama-1.1b part is a multiple of d_model = 2048),
+so there the remainder is empty.
+
+Each part's chunk loop is a ``fori_loop`` and the staging is
+double-buffered: every staging buffer exists once per slot (two separate
+VMEM scratch buffers, so no DMA slices a single row out of a tiled
+dimension) with a two-entry DMA semaphore array.  The first inbound copy
+is warmed up before the loop; at chunk ``k`` the kernel starts the
+inbound copy for chunk ``k+1`` into slot ``(k+1) % 2`` before waiting on
+chunk ``k``'s, so the next HBM read is in flight while the current chunk
+is cast (and the previous chunk's arena write drains).  Slot reuse is
 fenced by waiting chunk ``k-1``'s *outbound* copy before starting chunk
-``k+1``'s inbound one, which shares its slot.
+``k+1``'s inbound one, which shares its slot.  Every chunk is ``chunk``
+elements long; the last one is pulled back to end exactly at the body
+(it re-does part of its predecessor, writing the same values), so a
+part needs one staging shape and no masking.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ref import decode_part, encode_part
+
 #: Staging-buffer length in elements (f32: 256 KiB — comfortably inside
 #: VMEM next to its wire-dtype twin).
 DEFAULT_CHUNK = 1 << 16
 
-_ANY = pl.BlockSpec(memory_space=pltpu.ANY)
+#: Elements in one HBM tile of a 1-D array (8 sublanes x 128 lanes): the
+#: granularity of every DMA offset and length the kernel issues.
+ALIGN = 1024
+
+_ANY = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+
+
+def aligned_body(offset: int, size: int) -> int:
+    """Elements of a part at ``offset`` in the arena that the kernel moves."""
+    return (size // ALIGN) * ALIGN if offset % ALIGN == 0 else 0
+
+
+def _check_chunk(chunk: int) -> None:
+    if chunk <= 0 or chunk % ALIGN:
+        raise ValueError(f"chunk must be a positive multiple of {ALIGN}, got {chunk}")
+
+
+def _pipeline(
+    body: int,
+    ck: int,
+    in_copies: Callable[[Any, int], list],
+    out_copies: Callable[[Any, int], list],
+    compute: Callable[[int], None],
+) -> None:
+    """Double-buffered chunk loop over ``body`` elements in ``ck`` chunks.
+
+    ``in_copies(c0, s)`` / ``out_copies(c0, s)`` build the DMA
+    descriptors of the chunk starting at element ``c0`` staged in slot
+    ``s``; ``compute(s)`` transforms slot ``s`` in VMEM.
+    """
+    n = -(-body // ck)
+
+    def start(k):  # the last chunk ends exactly at the body
+        if isinstance(k, int):
+            return min(k * ck, body - ck)
+        return pl.multiple_of(jnp.minimum(k * ck, body - ck), ALIGN)
+
+    for cp in in_copies(0, 0):  # warm-up: the first chunk's inbound copies
+        cp.start()
+
+    def step(k, carry):
+        for s in (0, 1):
+
+            @pl.when(k % 2 == s)
+            def _():
+                @pl.when(k >= 1)
+                def _():
+                    # drain chunk k-1's outbound copies: they share slot
+                    # 1 - s with chunk k+1's inbound ones
+                    for cp in out_copies(start(k - 1), 1 - s):
+                        cp.wait()
+
+                @pl.when(k + 1 < n)
+                def _():
+                    for cp in in_copies(start(k + 1), 1 - s):
+                        cp.start()
+
+                for cp in in_copies(start(k), s):
+                    cp.wait()
+                compute(s)
+                for cp in out_copies(start(k), s):
+                    cp.start()
+
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
+    for cp in out_copies(start(n - 1), (n - 1) % 2):
+        cp.wait()
 
 
 def _pack_kernel(
     *refs,
-    sizes: tuple[int, ...],
+    bodies: tuple[int, ...],
     offsets: tuple[int, ...],
     chunk: int,
     comm_dtype: Any,
     ef: bool,
 ):
-    n = len(sizes)
+    n = len(bodies)
     parts = refs[:n]
     resid = refs[n : 2 * n] if ef else ()
     outs = refs[2 * n :] if ef else refs[n:]
     arena, new_res = outs[0], outs[1:]
 
-    for i in range(n):
-        ck = min(chunk, sizes[i])
-        c0s = tuple(range(0, sizes[i], ck))
+    for i, body in enumerate(bodies):
+        ck = min(chunk, body)
 
-        def part(
-            src,
-            wire,
-            in_sem,
-            out_sem,
-            res=None,
-            res_in_sem=None,
-            res_out_sem=None,
-            i=i,
-            ck=ck,
-            c0s=c0s,
-        ):
-            def in_dmas(k):
-                c0 = c0s[k]
-                m = min(ck, sizes[i] - c0)
-                s = k % 2
-                cps = [
-                    pltpu.make_async_copy(
-                        parts[i].at[pl.ds(c0, m)], src.at[s, pl.ds(0, m)], in_sem.at[s]
-                    )
-                ]
+        def part(src0, src1, wire0, wire1, in_sem, out_sem, res0=None, res1=None,
+                 res_in_sem=None, res_out_sem=None, i=i, body=body, ck=ck):
+            src, wire, res = (src0, src1), (wire0, wire1), (res0, res1)
+
+            def in_copies(c0, s):
+                cps = [pltpu.make_async_copy(
+                    parts[i].at[pl.ds(c0, ck)], src[s], in_sem.at[s])]
                 if ef:
-                    cps.append(
-                        pltpu.make_async_copy(
-                            resid[i].at[pl.ds(c0, m)],
-                            res.at[s, pl.ds(0, m)],
-                            res_in_sem.at[s],
-                        )
-                    )
+                    cps.append(pltpu.make_async_copy(
+                        resid[i].at[pl.ds(c0, ck)], res[s], res_in_sem.at[s]))
                 return cps
 
-            def out_dmas(k):
-                c0 = c0s[k]
-                m = min(ck, sizes[i] - c0)
-                s = k % 2
-                cps = [
-                    pltpu.make_async_copy(
-                        wire.at[s, pl.ds(0, m)],
-                        arena.at[pl.ds(offsets[i] + c0, m)],
-                        out_sem.at[s],
-                    )
-                ]
+            def out_copies(c0, s):
+                cps = [pltpu.make_async_copy(
+                    wire[s], arena.at[pl.ds(offsets[i] + c0, ck)], out_sem.at[s])]
                 if ef:
-                    cps.append(
-                        pltpu.make_async_copy(
-                            res.at[s, pl.ds(0, m)],
-                            new_res[i].at[pl.ds(c0, m)],
-                            res_out_sem.at[s],
-                        )
-                    )
+                    cps.append(pltpu.make_async_copy(
+                        res[s], new_res[i].at[pl.ds(c0, ck)], res_out_sem.at[s]))
                 return cps
 
-            for cp in in_dmas(0):  # warm-up: first chunk's inbound copies
-                cp.start()
-            for k in range(len(c0s)):
-                m = min(ck, sizes[i] - c0s[k])
-                s = k % 2
-                if k >= 1:
-                    # Drain chunk k-1's outbound copies: they share slot
-                    # (k+1) % 2 with chunk k+1's inbound ones.
-                    for cp in out_dmas(k - 1):
-                        cp.wait()
-                if k + 1 < len(c0s):
-                    for cp in in_dmas(k + 1):
-                        cp.start()
-                for cp in in_dmas(k):
-                    cp.wait()
-                x = src[s, pl.ds(0, m)].astype(jnp.float32)
+            def compute(s):
+                x = src[s][...].astype(jnp.float32)
                 if ef:
-                    x = x + res[s, pl.ds(0, m)]
+                    x = x + res[s][...]
                 w = x.astype(comm_dtype)
-                wire[s, pl.ds(0, m)] = w
+                wire[s][...] = w
                 if ef:
-                    res[s, pl.ds(0, m)] = x - w.astype(jnp.float32)
-                for cp in out_dmas(k):
-                    cp.start()
-            for cp in out_dmas(len(c0s) - 1):
-                cp.wait()
+                    res[s][...] = x - w.astype(jnp.float32)
+
+            _pipeline(body, ck, in_copies, out_copies, compute)
 
         scratch = dict(
-            src=pltpu.VMEM((2, ck), parts[i].dtype),
-            wire=pltpu.VMEM((2, ck), comm_dtype),
+            src0=pltpu.VMEM((ck,), parts[i].dtype),
+            src1=pltpu.VMEM((ck,), parts[i].dtype),
+            wire0=pltpu.VMEM((ck,), comm_dtype),
+            wire1=pltpu.VMEM((ck,), comm_dtype),
             in_sem=pltpu.SemaphoreType.DMA((2,)),
             out_sem=pltpu.SemaphoreType.DMA((2,)),
         )
         if ef:
-            scratch["res"] = pltpu.VMEM((2, ck), jnp.float32)
-            scratch["res_in_sem"] = pltpu.SemaphoreType.DMA((2,))
-            scratch["res_out_sem"] = pltpu.SemaphoreType.DMA((2,))
+            scratch.update(
+                res0=pltpu.VMEM((ck,), jnp.float32),
+                res1=pltpu.VMEM((ck,), jnp.float32),
+                res_in_sem=pltpu.SemaphoreType.DMA((2,)),
+                res_out_sem=pltpu.SemaphoreType.DMA((2,)),
+            )
         pl.run_scoped(part, **scratch)
 
 
@@ -167,77 +204,82 @@ def pack_arena_pallas(
     interpret: bool = False,
 ) -> tuple[jax.Array, list[jax.Array] | None]:
     """Fused pack(+cast[+error-feedback]) of one group's wire arena."""
+    _check_chunk(chunk)
     ef = residuals is not None
+    offsets = tuple(int(o) for o in offsets)
     sizes = tuple(int(p.size) for p in parts)
-    kernel = functools.partial(
-        _pack_kernel,
-        sizes=sizes,
-        offsets=tuple(int(o) for o in offsets),
-        chunk=chunk,
-        comm_dtype=comm_dtype,
-        ef=ef,
-    )
-    out_shape = [jax.ShapeDtypeStruct((size,), comm_dtype)]
-    if ef:
-        out_shape += [jax.ShapeDtypeStruct((s,), jnp.float32) for s in sizes]
-    operands = list(parts) + (list(residuals) if ef else [])
-    out = pl.pallas_call(
-        kernel,
-        in_specs=[_ANY] * len(operands),
-        out_specs=[_ANY] * len(out_shape),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
-    return (out[0], list(out[1:])) if ef else (out[0], None)
+    bodies = tuple(aligned_body(o, n) for o, n in zip(offsets, sizes))
+    kin = [i for i, b in enumerate(bodies) if b]
+    if kin:
+        kernel = functools.partial(
+            _pack_kernel,
+            bodies=tuple(bodies[i] for i in kin),
+            offsets=tuple(offsets[i] for i in kin),
+            chunk=chunk,
+            comm_dtype=comm_dtype,
+            ef=ef,
+        )
+        out_shape = [jax.ShapeDtypeStruct((size,), comm_dtype)]
+        if ef:
+            out_shape += [jax.ShapeDtypeStruct((sizes[i],), jnp.float32) for i in kin]
+        operands = [parts[i] for i in kin] + ([residuals[i] for i in kin] if ef else [])
+        out = pl.pallas_call(
+            kernel,
+            in_specs=[_ANY] * len(operands),
+            out_specs=[_ANY] * len(out_shape),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(*operands)
+        arena = out[0]
+        new_res = dict(zip(kin, out[1:]))
+    else:
+        arena = jnp.zeros((size,), comm_dtype)
+        new_res = {}
+    # the remainder: ragged tails, and parts that start mid-tile
+    for i, (off, n, b) in enumerate(zip(offsets, sizes, bodies)):
+        if b == n:
+            continue
+        w, r = encode_part(parts[i][b:], residuals[i][b:] if ef else None, comm_dtype)
+        arena = jax.lax.dynamic_update_slice(arena, w, (off + b,))
+        if ef:
+            new_res[i] = jax.lax.dynamic_update_slice(new_res[i], r, (b,)) if b else r
+    return arena, ([new_res[i] for i in range(len(parts))] if ef else None)
 
 
 def _unpack_kernel(
     arena,
     scale_ref,  # (1,) f32 in SMEM: the DP averaging factor
     *outs,
-    slots: tuple[tuple[int, int], ...],
+    slots: tuple[tuple[int, int], ...],  # (offset, aligned body) per part
     dtypes: tuple[Any, ...],
     chunk: int,
 ):
-    for i, (off, sz) in enumerate(slots):
-        ck = min(chunk, sz)
-        c0s = tuple(range(0, sz, ck))
+    for i, (off, body) in enumerate(slots):
+        ck = min(chunk, body)
 
-        def part(wire, dst, in_sem, out_sem, i=i, off=off, sz=sz, ck=ck, c0s=c0s):
-            def in_dma(k):
-                c0 = c0s[k]
-                m = min(ck, sz - c0)
-                s = k % 2
-                return pltpu.make_async_copy(
-                    arena.at[pl.ds(off + c0, m)], wire.at[s, pl.ds(0, m)], in_sem.at[s]
-                )
+        def part(wire0, wire1, dst0, dst1, in_sem, out_sem, i=i, off=off, body=body, ck=ck):
+            wire, dst = (wire0, wire1), (dst0, dst1)
 
-            def out_dma(k):
-                c0 = c0s[k]
-                m = min(ck, sz - c0)
-                s = k % 2
-                return pltpu.make_async_copy(
-                    dst.at[s, pl.ds(0, m)], outs[i].at[pl.ds(c0, m)], out_sem.at[s]
-                )
+            def in_copies(c0, s):
+                return [pltpu.make_async_copy(
+                    arena.at[pl.ds(off + c0, ck)], wire[s], in_sem.at[s])]
 
-            in_dma(0).start()  # warm-up
-            for k in range(len(c0s)):
-                m = min(ck, sz - c0s[k])
-                s = k % 2
-                if k >= 1:
-                    out_dma(k - 1).wait()  # frees the slot chunk k+1 stages into
-                if k + 1 < len(c0s):
-                    in_dma(k + 1).start()
-                in_dma(k).wait()
-                x = wire[s, pl.ds(0, m)].astype(jnp.float32) * scale_ref[0]
-                dst[s, pl.ds(0, m)] = x.astype(dtypes[i])
-                out_dma(k).start()
-            out_dma(len(c0s) - 1).wait()
+            def out_copies(c0, s):
+                return [pltpu.make_async_copy(
+                    dst[s], outs[i].at[pl.ds(c0, ck)], out_sem.at[s])]
+
+            def compute(s):
+                x = wire[s][...].astype(jnp.float32) * scale_ref[0]
+                dst[s][...] = x.astype(dtypes[i])
+
+            _pipeline(body, ck, in_copies, out_copies, compute)
 
         pl.run_scoped(
             part,
-            wire=pltpu.VMEM((2, ck), arena.dtype),
-            dst=pltpu.VMEM((2, ck), dtypes[i]),
+            wire0=pltpu.VMEM((ck,), arena.dtype),
+            wire1=pltpu.VMEM((ck,), arena.dtype),
+            dst0=pltpu.VMEM((ck,), dtypes[i]),
+            dst1=pltpu.VMEM((ck,), dtypes[i]),
             in_sem=pltpu.SemaphoreType.DMA((2,)),
             out_sem=pltpu.SemaphoreType.DMA((2,)),
         )
@@ -253,17 +295,31 @@ def unpack_arena_pallas(
     interpret: bool = False,
 ) -> list[jax.Array]:
     """Fused unpack(+decompress+average) of one reduced arena."""
-    kernel = functools.partial(
-        _unpack_kernel,
-        slots=tuple((int(o), int(s)) for o, s in slots),
-        dtypes=tuple(dtypes),
-        chunk=chunk,
-    )
-    out = pl.pallas_call(
-        kernel,
-        in_specs=[_ANY, pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[_ANY] * len(slots),
-        out_shape=[jax.ShapeDtypeStruct((s,), dt) for (_, s), dt in zip(slots, dtypes)],
-        interpret=interpret,
-    )(arena, scale.astype(jnp.float32).reshape(1))
-    return list(out)
+    _check_chunk(chunk)
+    slots = tuple((int(o), int(s)) for o, s in slots)
+    dtypes = tuple(dtypes)
+    scale = scale.astype(jnp.float32).reshape(1)
+    bodies = tuple(aligned_body(o, n) for o, n in slots)
+    kin = [i for i, b in enumerate(bodies) if b]
+    out: dict[int, jax.Array] = {}
+    if kin:
+        kernel = functools.partial(
+            _unpack_kernel,
+            slots=tuple((slots[i][0], bodies[i]) for i in kin),
+            dtypes=tuple(dtypes[i] for i in kin),
+            chunk=chunk,
+        )
+        res = pl.pallas_call(
+            kernel,
+            in_specs=[_ANY, pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM)],
+            out_specs=[_ANY] * len(kin),
+            out_shape=[jax.ShapeDtypeStruct((slots[i][1],), dtypes[i]) for i in kin],
+            interpret=interpret,
+        )(arena, scale)
+        out = dict(zip(kin, res))
+    # the remainder: ragged tails, and parts that start mid-tile
+    for i, ((off, n), b) in enumerate(zip(slots, bodies)):
+        if b < n:
+            seg = decode_part(jax.lax.slice(arena, (off + b,), (off + n,)), dtypes[i], scale[0])
+            out[i] = seg if not b else jax.lax.dynamic_update_slice(out[i], seg, (b,))
+    return [out[i] for i in range(len(slots))]

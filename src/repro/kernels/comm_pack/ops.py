@@ -23,6 +23,27 @@ def _use_pallas(use_pallas: bool | None) -> bool:
     return jax.default_backend() == "tpu" if use_pallas is None else use_pallas
 
 
+def _per_device(f):
+    """Run ``f`` once per device over the mesh axes left to the compiler.
+
+    GSPMD cannot partition a Mosaic kernel, so inside a ``shard_map``
+    that keeps some mesh axes automatic (the train step's model axis)
+    the kernel is wrapped in one more ``shard_map``, its operands
+    replicated across those axes.  That ``shard_map`` names every mesh
+    axis, the outer manual ones too: under ``jax.set_mesh`` it lowers
+    against the concrete mesh, which does not know the outer axes are
+    manual, and Mosaic refuses a kernel unless all axes are.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if not set(mesh.axis_names) - set(mesh.manual_axes):
+        return f
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=P(), out_specs=P(), axis_names=set(mesh.axis_names),
+        check_vma=False,
+    )
+
+
 def pack_arena(
     parts: Sequence[jax.Array],
     offsets: Sequence[int],
@@ -46,9 +67,11 @@ def pack_arena(
     res_flat = None if residuals is None else [r.reshape(-1) for r in residuals]
     if _use_pallas(use_pallas) or interpret:
         kw = {} if chunk is None else {"chunk": chunk}
-        arena, new_res = pack_arena_pallas(
-            flat, offsets, size, comm_dtype, res_flat, interpret=interpret, **kw
-        )
+        arena, new_res = _per_device(
+            lambda flat, res_flat: pack_arena_pallas(
+                flat, offsets, size, comm_dtype, res_flat, interpret=interpret, **kw
+            )
+        )(flat, res_flat)
     else:
         arena, new_res = pack_arena_ref(flat, offsets, size, comm_dtype, res_flat)
     if new_res is not None:
@@ -71,10 +94,11 @@ def unpack_arena(
     fused); parts come back in their original shapes/dtypes."""
     if _use_pallas(use_pallas) or interpret:
         kw = {} if chunk is None else {"chunk": chunk}
-        out = unpack_arena_pallas(
-            arena, slots, dtypes, jnp.asarray(scale, jnp.float32).reshape(1),
-            interpret=interpret, **kw,
-        )
+        out = _per_device(
+            lambda arena, scale: unpack_arena_pallas(
+                arena, slots, dtypes, scale, interpret=interpret, **kw
+            )
+        )(arena, jnp.asarray(scale, jnp.float32).reshape(1))
     else:
         out = unpack_arena_ref(arena, slots, dtypes, scale)
     return [p.reshape(s) for p, s in zip(out, shapes)]

@@ -21,6 +21,25 @@ import jax
 import jax.numpy as jnp
 
 
+def encode_part(
+    part: jax.Array,  # 1-D gradient span
+    residual: jax.Array | None,  # 1-D f32, same size, or None
+    comm_dtype: Any,
+) -> tuple[jax.Array, jax.Array | None]:
+    """(wire, new_residual) of one span: the cast, with the carried
+    quantization error re-added first when ``residual`` is given."""
+    if residual is None:
+        return part.astype(comm_dtype), None
+    acc = part.astype(jnp.float32) + residual.astype(jnp.float32)
+    wire = acc.astype(comm_dtype)
+    return wire, acc - wire.astype(jnp.float32)
+
+
+def decode_part(seg: jax.Array, dtype: Any, scale: jax.Array | float) -> jax.Array:
+    """Decompress one reduced span and apply the DP averaging factor."""
+    return (seg.astype(jnp.float32) * scale).astype(dtype)
+
+
 def pack_arena_ref(
     parts: Sequence[jax.Array],  # flattened 1-D gradient parts
     offsets: Sequence[int],  # element offset of each part in the arena
@@ -32,12 +51,9 @@ def pack_arena_ref(
     arena = jnp.zeros((size,), comm_dtype)
     new_res: list[jax.Array] | None = None if residuals is None else []
     for i, (p, off) in enumerate(zip(parts, offsets)):
-        if residuals is not None:
-            acc = p.astype(jnp.float32) + residuals[i].astype(jnp.float32)
-            wire = acc.astype(comm_dtype)
-            new_res.append(acc - wire.astype(jnp.float32))
-        else:
-            wire = p.astype(comm_dtype)
+        wire, r = encode_part(p, None if residuals is None else residuals[i], comm_dtype)
+        if new_res is not None:
+            new_res.append(r)
         arena = jax.lax.dynamic_update_slice(arena, wire, (off,))
     return arena, new_res
 
@@ -49,8 +65,7 @@ def unpack_arena_ref(
     scale: jax.Array | float = 1.0,  # DP averaging factor (1/world)
 ) -> list[jax.Array]:
     """Static slices out of the reduced arena, decompress + scale fused."""
-    out = []
-    for (off, n), dt in zip(slots, dtypes):
-        seg = jax.lax.slice(arena, (off,), (off + n,))
-        out.append((seg.astype(jnp.float32) * scale).astype(dt))
-    return out
+    return [
+        decode_part(jax.lax.slice(arena, (off,), (off + n,)), dt, scale)
+        for (off, n), dt in zip(slots, dtypes)
+    ]

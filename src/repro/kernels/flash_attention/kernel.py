@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ...compat import tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -185,7 +183,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

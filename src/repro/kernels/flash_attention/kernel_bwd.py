@@ -22,8 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ...compat import tpu_compiler_params
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
@@ -196,7 +194,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((None, block_q, hd), q_map_q),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -232,7 +230,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

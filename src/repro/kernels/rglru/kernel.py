@@ -23,8 +23,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ...compat import tpu_compiler_params
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -105,7 +103,7 @@ def rglru_pallas(
             jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,8 +23,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ...compat import tpu_compiler_params
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -134,7 +132,7 @@ def wkv_pallas(
             jax.ShapeDtypeStruct((B * H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

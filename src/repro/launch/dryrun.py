@@ -31,7 +31,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.shapes import LONG_CONTEXT_SKIP, SHAPES, applicable_shapes
 from repro.core.profiler import parse_collectives
@@ -138,7 +137,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, fsdp_data=True,
         b_specs = batch_input_specs(cfg, shape)
         b_sh = named(batch_specs(cfg, rules, shape.global_batch, shape.seq_len), mesh)
         step = make_train_step(cfg, rules, opt, n_microbatches=n_microbatches)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, in_shardings=(p_sh, o_sh, b_sh), out_shardings=(p_sh, o_sh, None),
                 donate_argnums=(0, 1),
@@ -151,7 +150,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, fsdp_data=True,
         bsp.pop("targets")
         b_sh = named(bsp, mesh)
         step = make_prefill_step(cfg, rules, max_seq=shape.seq_len)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(p_sh, b_sh)).lower(p_shapes, b_specs)
             compiled = lowered.compile()
     else:  # decode
@@ -163,7 +162,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, fsdp_data=True,
         b_sh = named(bsp, mesh)
         pos = jax.ShapeDtypeStruct((), jnp.int32)
         step = make_decode_step(cfg, rules)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, in_shardings=(p_sh, c_sh, b_sh, None), donate_argnums=(1,),
             ).lower(p_shapes, c_shapes, b_specs, pos)

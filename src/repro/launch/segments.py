@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import set_mesh
 from ..core.profiler import CollectiveStats, parse_collectives
 from ..models.common import ArchConfig
 from ..models.transformer import apply_stage, init_params
@@ -117,7 +116,7 @@ def stage_train_segment(
         dsp, dx = vjp((dy, jnp.zeros((), jnp.float32)))
         return y, dsp, dx
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
             jax.jit(seg, in_shardings=(stage_sh, x_sh, x_sh))
             .lower(stage_shapes, x_spec, x_spec)
@@ -154,7 +153,7 @@ def stage_fwd_segment(
 
     args = (stage_shapes, x_spec, caches)
     shardings = (stage_sh, x_sh, cache_sh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(seg, in_shardings=shardings).lower(*args).compile()
     return _cost_of("stage_fwd", compiled)
 
@@ -209,7 +208,7 @@ def head_train_segment(
     def seg_grad(hp, batch_in, x_mid):
         return jax.value_and_grad(seg)(hp, batch_in, x_mid)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
             jax.jit(seg_grad, in_shardings=(hp_sh, b_sh, x_sh))
             .lower(hp_shapes, batch_in, x_spec)
@@ -244,6 +243,6 @@ def head_fwd_segment(
         return softcap_logits(logits, cfg.logit_softcap)
 
     x_spec = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.param_dtype)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(seg, in_shardings=(hp_sh, x_sh)).lower(hp_shapes, x_spec).compile()
     return _cost_of("head_fwd", compiled)

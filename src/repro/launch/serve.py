@@ -27,6 +27,7 @@ per-group table.
 
 from __future__ import annotations
 
+import os
 import sys
 
 
@@ -43,9 +44,12 @@ def _requested_virtual_tp() -> int:
     return 8
 
 
-if "--sharded" in sys.argv or "--measure-comm" in sys.argv:
-    # the TP mesh needs the virtual CPU devices before jax initializes
-    from ..compat import ensure_virtual_devices
+if os.environ.get("JAX_PLATFORMS") == "cpu" and (
+    "--sharded" in sys.argv or "--measure-comm" in sys.argv
+):
+    # CPU rehearsal only: the TP mesh needs virtual CPU devices before jax
+    # initializes.  Anywhere else the mesh takes real devices or fails.
+    from .mesh import ensure_virtual_devices
 
     ensure_virtual_devices(_requested_virtual_tp())
 
@@ -59,9 +63,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import make_mesh
 from ..configs import ARCH_NAMES, get_config, get_reduced
+from ..core.cost_model import hardware_for
 from ..fabric import MeasuredFabric, available_fabrics
+from ..launch.compile_cache import enable_compile_cache
+from ..launch.mesh import make_mesh
 from ..launch.specs import param_specs
 from ..models.transformer import init_params
 from ..planning import (
@@ -82,7 +88,9 @@ from ..serving import (
 )
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> list[Request]:
+    """Run the launcher on ``argv`` (default: the command line) and
+    return the completed requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_NAMES)
     ap.add_argument("--reduced", action="store_true")
@@ -92,6 +100,8 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the weights and the request prompts")
     ap.add_argument("--fabric", default="tpu_v5e",
                     choices=available_fabrics(),
                     help="interconnect preset pricing the decode collectives: "
@@ -101,10 +111,12 @@ def main() -> None:
                     help="scheduler policy for the serve plan")
     ap.add_argument("--virtual-tp", type=int, default=8,
                     help="TP size of the serve-plan collective model (and of "
-                         "the virtual mesh under --sharded)")
+                         "the mesh under --sharded, which needs that many "
+                         "devices)")
     ap.add_argument("--sharded", action="store_true",
-                    help="execute the plan: sharded decode on a virtual TP "
-                         "mesh, one fused collective per serve group")
+                    help="execute the plan: sharded decode on a "
+                         "--virtual-tp-wide mesh, one fused collective per "
+                         "serve group")
     ap.add_argument("--measure-comm", action="store_true",
                     help="time the real per-group collectives, fit a "
                          "MeasuredFabric, and print predicted-vs-measured "
@@ -133,29 +145,30 @@ def main() -> None:
                          "is active and this is unset)")
     ap.add_argument("--max-restarts", type=int, default=5,
                     help="restart budget for the resilient serve loop")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg, param_dtype=jnp.float32)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    max_seq = args.prompt_len + args.tokens + 1
-
+    hw = hardware_for(jax.devices()[0])
     mesh = None
     tp = args.virtual_tp
     if args.sharded or args.measure_comm:
-        tp = min(args.virtual_tp, jax.device_count())
-        if tp < args.virtual_tp:
-            print(f"[serve] only {jax.device_count()} devices visible; "
-                  f"clamping TP {args.virtual_tp} -> {tp}")
+        if jax.device_count() < tp:
+            ap.error(f"--virtual-tp {tp} needs {tp} devices; "
+                     f"{jax.device_count()} {jax.devices()[0].platform} "
+                     "device(s) are visible")
         mesh = make_mesh((tp,), ("model",))
+    print(f"[serve] compile cache: {enable_compile_cache()}")
+    params = init_params(jax.random.PRNGKey(args.seed), cfg)
+    max_seq = args.prompt_len + args.tokens + 1
 
     # ServingEngine allocates fp32 decode caches, so the executed wire
     # ships 4-byte elements — price the plan at what the step ships
     cache_bytes = 4
     plan = build_serve_plan(
         cfg, param_specs(cfg), args.fabric, {"model": tp},
-        batch_rows=args.slots, policy=args.policy,
+        batch_rows=args.slots, policy=args.policy, hw=hw,
         cache_dtype_bytes=cache_bytes, act_dtype_bytes=cache_bytes,
     )
     print(f"[serve] {plan.describe()}")
@@ -189,7 +202,7 @@ def main() -> None:
     )
 
     def submit_all(eng, deadline_s=None):
-        rng = np.random.default_rng(0)
+        rng = np.random.default_rng(args.seed)
         for rid in range(args.requests):
             eng.submit(Request(
                 rid=rid,
@@ -275,7 +288,9 @@ def main() -> None:
         completed = engine.run_to_completion()
         dt = time.time() - t0
     n_tok = sum(len(r.generated) for r in completed)
-    mode = f"sharded TP={tp}" if args.sharded else "unsharded"
+    dev = jax.devices()[0]
+    mode = (f"sharded TP={tp}" if args.sharded else "unsharded") + \
+        f" on {dev.platform} {dev.device_kind}"
     print(f"[serve] {len(completed)} requests, {n_tok} tokens in {dt:.2f}s "
           f"({n_tok / max(dt, 1e-9):.1f} tok/s, {args.slots} slots, {mode})")
     predicted = engine.predicted_step_time()
@@ -297,7 +312,7 @@ def main() -> None:
             print(f"[serve] measured fit {key}: a={fit.a:.3e}s b={fit.b:.3e}s/B")
         measured_plan = build_serve_plan(
             cfg, param_specs(cfg), fab, {"model": tp},
-            batch_rows=args.slots, policy=args.policy, op=plan.op,
+            batch_rows=args.slots, policy=args.policy, op=plan.op, hw=hw,
             cache_dtype_bytes=cache_bytes, act_dtype_bytes=cache_bytes,
         )
         print(f"[serve] measured-fabric plan: {measured_plan.describe()}")
@@ -310,6 +325,7 @@ def main() -> None:
     if args.plan_out:
         path = plan.save(args.plan_out)
         print(f"[serve] serve plan written to {path}")
+    return completed
 
 
 if __name__ == "__main__":

@@ -39,21 +39,26 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import latest_step, load_plan, load_tuner_state
-from ..compat import set_mesh
 from ..configs import ARCH_NAMES, get_config, get_reduced
+from ..core.cost_model import Hardware, hardware_for
 from ..core.sync import SyncConfig
 from ..core.trainer import MGWFBPEngine
 from ..data import DataConfig, make_stream
 from ..fabric import MeasuredFabric, available_fabrics, get_fabric
+from ..launch.compile_cache import enable_compile_cache
 from ..launch.mesh import make_mesh
 from ..launch.specs import param_specs
+from ..models.common import ArchConfig
 from ..models.transformer import init_params
 from ..optim import make_optimizer
+from ..optim.optimizers import Optimizer
 from ..planning import (
     CommRefitter,
     DEFAULT_COMM_SWEEP,
@@ -69,6 +74,56 @@ from ..runtime import RunState, StragglerMonitor, StepTimer, resilient_loop
 from ..runtime.timeline import make_unit_probes, probe_unit_times
 
 
+@dataclasses.dataclass
+class TrainSetup:
+    """What a parsed command line resolves to before any step is built:
+    the model, the mesh, the wire and the comm model that prices plans."""
+
+    args: argparse.Namespace
+    cfg: ArchConfig
+    mesh: jax.sharding.Mesh
+    sync_cfg: SyncConfig
+    hw: Hardware
+    ar_model: Any
+    comm_obs: MeasuredComm
+    opt: Optimizer
+
+    def engine(self, plan: Plan | None = None, from_tuner: bool = False) -> MGWFBPEngine:
+        """The engine for ``plan``, or for a fresh plan of ``--policy``."""
+        args = self.args
+        return MGWFBPEngine.build(
+            self.cfg,
+            param_specs(self.cfg),
+            dp_axes=("data",),
+            ar_model=self.ar_model,
+            tokens_per_device=args.batch * args.seq // self.mesh.size,
+            hw=self.hw,
+            # a loaded plan carries its own policy; an explicitly requested
+            # one is forwarded so the engine can reject a mismatch instead
+            # of silently losing it.  Tuner-chosen plans own their policy.
+            policy=(None if from_tuner else args.policy)
+            if plan is not None
+            else (args.policy or "mg_wfbp"),
+            sync_config=self.sync_cfg,
+            plan=plan,
+        )
+
+    def train_step(self, eng: MGWFBPEngine, recorder=None):
+        """The jitted train step of ``eng`` under this command line."""
+        return eng.make_train_step(
+            self.opt, self.mesh, lr=self.args.lr,
+            issue=self.args.issue_order, recorder=recorder,
+        )
+
+
+@dataclasses.dataclass
+class TrainResult:
+    """What ``main`` returns: the final state and every step's loss."""
+
+    final: RunState
+    losses: list[float]
+
+
 def _dryrun(args, eng, make_step, init_state, data, mesh) -> None:
     """Trace-first smoke: run ``args.dryrun`` steps under a span recorder
     and report how much of the wire the chosen issue order actually hides
@@ -81,7 +136,7 @@ def _dryrun(args, eng, make_step, init_state, data, mesh) -> None:
     batch = jax.tree.map(jnp.asarray, data.batch_at(0))
 
     def one(state):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if eng.stateful:
                 p, o, res, m = step_fn(
                     state.params, state.opt_state, state.residual, batch
@@ -123,7 +178,7 @@ def _dryrun(args, eng, make_step, init_state, data, mesh) -> None:
         print(f"[dryrun] trace written to {args.trace_out}")
 
 
-def main() -> None:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_NAMES)
     ap.add_argument("--reduced", action="store_true",
@@ -132,6 +187,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--lr", type=float, default=3e-4)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the initial weights and the data stream")
     ap.add_argument("--optimizer", default="adamw", choices=["adamw", "sgd"])
     ap.add_argument("--policy", "--method", dest="policy", default=None,
                     choices=list(available_policies()),
@@ -199,26 +256,36 @@ def main() -> None:
     ap.add_argument("--trace-out", default=None,
                     help="with --dryrun: write the Chrome-trace JSON here "
                          "(.gz for gzip)")
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """The launcher's command line (default: ``sys.argv``), validated."""
+    ap = _parser()
+    args = ap.parse_args(argv)
     if args.plan_in and args.autotune:
         ap.error("--plan-in and --autotune are mutually exclusive: the "
                  "tuner's sweep picks the plan (drop --autotune to pin a "
                  "serialized plan)")
+    if args.compression is None and args.comm_dtype == "bf16":
+        args.compression = "bf16"
+    if args.compression == "bf16_ef" and args.fuse != "arena":
+        ap.error("--compression bf16_ef requires --fuse arena")
+    return args
 
+
+def setup(args: argparse.Namespace) -> TrainSetup:
+    """Resolve a parsed command line: model, data-parallel mesh over every
+    visible device, wire config, and the comm model pricing the plan."""
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg, param_dtype=jnp.float32)
+    hw = hardware_for(jax.devices()[0])
     n_dev = jax.device_count()
     mesh = make_mesh((n_dev, 1), ("data", "model"))
-
-    compression = args.compression
-    if compression is None and args.comm_dtype == "bf16":
-        compression = "bf16"
-    if compression == "bf16_ef" and args.fuse != "arena":
-        ap.error("--compression bf16_ef requires --fuse arena")
     sync_cfg = SyncConfig(
         comm_dtype=jnp.bfloat16 if args.comm_dtype == "bf16" else jnp.float32,
-        compression=compression,
+        compression=args.compression,
         fuse=args.fuse,
     )
 
@@ -237,41 +304,35 @@ def main() -> None:
             times_s=tuple(ar_model(s) for s in DEFAULT_COMM_SWEEP),
             name="analytic_prior",
         )
+    return TrainSetup(args=args, cfg=cfg, mesh=mesh, sync_cfg=sync_cfg, hw=hw,
+                      ar_model=ar_model, comm_obs=comm_obs,
+                      opt=make_optimizer(args.optimizer))
 
-    def build_engine(plan: Plan | None = None, from_tuner: bool = False) -> MGWFBPEngine:
-        return MGWFBPEngine.build(
-            cfg,
-            param_specs(cfg),
-            dp_axes=("data",),
-            ar_model=ar_model,
-            tokens_per_device=args.batch * args.seq // n_dev,
-            # a loaded plan carries its own policy; an explicitly requested
-            # one is forwarded so the engine can reject a mismatch instead
-            # of silently losing it.  Tuner-chosen plans own their policy.
-            policy=(None if from_tuner else args.policy)
-            if plan is not None
-            else (args.policy or "mg_wfbp"),
-            sync_config=sync_cfg,
-            plan=plan,
-        )
+
+def main(argv: list[str] | None = None) -> TrainResult | None:
+    """Run the launcher on ``argv`` (default: the command line).  Returns
+    the final state and per-step losses (None after ``--dryrun``)."""
+    args = parse_args(argv)
+    print(f"[train] compile cache: {enable_compile_cache()}")
+    ts = setup(args)
+    cfg, mesh, sync_cfg = ts.cfg, ts.mesh, ts.sync_cfg
+    ar_model, comm_obs = ts.ar_model, ts.comm_obs
+    build_engine = ts.engine
 
     plan_in = Plan.load(args.plan_in) if args.plan_in else None
     state_box = {"eng": build_engine(plan_in)}
 
-    opt = make_optimizer(args.optimizer)
+    opt = ts.opt
     data = make_stream(
         DataConfig(
             vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch,
-            input_mode=cfg.input_mode, d_model=cfg.d_model,
+            seed=args.seed, input_mode=cfg.input_mode, d_model=cfg.d_model,
         )
     )
     monitor = StragglerMonitor()
     timer = StepTimer(window=max(8, args.replan_every or 8))
 
-    def make_step(eng: MGWFBPEngine, recorder=None):
-        return eng.make_train_step(
-            opt, mesh, lr=args.lr, issue=args.issue_order, recorder=recorder
-        )
+    make_step = ts.train_step
 
     tuner: Tuner | None = None
     if args.autotune:
@@ -286,7 +347,7 @@ def main() -> None:
         # built) when the tuner actually needs them — a plain run must not
         # pin a second copy of the parameters
         probe_batch = jax.tree.map(jnp.asarray, data.batch_at(0))
-        probe_params = init_params(jax.random.PRNGKey(0), cfg)
+        probe_params = init_params(jax.random.PRNGKey(args.seed), cfg)
         state_box["probes"] = make_unit_probes(cfg, probe_params, probe_batch)
     if args.comm_refit_every:
         state_box["refitter"] = CommRefitter(
@@ -335,15 +396,25 @@ def main() -> None:
     print(f"[train] scan segments: {state_box['eng'].segments}")
 
     def init_state() -> RunState:
-        params = init_params(jax.random.PRNGKey(0), cfg)
+        # one replica per device, committed once: every step then takes
+        # the shardings its outputs come back with, and compiles once.
+        # Each device draws its own replica, so no device holds an extra
+        # unreplicated copy or the draw's f32 temporaries.
+        eng = state_box["eng"]
+        replicated = NamedSharding(mesh, P())
+        params = jax.jit(init_params, static_argnums=1, out_shardings=replicated)(
+            jax.random.PRNGKey(args.seed), cfg)
+        residual = eng.init_residual(params, mesh)
         return RunState(
-            step=0, params=params, opt_state=opt.init(params),
-            residual=state_box["eng"].init_residual(params, mesh),
+            step=0, params=params,
+            opt_state=jax.device_put(opt.init(params), replicated),
+            residual=None if residual is None
+            else jax.device_put(residual, NamedSharding(mesh, P(eng.dp_axes))),
         )
 
     if args.dryrun:
         _dryrun(args, state_box["eng"], make_step, init_state, data, mesh)
-        return
+        return None
 
     def maybe_replan(step: int) -> None:
         """Measured-profile drift check (journal MG-WFBP online re-plan)."""
@@ -402,12 +473,13 @@ def main() -> None:
                 adopt_plan(new_plan, f"step {step}: comm drift {drift:.3f} re-plan")
 
     track_time = bool(args.replan_every or args.comm_refit_every or args.autotune)
+    losses: dict[int, jax.Array] = {}  # by step: a restart overwrites its redo
 
     def do_step(state: RunState, step: int) -> RunState:
         batch = jax.tree.map(jnp.asarray, data.batch_at(step))
         eng = state_box["eng"]
         timer.start()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if eng.stateful:
                 p, o, res, m = state_box["step_fn"](
                     state.params, state.opt_state, state.residual, batch
@@ -424,6 +496,7 @@ def main() -> None:
                 maybe_replan(step)
             if args.comm_refit_every and step and step % args.comm_refit_every == 0:
                 maybe_refit_comm(step)
+        losses[step] = m["loss"]
         if step % 10 == 0:
             print(f"[train] step {step} loss {float(m['loss']):.4f}")
         return RunState(step=state.step, params=p, opt_state=o,
@@ -507,6 +580,7 @@ def main() -> None:
     if args.plan_out:
         path = state_box["eng"].plan.save(args.plan_out)
         print(f"[train] plan written to {path}")
+    return TrainResult(final=final, losses=[float(losses[k]) for k in sorted(losses)])
 
 
 if __name__ == "__main__":

@@ -298,8 +298,6 @@ class MeasuredComm:
         import jax
         import jax.numpy as jnp
 
-        from ..compat import shard_map
-
         dtype = jnp.float32 if dtype is None else dtype
         P = jax.sharding.PartitionSpec
         axis_arg = axes if len(axes) > 1 else axes[0]
@@ -312,7 +310,7 @@ class MeasuredComm:
                 return jax.lax.psum(v, axis_arg)
 
             f = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                     axis_names=set(axes), check_vma=False,
                 )

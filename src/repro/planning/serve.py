@@ -420,8 +420,6 @@ def make_group_collective(plan: ServePlan, axis: str | None = None):
     import jax
     import jax.numpy as jnp
 
-    from ..compat import axis_size
-
     ax = axis or plan.axis
     op = Collective(plan.op)
     groups = plan.schedule.groups
@@ -436,7 +434,7 @@ def make_group_collective(plan: ServePlan, axis: str | None = None):
             flat = stacked[lo - 1 : hi].reshape(-1)
             with jax.named_scope(f"serve_group{gi}_s{lo}_{hi}"):
                 if op is Collective.ALL_TO_ALL:
-                    n = axis_size(ax)
+                    n = jax.lax.axis_size(ax)
                     pad = (-flat.shape[0]) % n
                     if pad:
                         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
@@ -553,7 +551,6 @@ def measure_serve_comm(
     import jax
     import jax.numpy as jnp
 
-    from ..compat import shard_map
     from .costs import DEFAULT_COMM_SWEEP, MeasuredComm, time_collective_call
 
     if len(axes) != 1:
@@ -580,7 +577,7 @@ def measure_serve_comm(
             return issue(op, v, axis)
 
         f = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=mesh, in_specs=(P(),),
                 out_specs=P() if replicated_out else P(axis),
                 axis_names={axis}, check_vma=False,

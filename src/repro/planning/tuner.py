@@ -394,8 +394,6 @@ def psum_time_fn(mesh, axes: tuple[str, ...] = ("data",), dtype=None,
     import jax.numpy as jnp
     import numpy as _np
 
-    from ..compat import shard_map
-
     dt = jnp.float32 if dtype is None else dtype
     axis_arg = axes if len(axes) > 1 else axes[0]
     P = jax.sharding.PartitionSpec
@@ -407,7 +405,7 @@ def psum_time_fn(mesh, axes: tuple[str, ...] = ("data",), dtype=None,
                 return jax.lax.psum(v, axis_arg)
 
             compiled[n] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                     axis_names=set(axes), check_vma=False,
                 )

@@ -32,7 +32,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size as _axis_size
 
 Pytree = Any
 
@@ -91,7 +90,7 @@ def compressed_psum_rs_ag(
     if residual is not None:
         gf = gf + residual
 
-    axis_size = _axis_size(axis)
+    axis_size = jax.lax.axis_size(axis)
     pad = (-gf.size) % axis_size
     flat = jnp.pad(gf.reshape(-1), (0, pad))
     # reduce-scatter: each rank owns shard i of the full sum

@@ -190,10 +190,12 @@ class ServingEngine:
             "key": jax.random.PRNGKey(sample_seed),
         }
         if mesh is not None:
-            # the step runs mirror-compute over the mesh: all state rides
-            # replicated, committed ONCE here — never again per step
+            # the step runs mirror-compute over the mesh: weights and state
+            # ride replicated, committed ONCE here — never again per step
+            # (weights left on one device would be broadcast every step)
             sh = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
-            self._state = jax.tree.map(lambda x: jax.device_put(x, sh), self._state)
+            self.params = jax.device_put(self.params, sh)
+            self._state = jax.device_put(self._state, sh)
         self._admit_key = jax.random.PRNGKey(sample_seed + 1)
         self.active: dict[int, Request] = {}  # slot -> request
         self.row_pos = np.zeros((slots,), np.int32)  # host mirror (bookkeeping)

@@ -163,9 +163,8 @@ def make_sharded_decode_step(cfg, plan: "ServePlan", *, tp_axis: str = "model"):
         stacked = stack_fresh_rows(cfg, caches, pos)
         if stacked is None:  # recurrent-only arch: nothing to cohere
             return logits, caches, ()
-        from ..compat import axis_size
 
-        n = axis_size(tp_axis)
+        n = jax.lax.axis_size(tp_axis)
         r = jax.lax.axis_index(tp_axis)
         n_stages, full = stacked.shape
         width = -(-full // n)  # ceil: every rank ships an equal slice
@@ -200,7 +199,6 @@ def sharded_decode_core(cfg, plan: "ServePlan", mesh, *, tp_axis: str = "model")
     ``len(plan.schedule.groups)`` collective ops — pinned by the engine
     lowering test.
     """
-    from ..compat import shard_map
 
     P = jax.sharding.PartitionSpec
     step = make_sharded_decode_step(cfg, plan, tp_axis=tp_axis)
@@ -208,7 +206,7 @@ def sharded_decode_core(cfg, plan: "ServePlan", mesh, *, tp_axis: str = "model")
     if not _attn_sublayers(cfg):
         n_wire = 0
     out_specs = (P(), P(), tuple(P(tp_axis) for _ in range(n_wire)))
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh, in_specs=(P(), P(), P(), P()),
         out_specs=out_specs, axis_names={tp_axis}, check_vma=False,
     )

@@ -73,16 +73,19 @@ class TestPackUnpack:
 
     @staticmethod
     def _setup():
-        # odd sizes on purpose: 15, 7, 12, 1, 11 — tails never tile-align
-        return _parts()
+        # 3072 and 2048 start on HBM tiles (the kernel moves them); the
+        # odd 7, 1, 11 behind them go through the oracle's encode/decode
+        return _parts(shapes=((3, 1024), (2, 2, 512), (7,), (1,), (11,)))
 
     @pytest.mark.parametrize("ef", [False, True])
     def test_multi_chunk_pipeline_matches_oracle(self, ef):
         """Shrinking ``chunk`` below the part sizes forces the
-        double-buffered DMA pipeline (warm-up + cross-chunk slot reuse,
-        odd tails) — results must stay bit-identical to the oracle."""
+        double-buffered DMA pipeline (warm-up + cross-chunk slot reuse, a
+        last chunk pulled back onto its predecessor, ragged tails, parts
+        at mid-tile offsets) — results must stay bit-identical to the
+        oracle."""
         parts, offsets, sizes, total = _parts(
-            seed=3, shapes=((40, 25), (37,), (250, 10), (1,), (1001,))
+            seed=3, shapes=((5, 1024), (2500,), (37,), (3, 1024), (1,), (1001,))
         )
         rng = np.random.default_rng(4)
         res = (
@@ -93,10 +96,11 @@ class TestPackUnpack:
         a_ref, r_ref = pack_arena(
             parts, offsets, total, jnp.bfloat16, residuals=res, use_pallas=False
         )
-        # chunk=256: parts span 4, 1, 10, 1, 4 chunks with ragged tails
+        # chunk=2048: the 5120-part takes chunks at 0, 2048 and 3072; the
+        # 2500-part one chunk and a 452 tail; the rest start mid-tile
         a_pal, r_pal = pack_arena(
             parts, offsets, total, jnp.bfloat16, residuals=res,
-            interpret=True, chunk=256,
+            interpret=True, chunk=2048,
         )
         np.testing.assert_array_equal(
             np.asarray(a_ref, np.float32), np.asarray(a_pal, np.float32)
@@ -109,7 +113,7 @@ class TestPackUnpack:
         dts = [p.dtype for p in parts]
         o_ref = unpack_arena(a_ref, slots, shapes, dts, scale=0.5, use_pallas=False)
         o_pal = unpack_arena(a_pal, slots, shapes, dts, scale=0.5,
-                             interpret=True, chunk=256)
+                             interpret=True, chunk=2048)
         for r, p in zip(o_ref, o_pal):
             np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
 
@@ -219,7 +223,7 @@ ARENA_LOWERING_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core import (
         AllReduceModel, SyncConfig, count_expected_allreduces,
         make_gradient_sync, parse_collectives, stacked_lm_layout,
@@ -255,7 +259,7 @@ ARENA_LOWERING_SCRIPT = textwrap.dedent("""
                     r = jax.lax.axis_index("data").astype(jnp.float32)
                     return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
 
-                f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                                       axis_names={"data"}, check_vma=False))
                 stats = parse_collectives(f.lower(grads).as_text())
                 got = f(grads)
@@ -281,7 +285,7 @@ ARENA_LOWERING_SCRIPT = textwrap.dedent("""
     def body_ef(g, r):
         return sync(g, r)
 
-    f = jax.jit(shard_map(body_ef, mesh=mesh, in_specs=(P(), P()),
+    f = jax.jit(jax.shard_map(body_ef, mesh=mesh, in_specs=(P(), P()),
                           out_specs=(P(), P()), axis_names={"data"}, check_vma=False))
     stats = parse_collectives(f.lower(grads, res0).as_text())
     o, r1 = f(grads, res0)
@@ -358,7 +362,7 @@ class TestMeasuredComm:
             fit_affine([100], [1e-6])
 
     def test_live_sweep_on_host_mesh(self):
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         mesh = make_mesh((1,), ("data",))
         m = MeasuredComm.time_psums(
@@ -420,14 +424,11 @@ class TestPlanAwareCheckpoint:
 
 class TestCompatProbe:
     def test_variadic_probe_cached_and_consistent(self):
-        from repro.compat import variadic_psum_is_single_op
+        from repro.core.sync import variadic_psum_is_single_op
 
         first = variadic_psum_is_single_op()
         assert variadic_psum_is_single_op() is first  # functools.cache
         assert variadic_psum_is_single_op.cache_info().hits >= 1
-        # on this container's jax (0.4.x) the version gate answers False
-        # without lowering; on modern jax the probe must agree with the
-        # shard_map feature boundary either way
         assert isinstance(first, bool)
 
     def test_sync_rejects_bad_modes(self):

@@ -25,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.comm_model import AllReduceModel
     from repro.core.sync import SyncConfig
@@ -59,7 +59,7 @@ SCRIPT = textwrap.dedent("""
         step = eng.make_train_step(opt, mesh, lr=1e-2, issue=issue)
         # the step donates params/opt_state: hand it fresh copies
         p0 = jax.tree.map(jnp.array, params)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = step.lower(p0, opt.init(p0), batch)
             compiled = lowered.compile()
             out[f"n_allreduce_{issue}"] = len(

@@ -26,7 +26,7 @@ PUBLIC_MODULES = (
 #: links into these as subsystem entry points).
 DOCUMENTED_MODULES = PUBLIC_MODULES + (
     "repro",
-    "repro.compat",
+    "repro.launch.compile_cache",
     "repro.planning.serve",
     "repro.planning.tuner",
     "repro.fabric.measured",

@@ -335,7 +335,7 @@ SERVE_LOWERING_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.core.profiler import parse_collectives
     from repro.launch.specs import param_specs
@@ -354,7 +354,7 @@ SERVE_LOWERING_SCRIPT = textwrap.dedent("""
         gather = make_group_collective(plan)
         stacked = jnp.ones((cfg.n_stages, 16, 64), jnp.float32)
 
-        f = shard_map(gather, mesh=mesh, in_specs=(P(),),
+        f = jax.shard_map(gather, mesh=mesh, in_specs=(P(),),
                       out_specs=[P(None, "model") for _ in plan.schedule.groups],
                       axis_names={"model"}, check_vma=False)
         stats = parse_collectives(jax.jit(f).lower(stacked).as_text())

@@ -52,7 +52,7 @@ COMPRESSION_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.runtime.compression import compressed_psum_rs_ag
 
     mesh = make_mesh((8,), ("dp",))
@@ -60,7 +60,7 @@ COMPRESSION_SCRIPT = textwrap.dedent("""
     def body(g, res):
         return compressed_psum_rs_ag(g, "dp", res)
 
-    f = jax.jit(shard_map(body, mesh=mesh, axis_names={"dp"},
+    f = jax.jit(jax.shard_map(body, mesh=mesh, axis_names={"dp"},
                  in_specs=(P("dp"), P("dp")), out_specs=(P("dp"), P("dp")),
                  check_vma=False))
 
@@ -68,7 +68,7 @@ COMPRESSION_SCRIPT = textwrap.dedent("""
     # per-device distinct gradients: (8, n) rows = one per device
     g = jax.random.normal(key, (8, 1024), jnp.float32)
     res = jnp.zeros_like(g)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, new_res = f(g, res)
     exact = jnp.sum(g, axis=0)
     out_rows = np.asarray(out)
@@ -79,7 +79,7 @@ COMPRESSION_SCRIPT = textwrap.dedent("""
     res_norm = float(np.max(np.abs(np.asarray(new_res))))
 
     # second round with error feedback reduces accumulated bias:
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out2, res2 = f(g, new_res)
     two_step = np.asarray(out) + np.asarray(out2)
     exact2 = 2 * np.asarray(exact)

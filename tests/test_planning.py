@@ -310,7 +310,7 @@ SYNC_LOWERING_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core import (
         AllReduceModel, SyncConfig, count_expected_allreduces,
         make_gradient_sync, stacked_lm_layout,
@@ -356,7 +356,7 @@ SYNC_LOWERING_SCRIPT = textwrap.dedent("""
                 scaled = jax.tree.map(lambda x: x * (r + 1.0), g)
                 return sync(scaled)
 
-            f = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+            f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                           axis_names={"data"}, check_vma=False)
             lowered = jax.jit(f).lower(grads)
             n_ar = len(re.findall(r"stablehlo\\.all_reduce", lowered.as_text()))

@@ -17,7 +17,7 @@ import pytest
 
 from _env import REPO_ROOT, SUBPROC_ENV
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_reduced
 from repro.core.comm_model import AllReduceModel, fit_affine
 from repro.fabric import MeasuredFabric
@@ -177,7 +177,7 @@ SHARDED_EXEC_SCRIPT = textwrap.dedent("""
     import dataclasses, json
     import jax, jax.numpy as jnp, numpy as np
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.profiler import parse_collectives
     from repro.launch.specs import param_specs
@@ -224,6 +224,12 @@ SHARDED_EXEC_SCRIPT = textwrap.dedent("""
         toks, eng, plan = run(mesh, policy, fabric)
         text = eng._step_fn.lower(eng.params, eng._state).as_text()
         stats = parse_collectives(text)
+        try:  # a decode step moves no weights or state between devices
+            with jax.transfer_guard("disallow"):
+                eng._step_fn(eng.params, eng._state)
+            no_transfer = True
+        except Exception as e:
+            no_transfer = repr(e)[:300]
         out["cells"].append({
             "policy": policy, "fabric": fabric, "op": plan.op,
             "n_groups": len(plan.schedule.groups),
@@ -232,6 +238,7 @@ SHARDED_EXEC_SCRIPT = textwrap.dedent("""
             "tokens_match": toks == base,
             "donated": donated(text),
             "decode_execs": eng.compile_stats()["decode"],
+            "no_transfer": no_transfer,
         })
 
     # MoE: the plan schedules the expert all-to-all; same invariant
@@ -270,8 +277,9 @@ def test_engine_step_lowers_one_collective_per_group():
     its ``DecodeState`` buffers (``tf.aliasing_output``/``jax.buffer_donor``
     in the lowered text — the cache arena is updated in place), compiles
     exactly one
-    decode executable across joins/leaves/slot reuse, and the sharded
-    engine decodes token-for-token what the unsharded engine decodes."""
+    decode executable across joins/leaves/slot reuse, moves no weights or
+    state between devices in a step, and the sharded engine decodes
+    token-for-token what the unsharded engine decodes."""
     out = subprocess.run(
         [sys.executable, "-c", SHARDED_EXEC_SCRIPT],
         capture_output=True, text=True, timeout=900,
@@ -289,6 +297,7 @@ def test_engine_step_lowers_one_collective_per_group():
         assert c["tokens_match"], c
         assert c["donated"], c  # the DecodeState buffers alias outputs
         assert c["decode_execs"] == 1, c  # zero steady-state retraces
+        assert c["no_transfer"] is True, c  # weights placed once, not per step
     moe = rec["moe"]
     assert moe["op"] == "all_to_all"
     assert moe["a2a_ops"] == moe["n_groups"]

@@ -20,7 +20,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.comm_model import AllReduceModel
     from repro.core.trainer import MGWFBPEngine, lm_unit_costs
@@ -63,7 +63,7 @@ SCRIPT = textwrap.dedent("""
     ref_params, _ = sgd_update(g_ref, sgd_init(params, 0.9), params, 1e-2, 0.9)
     ref_params = jax.tree.map(np.asarray, ref_params)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = step.lower(params, opt_state, batch)
         compiled = lowered.compile()
         hlo = compiled.as_text()

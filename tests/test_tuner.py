@@ -348,7 +348,7 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_reduced
 from repro.core.comm_model import AllReduceModel
 from repro.core.sync import SyncConfig
@@ -382,7 +382,7 @@ batch = {
     "targets": jax.random.randint(key, (4, 32), 0, cfg.vocab),
     "tokens": jax.random.randint(key, (4, 32), 0, cfg.vocab),
 }
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     p1, o1, r1, m1 = step(params, opt_state, residual, batch)
     p2, o2, r2, m2 = step(p1, o1, r1, batch)
 res_norm = float(sum(jnp.sum(jnp.abs(x)) for x in jax.tree.leaves(r2)))
