@@ -618,22 +618,34 @@ def overlap() -> list[str]:
     """Measured DAG-overlap acceptance -> ``BENCH_overlap.json``.
 
     Runs the same reduced arch through both communication issue orders —
-    ``post`` (every merged all-reduce after the whole backward) and
+    ``post`` (every merged all-reduce issued after the whole backward) and
     ``dag`` (each group's all-reduce at its last-gradient event inside
-    backward) — under the span recorder, and prices the contrast from
-    the PARSED TRACE, not the timeline model:
+    backward) — one steady step of each traced with ``jax.profiler``, and
+    prices the contrast from the ``bwd_*`` scopes and the ``wfbp_group*``
+    all-reduces of that DEVICE TRACE (``profiler.scope_spans``), not the
+    timeline model:
 
-      * ``overlap_fraction`` (comm inside the backward window) must be
-        > 0 for dag and 0 for post — re-asserted by the
-        ``overlap-smoke`` CI job and the baseline gate;
-      * every comm span must carry its group's exact wire bytes;
-      * the dag step must still lower to ONE all-reduce per schedule
-        group (small slack for the loss pmean etc.);
+      * ``overlap_fraction`` (comm inside the backward window) and the
+        overlapped starts must be > 0 for dag, and post must start fewer
+        all-reduces inside backward than dag — re-asserted by the
+        ``overlap-smoke`` CI job and the baseline gate.  Post is not
+        zero on XLA:CPU, which runs each op once its operands are ready:
+        the head's group is ready before the scan's backward ends.  The
+        fractions are not compared: on XLA:CPU a device's all-reduce
+        span includes its wait for the slowest device, so post's early
+        group reads anywhere from 0.2 to 0.7 between runs;
+      * every group has one all-reduce span on every device, carrying
+        its group's exact wire bytes;
+      * both steps must compile to ONE all-reduce per schedule group
+        (small slack for the loss pmean etc.): the train step keeps
+        XLA's all-reduce combiner from merging them;
       * dag and post losses must agree bit-exactly — reordering the
         issue points must not change the arithmetic.
     """
     import dataclasses as _dc
+    import glob as _glob
     import re as _re
+    import tempfile as _tempfile
 
     import jax
     import jax.numpy as jnp
@@ -641,7 +653,7 @@ def overlap() -> list[str]:
     from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.comm_model import AllReduceModel
-    from repro.core.profiler import TraceRecorder, overlap_report
+    from repro.core.profiler import GROUP_SPAN_RE, overlap_report, scope_spans
     from repro.core.sync import SyncConfig
     from repro.core.trainer import MGWFBPEngine
     from repro.launch.specs import param_specs
@@ -678,26 +690,34 @@ def overlap() -> list[str]:
     }
     reports = {}
     for issue in ("post", "dag"):
-        rec = TraceRecorder()
-        step = eng.make_train_step(opt, mesh, lr=1e-2, issue=issue, recorder=rec)
-
-        def call(step=step):  # the step donates params/opt_state buffers
-            p0 = jax.tree.map(jnp.array, params)
-            return step(p0, opt.init(p0), batch)
-
+        step = eng.make_train_step(opt, mesh, lr=1e-2, issue=issue)
         with jax.set_mesh(mesh):
-            hlo = step.lower(params, opt.init(params), batch).compile().as_text()
-            n_ar = len(_re.findall(r" all-reduce\(", hlo))
-            # steady-state trace: drop the compile step's spans
+            compiled = step.lower(params, opt.init(params), batch).compile()
+        hlo = compiled.as_text()
+        n_ar = len(_re.findall(r" all-reduce\(", hlo))
+
+        def call(compiled=compiled):  # the step donates params/opt_state buffers
+            p0 = jax.tree.map(jnp.array, params)
+            with jax.set_mesh(mesh):
+                return compiled(p0, opt.init(p0), batch)
+
+        # steady-state trace: the first run stays out of it
+        jax.block_until_ready(call())
+        with _tempfile.TemporaryDirectory() as tdir:
+            jax.profiler.start_trace(tdir)
             p, o, m = call()
             jax.block_until_ready(p)
-            jax.effects_barrier()
-            rec.clear()
-            p, o, m = call()
-            jax.block_until_ready(p)
-        jax.effects_barrier()
-        rep = overlap_report(rec.spans())
+            jax.profiler.stop_trace()
+            (xplane,) = _glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
+            spans = scope_spans(xplane, hlo, eng.sync.group_wire_bytes)
+        rep = overlap_report(spans)
         reports[issue] = rep
+        covered: dict[int, set] = {}  # group -> devices with its all-reduce span
+        for s in spans:
+            g = GROUP_SPAN_RE.match(s.name)
+            if g:
+                covered.setdefault(int(g.group(1)), set()).add(s.device)
+        assert covered == {gi: set(range(n_dev)) for gi in range(n_groups)}, (issue, covered)
         record[issue] = {
             "loss": float(m["loss"]),
             "allreduce_ops": n_ar,
@@ -718,8 +738,7 @@ def overlap() -> list[str]:
     # trace-proved acceptance: the wire moved inside backward under dag
     assert record["dag"]["overlap_fraction"] > 0.0, record["dag"]
     assert record["dag"]["n_overlapped_starts"] > 0, record["dag"]
-    assert record["post"]["n_overlapped_starts"] == 0, record["post"]
-    assert record["dag"]["overlap_fraction"] > record["post"]["overlap_fraction"]
+    assert record["post"]["n_overlapped_starts"] < record["dag"]["n_overlapped_starts"]
     # one merged all-reduce per group (slack: loss pmean & friends)
     for issue in ("post", "dag"):
         assert n_groups <= record[issue]["allreduce_ops"] <= n_groups + 4, record
@@ -739,7 +758,7 @@ def overlap() -> list[str]:
 
     def gate(rec, base):
         assert rec["dag"]["overlap_fraction"] > 0.0
-        assert rec["post"]["n_overlapped_starts"] == 0
+        assert rec["dag"]["n_overlapped_starts"] > rec["post"]["n_overlapped_starts"]
         assert rec["loss_bit_identical"]
 
     write_bench("overlap", record, rows, gate=gate)

@@ -21,19 +21,21 @@ exists before compilation.
 
 Trace-first overlap verification (the DAG-step proof obligation)
 ----------------------------------------------------------------
-The JAX CPU profiler emits no named-scope / op-level spans, so overlap
-cannot be read off ``jax.profiler`` output here.  Instead the executed
-step self-records: :class:`TraceRecorder` plants host-callback markers
-whose *data dependencies* pin them to the events they time — a span
-begin consumes the group's packed gradient (fires when the gradient is
-ready), a span end consumes the all-reduce output (fires at completion).
-Recordings serialize to Chrome-trace JSON (``ph: "X"`` complete events,
-``pid`` = device), and one parser — :func:`parse_trace_spans` — reads
-recorded traces, committed fixtures under ``tests/data/``, and real
-``trace.json.gz`` files alike.  :func:`overlap_report` then computes the
-measured overlap fraction (comm time hidden under backward / total comm
-time) and the structural DAG property: a non-final ``wfbp_group*`` span
-starting before the last backward span ends.
+The train step names its layers with ``jax.named_scope`` (the names of
+``repro.scopes``: ``fwd_*``, ``bwd_*``, ``optimizer``, ``attention``, and
+the sync engine's ``wfbp_group*``).  XLA keeps each scope path in the
+compiled module's ``op_name`` metadata, so :func:`scope_spans` reads a
+``jax.profiler`` trace, joins each executed op to its scope by
+instruction name, and emits one :class:`Span` per ``bwd_*`` scope and
+one per ``wfbp_group*`` scope's all-reduce, per device and step, on the
+device's own clock.
+:func:`overlap_report` then computes the measured overlap fraction (comm
+time inside the backward window / total comm time) and the structural
+DAG property: a non-final ``wfbp_group*`` span starting before the last
+backward span ends.  :func:`parse_trace_spans` reads Chrome-trace JSON
+(the committed fixture under ``tests/data/``) into the same spans.
+:func:`scope_layers` reads the same trace by layer: device time per step
+of the wire, remat recompute, backward, forward, optimizer and attention.
 """
 
 from __future__ import annotations
@@ -43,8 +45,9 @@ import gzip
 import json
 import pathlib
 import re
-import threading
 import time
+
+from .. import scopes
 
 _DTYPE_BYTES = {
     "pred": 1,
@@ -293,9 +296,8 @@ def time_segment(fn, *args, warmup: int = 1, repeats: int = 3, clock=None) -> fl
 
 
 # ---------------------------------------------------------------------------
-# Self-recorded execution traces (the DAG-step overlap proof)
+# Named scopes of the train step, and the spans read back from a trace
 # ---------------------------------------------------------------------------
-
 
 @dataclasses.dataclass(frozen=True)
 class Span:
@@ -315,131 +317,306 @@ class Span:
 #: ``wfbp_group{gi}_l{lo}_{hi}`` — the sync engine's per-group scope name.
 GROUP_SPAN_RE = re.compile(r"^wfbp_group(\d+)_l(\d+)_(\d+)$")
 
-#: Backward-compute scopes the DAG step records (``bwd_<event>``).
-BWD_SPAN_PREFIX = "bwd_"
+#: Backward-compute scopes of the train step (``bwd_<event>``).
+BWD_SPAN_PREFIX = scopes.BWD_PREFIX
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+#: An all-reduce instruction's line, or a part of an asynchronous one.
+_ALLREDUCE_OP = re.compile(r"\sall-reduce(-start|-done)?\(")
+_TRANSPOSED_FWD = re.compile(r"^transpose\(jvp\(fwd_(\w+)\)\)$")
+_DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:(\d+)$")
 
 
-class TraceRecorder:
-    """Host-callback span recorder for jitted steps.
+def hlo_op_names(hlo_text: str) -> dict[str, str]:
+    """Instruction name -> ``op_name`` scope path, from a compiled
+    module's text (``compiled.as_text()``).  An instruction without one
+    takes that of the computation it calls (a fusion), else that of its
+    first operand that has one, in turn: XLA's copies, prefetches and
+    layout changes move a value that a scoped instruction made."""
+    names: dict[str, str] = {}
+    first_in: dict[str, str] = {}  # computation -> its first op_name
+    calls: dict[str, str] = {}  # instruction without op_name -> computation
+    operands: dict[str, list[str]] = {}  # instruction without op_name -> operands
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        n = _OP_NAME.search(line)
+        if n:
+            names[m.group(1)] = n.group(1)
+            if comp is not None:
+                first_in.setdefault(comp, n.group(1))
+            continue
+        c = _CALLS.search(line)
+        if c:
+            calls[m.group(1)] = c.group(1)
+        rhs = line.split("=", 1)[1]
+        operands[m.group(1)] = _OPERAND.findall(rhs[rhs.find("("):])
+    for inst, c in calls.items():
+        if c in first_in:
+            names[inst] = first_in[c]
 
-    The pattern: ``span_begin`` plants a ``jax.debug.callback`` whose
-    operand is (a cheap scalar of) the value that *becomes ready* when
-    the span starts — the runtime cannot fire the callback before its
-    operand exists, so the host timestamp is a true not-before bound.
-    ``span_end`` does the same with the value the span *produces*.  The
-    pair is matched by name per device.  Timestamps are
-    ``time.perf_counter_ns`` on the host (injectable for tests).
+    def resolve(inst: str) -> str | None:  # depth first, first operand first
+        stack, seen = [inst], set()
+        while stack:
+            x = stack.pop()
+            if x in names:
+                return names[x]
+            if x not in seen:
+                seen.add(x)
+                stack.extend(reversed(operands.get(x, ())))
+        return None
 
-    Under ``shard_map`` each device shard fires its own callback; pass
-    ``device=jax.lax.axis_index(...)`` so spans attribute per device.
-    Appends are lock-guarded — the CPU runtime may fire callbacks from
-    several device threads.
-    """
-
-    def __init__(self, clock_ns=None):
-        self._clock_ns = clock_ns or time.perf_counter_ns
-        self._lock = threading.Lock()
-        self._events: list[tuple[str, str, int, int, int]] = []  # name, ph, dev, t_ns, nbytes
-
-    # -- recording (called from inside traced code) -------------------------
-
-    def _mark(self, name: str, ph: str, nbytes: int, device) -> None:
-        t = int(self._clock_ns())
-        with self._lock:
-            self._events.append((name, ph, int(device), t, int(nbytes)))
-
-    def span_begin(self, name: str, dep, *, device=0, nbytes: int = 0):
-        """Record the start of ``name`` when ``dep`` becomes ready.
-
-        ``dep`` must be (or contain) the value whose readiness defines
-        the span start — e.g. the packed gradient arena right before its
-        ``psum``.  Returns ``dep`` unchanged for ergonomic chaining."""
-        import jax
-
-        jax.debug.callback(
-            lambda d, _x: self._mark(name, "B", nbytes, d), device, _cheap_dep(dep)
-        )
-        return dep
-
-    def span_end(self, name: str, val, *, device=0, nbytes: int = 0):
-        """Record the end of ``name`` when ``val`` becomes ready."""
-        import jax
-
-        jax.debug.callback(
-            lambda d, _x: self._mark(name, "E", nbytes, d), device, _cheap_dep(val)
-        )
-        return val
-
-    # -- reading back --------------------------------------------------------
-
-    def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._events)
-
-    def spans(self) -> list[Span]:
-        """Pair B/E markers into spans (per name × device, FIFO order)."""
-        with self._lock:
-            events = list(self._events)
-        open_: dict[tuple[str, int], list[tuple[int, int]]] = {}
-        out: list[Span] = []
-        for name, ph, dev, t_ns, nbytes in sorted(events, key=lambda e: e[3]):
-            key = (name, dev)
-            if ph == "B":
-                open_.setdefault(key, []).append((t_ns, nbytes))
-            else:
-                if not open_.get(key):
-                    continue  # unmatched end (cleared mid-step)
-                t0, b0 = open_[key].pop(0)
-                args = {"bytes": max(b0, nbytes)} if (b0 or nbytes) else {}
-                out.append(
-                    Span(name=name, device=dev, start_us=t0 / 1e3,
-                         dur_us=max(0.0, (t_ns - t0) / 1e3), args=args)
-                )
-        out.sort(key=lambda s: (s.device, s.start_us))
-        return out
-
-    def to_chrome_trace(self) -> dict:
-        """Chrome-trace dict: one ``ph: "X"`` complete event per span,
-        ``pid`` = device — the same shape real ``trace.json`` files use,
-        so one parser serves recordings, fixtures, and live profiles."""
-        return {
-            "displayTimeUnit": "ns",
-            "traceEvents": [
-                {
-                    "name": s.name, "ph": "X", "pid": s.device, "tid": 0,
-                    "ts": s.start_us, "dur": s.dur_us, "args": s.args,
-                }
-                for s in self.spans()
-            ],
-        }
-
-    def save(self, path) -> None:
-        """Write the Chrome trace to ``path`` (gzipped iff it ends .gz)."""
-        data = json.dumps(self.to_chrome_trace(), indent=1, sort_keys=True)
-        if str(path).endswith(".gz"):
-            with gzip.open(path, "wt") as f:
-                f.write(data)
-        else:
-            with open(path, "w") as f:
-                f.write(data)
+    for inst in operands:
+        if inst not in names and (path := resolve(inst)) is not None:
+            names[inst] = path
+    return names
 
 
-def _cheap_dep(x):
-    """A scalar that depends on ``x`` without materializing it host-side —
-    callbacks transfer their operands, so ship 1 element, not the arena.
-    Pytrees (the variadic wire path) resolve to their first leaf."""
+def hlo_allreduces(hlo_text: str) -> set[str]:
+    """Names of the all-reduce instructions (and the start / done halves
+    of asynchronous ones) of a compiled module's text."""
+    out = set()
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and _ALLREDUCE_OP.search(line):
+            out.add(m.group(1))
+    return out
+
+
+def span_scope(op_name: str, allreduce: bool = False) -> str | None:
+    """The overlap report's span an op belongs to: for an all-reduce its
+    ``wfbp_group*`` scope; otherwise its ``bwd_*`` scope, or ``bwd_<x>``
+    for the transposed forward of ``fwd_<x>`` (the ``post`` step's
+    backward).  None for anything else, such as a group's pack and
+    unpack: a comm span is the group's time on the wire alone."""
+    parts = op_name.split("/")
+    if allreduce:
+        return next((c for c in parts if GROUP_SPAN_RE.match(c)), None)
+    if any(GROUP_SPAN_RE.match(c) for c in parts):
+        return None
+    for c in parts:
+        if c.startswith(BWD_SPAN_PREFIX):
+            return c
+    for c in parts:
+        m = _TRANSPOSED_FWD.match(c)
+        if m:
+            return BWD_SPAN_PREFIX + m.group(1)
+    return None
+
+
+def _stats(ev) -> dict:
+    try:
+        return dict(ev.stats)
+    except (AttributeError, TypeError):
+        return {}
+
+
+def _leaves(events):
+    """The events of one trace line, ``(start, end, ...)``, that hold no
+    other: a loop's or a call's event is dropped where its body's events
+    are listed too, so no time is counted twice."""
+    events = sorted(events, key=lambda ev: (ev[0], -ev[1]))
+    holds = [False] * len(events)
+    stack: list[int] = []
+    for i, (start, end, *_) in enumerate(events):
+        while stack and events[stack[-1]][1] <= start:
+            stack.pop()
+        if stack and events[stack[-1]][1] >= end > start:
+            holds[stack[-1]] = True
+        stack.append(i)
+    return [ev for ev, h in zip(events, holds) if not h]
+
+
+def trace_ops(xplane_path, module: str) -> list[tuple[int, int, int, int, str]]:
+    """``(device, run, start_ns, end_ns, instruction)`` of every executed
+    op of the HLO module ``module`` in a ``jax.profiler`` trace, without
+    the loops and calls whose bodies' ops are listed (:func:`_leaves`).
+
+    On a TPU each chip's ``XLA Ops`` line names an op by its HLO text
+    (``%fusion.12 = ...``) and the ``XLA Modules`` line gives each
+    execution of a module; on the CPU each op is a host event with
+    ``hlo_op``, ``hlo_module``, ``run_id`` and ``device_ordinal`` stats.
+    ``run`` numbers the module's executions in the trace from 0."""
+    import bisect
+
     import jax
-    import jax.numpy as jnp
 
-    leaves = jax.tree.leaves(x)
-    x0 = leaves[0] if leaves else 0.0
-    if hasattr(x0, "ravel") and getattr(x0, "ndim", 0) > 0:
-        return x0.ravel()[0]
-    return jnp.asarray(x0)
+    pd = jax.profiler.ProfileData.from_file(str(xplane_path))
+    raw: list[tuple[int, int, int, object, str]] = []  # start, end, device, run, op
+    for plane in pd.planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m:
+            lines = {ln.name: list(ln.events) for ln in plane.lines}
+            runs = sorted(e.start_ns for e in lines.get("XLA Modules", ())
+                          if e.name.startswith(module))
+            raw += _leaves(
+                (ev.start_ns, ev.start_ns + ev.duration_ns, int(m.group(1)),
+                 max(0, bisect.bisect_right(runs, ev.start_ns) - 1),
+                 ev.name.split(" = ", 1)[0].strip().lstrip("%"))
+                for ev in lines.get("XLA Ops", ()))
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                line_ops = []
+                for ev in line.events:
+                    st = _stats(ev)
+                    if "hlo_op" in st and str(st.get("hlo_module", module)) == module:
+                        line_ops.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                         int(st.get("device_ordinal", 0)),
+                                         int(st.get("run_id", 0)), str(st["hlo_op"])))
+                raw += _leaves(line_ops)
+    order = {r: i for i, r in enumerate(sorted({ev[3] for ev in raw}))}
+    return [(d, order[r], s, e, n) for s, e, d, r, n in raw]
+
+
+def spans_from_ops(ops, hlo_text: str, group_bytes=()) -> list[Span]:
+    """The overlap report's spans from executed ops ``(device, run,
+    start_ns, end_ns, instruction)`` (:func:`trace_ops`) of the compiled
+    module ``hlo_text``: each op is joined by instruction name to its
+    ``op_name`` scope path (:func:`hlo_op_names`).  For each device and
+    each run there is one :class:`Span` per ``bwd_*`` scope and per
+    ``wfbp_group*`` scope (:func:`span_scope`: a group's span covers its
+    all-reduce ops only), from the scope's first op to its last;
+    ``args["step"]`` numbers the runs.  ``group_bytes``
+    (``sync.group_wire_bytes``) sets each group span's ``args["bytes"]``.
+    """
+    names = hlo_op_names(hlo_text)
+    allreduces = hlo_allreduces(hlo_text)
+    bounds: dict[tuple[int, int, str], list[int]] = {}
+    for dev, run, start, end, op in ops:
+        scope = span_scope(names.get(op, ""), op in allreduces)
+        if scope is None:
+            continue
+        b = bounds.setdefault((dev, run, scope), [start, end])
+        b[0], b[1] = min(b[0], start), max(b[1], end)
+    spans = []
+    for (dev, run, scope), (start, end) in bounds.items():
+        args = {"step": run}
+        g = GROUP_SPAN_RE.match(scope)
+        if g and int(g.group(1)) < len(group_bytes):
+            args["bytes"] = int(group_bytes[int(g.group(1))])
+        spans.append(Span(name=scope, device=dev, start_us=start / 1e3,
+                          dur_us=(end - start) / 1e3, args=args))
+    spans.sort(key=lambda s: (s.device, s.start_us))
+    return spans
+
+
+def scope_spans(xplane_path, hlo_text: str, group_bytes=()) -> list[Span]:
+    """The overlap report's spans, read from a ``jax.profiler`` trace of
+    the step whose compiled module is ``hlo_text``
+    (``compiled.as_text()``): :func:`spans_from_ops` of its
+    :func:`trace_ops`."""
+    return spans_from_ops(_module_ops(xplane_path, hlo_text), hlo_text, group_bytes)
+
+
+def _module_ops(xplane_path, hlo_text: str):
+    m = _HLO_MODULE.search(hlo_text)
+    return trace_ops(xplane_path, m.group(1) if m else "")
+
+
+#: The train step's layers, in the order :func:`op_layer` tries them.
+LAYERS = ("wire", "remat", "backward", "forward", "optimizer", "unscoped")
+_WRAPPED = re.compile(r"^[\w.\-]+\((.*)\)$")
+
+
+def _core(component: str) -> str:
+    """A path component without its transformation wrappers:
+    ``transpose(jvp(attention))`` -> ``attention``."""
+    while (m := _WRAPPED.match(component)) is not None:
+        component = m.group(1)
+    return component
+
+
+def op_layer(op_name: str, allreduce: bool = False) -> str:
+    """The train-step layer of an op whose scope path is ``op_name``;
+    the first rule that matches wins:
+
+    1. ``wire``: an all-reduce, or an op under a ``wfbp_group*`` scope
+       (a group's pack, all-reduce, unpack and the slicing around them);
+    2. ``remat``: a component ``rematted_computation``, the
+       ``jax.checkpoint`` recompute inside a pullback;
+    3. ``backward``: a component ``bwd_*`` or ``transpose(...)``;
+    4. ``forward``: a component ``fwd_*`` or one that wraps it
+       (``jvp(fwd_model)``);
+    5. ``optimizer``: a component ``optimizer``;
+    6. ``unscoped``: anything else.
+
+    XLA's memory rematerialization clones an instruction under a name
+    ending in ``.remat`` with its original's ``op_name``: the clone counts
+    in its original's layer."""
+    parts = op_name.split("/") if op_name else []
+    if allreduce or any(GROUP_SPAN_RE.match(c) for c in parts):
+        return "wire"
+    if "rematted_computation" in parts:
+        return "remat"
+    if any(c.startswith((BWD_SPAN_PREFIX, "transpose(")) for c in parts):
+        return "backward"
+    cores = [_core(c) for c in parts]
+    if any(c.startswith(scopes.FWD_PREFIX) for c in cores):
+        return "forward"
+    if scopes.OPTIMIZER in cores:
+        return "optimizer"
+    return "unscoped"
+
+
+def is_attention(op_name: str) -> bool:
+    """An op of attention's core: a component ``attention`` or one that
+    wraps it (``jvp(attention)``, ``transpose(jvp(attention))``)."""
+    return any(_core(c) == scopes.ATTENTION for c in op_name.split("/")) if op_name else False
+
+
+def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
+    """Device milliseconds per step of each of :data:`LAYERS`
+    (``<layer>_ms``), of attention (``attention_ms``) and of all busy time
+    (``busy_ms``), averaged over devices and steps, from executed ops
+    ``(device, run, start_ns, end_ns, instruction)`` (:func:`trace_ops`) of
+    the compiled module ``hlo_text``.  Where ops of several layers run at
+    once (XLA:CPU runs them side by side; a chip seldom does) the time
+    counts once, for the first of them in :data:`LAYERS`, so the layers add
+    up to the busy time.  Attention cuts across the layers: the union of
+    its ops' intervals.  None where no op carries a train-step scope."""
+    names = hlo_op_names(hlo_text)
+    allreduces = hlo_allreduces(hlo_text)
+    runs: dict[tuple[int, int], list[tuple[int, int, int, bool]]] = {}
+    for dev, run, start, end, op in ops:
+        path = names.get(op, "")
+        runs.setdefault((dev, run), []).append(
+            (start, end, LAYERS.index(op_layer(path, op in allreduces)), is_attention(path)))
+    total = [0.0] * len(LAYERS)
+    attention = 0.0
+    for tagged in runs.values():
+        edges = sorted([(s, 1, k) for s, e, k, _ in tagged if e > s]
+                       + [(e, -1, k) for s, e, k, _ in tagged if e > s])
+        active, prev = [0] * len(LAYERS), None
+        for t, d, k in edges:
+            if prev is not None and t > prev:
+                first = next((i for i, c in enumerate(active) if c), None)
+                if first is not None:
+                    total[first] += t - prev
+            active[k] += d
+            prev = t
+        attention += _union_len([(s, e) for s, e, _, a in tagged if a])
+    if not any(total[LAYERS.index(k)] for k in ("remat", "backward", "forward", "optimizer")):
+        return None
+    per = 1e-6 / len(runs)  # ns summed over (device, step) -> ms per step
+    out = {f"{k}_ms": v * per for k, v in zip(LAYERS, total)}
+    return out | {"attention_ms": attention * per, "busy_ms": sum(total) * per}
+
+
+def scope_layers(xplane_path, hlo_text: str) -> dict[str, float] | None:
+    """:func:`layer_split` of a ``jax.profiler`` trace of the step whose
+    compiled module is ``hlo_text`` (``compiled.as_text()``)."""
+    return layer_split(_module_ops(xplane_path, hlo_text), hlo_text)
 
 
 def parse_trace_spans(trace) -> list[Span]:

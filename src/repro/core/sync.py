@@ -69,6 +69,7 @@ import numpy as np
 
 # submodule imports (not the fabric package) — core and fabric import each
 # other's leaves, and the package __init__s would cycle
+from .. import scopes
 from ..fabric.model import Collective
 from ..fabric.ops import issue
 from ..kernels.comm_pack import pack_arena, unpack_arena
@@ -126,21 +127,11 @@ class SyncConfig:
         return self.comm_dtype
 
 
-def device_index(dp_axes: tuple[str, ...]):
-    """Flat device index over the (manual) DP axes — the trace recorder's
-    per-device span attribution key.  Must run inside shard_map."""
-    idx = 0
-    for ax in dp_axes:
-        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
-    return idx
-
-
 def make_gradient_sync(
     layout: ParamLayout,
     schedule: Schedule,
     dp_axes: tuple[str, ...],
     config: SyncConfig = SyncConfig(),
-    recorder=None,
 ) -> Callable[..., Pytree]:
     """Build ``sync_fn(grads) -> reduced_grads`` for use inside shard_map.
 
@@ -160,11 +151,8 @@ def make_gradient_sync(
     last gradient lands can place the all-reduce at exactly that event.
     ``sync(grads)`` is simply all groups in backward order.
 
-    ``recorder`` (a ``profiler.TraceRecorder``) plants data-dependent
-    span markers around each group's reduction: the begin marker consumes
-    the on-wire value (fires when the merged gradient is ready), the end
-    marker consumes the reduced result — the ``wfbp_group{gi}_l{lo}_{hi}``
-    spans the overlap report parses.
+    Each group runs under the named scope ``wfbp_group{gi}_l{lo}_{hi}``:
+    the spans ``profiler.scope_spans`` reads from a device trace.
     """
     if config.fuse not in ("concat", "variadic", "arena"):
         raise ValueError(f"unknown fuse mode {config.fuse!r}")
@@ -183,15 +171,6 @@ def make_gradient_sync(
         for units in reversed(bucket_assignment(layout, schedule))
     )
 
-    def _marked_issue(name: str, gi: int, val, dp_axes_):
-        """The group's psum, optionally bracketed by trace markers."""
-        if recorder is None:
-            return issue(Collective.ALL_REDUCE, val, dp_axes_)
-        dev = device_index(dp_axes_)
-        val = recorder.span_begin(name, val, device=dev, nbytes=group_wire_bytes[gi])
-        red = issue(Collective.ALL_REDUCE, val, dp_axes_)
-        return recorder.span_end(name, red, device=dev)
-
     def sync_group(gi: int, grads: Pytree, out: Pytree, residual: Pytree | None = None):
         """Reduce group ``gi`` (backward issue order) only."""
         entries = group_entries[gi]
@@ -199,13 +178,10 @@ def make_gradient_sync(
         world = 1.0
         for ax in dp_axes:
             world *= jax.lax.axis_size(ax)
-        name = f"wfbp_group{gi}_l{lo}_{hi}"
+        name = scopes.wfbp_group(gi, lo, hi)
         with jax.named_scope(name):
             if config.fuse == "arena":
-                return _arena_group(
-                    entries, grads, out, residual, dp_axes, world, config,
-                    issue_fn=lambda v: _marked_issue(name, gi, v, dp_axes),
-                )
+                return _arena_group(entries, grads, out, residual, dp_axes, world, config)
             vals, metas = [], []
             for kind, path, ab in entries:
                 g = _get(grads, path)
@@ -219,14 +195,14 @@ def make_gradient_sync(
                     if len(vals) > 1
                     else vals[0].reshape(-1)
                 )
-                red = _marked_issue(name, gi, flat, dp_axes)
+                red = issue(Collective.ALL_REDUCE, flat, dp_axes)
                 parts, off = [], 0
                 for _, _, _, _, shp in metas:
                     n = int(np.prod(shp)) if shp else 1
                     parts.append(red[off : off + n].reshape(shp))
                     off += n
             else:
-                parts = list(_marked_issue(name, gi, tuple(vals), dp_axes))
+                parts = list(issue(Collective.ALL_REDUCE, tuple(vals), dp_axes))
             for (kind, path, ab, dt, _), r in zip(metas, parts):
                 r = r.astype(dt)
                 if config.average:
@@ -267,7 +243,6 @@ def _arena_group(
     dp_axes: tuple[str, ...],
     world,
     config: SyncConfig,
-    issue_fn=None,
 ) -> tuple[Pytree, Pytree | None]:
     """One group over the arena wire path: pack(+cast[+EF]) -> one psum
     -> unpack(+decompress+average).  The arena layout is the plan-time
@@ -291,9 +266,7 @@ def _arena_group(
         parts, [m[5] for m in metas], off, config.wire_dtype,
         residuals=resid if residual is not None else None,
     )
-    if issue_fn is None:
-        issue_fn = lambda v: issue(Collective.ALL_REDUCE, v, dp_axes)
-    red = issue_fn(arena)
+    red = issue(Collective.ALL_REDUCE, arena, dp_axes)
     scale = (1.0 / world) if config.average else 1.0
     unpacked = unpack_arena(
         red,

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import scopes
 from ..models import loss_fn, staged_loss_fns
 from ..models.common import ArchConfig
 from ..optim.optimizers import Optimizer
@@ -40,10 +41,18 @@ from ..planning.plan import Plan
 from .bucketing import stacked_lm_layout
 from .comm_model import AllReduceModel
 from .cost_model import Hardware, LayerCost, TPU_V5E
+from . import profiler
 from .schedule import Schedule
-from .sync import SyncConfig, device_index, make_gradient_sync
+from .sync import SyncConfig, make_gradient_sync
 
 Pytree = Any
+
+#: XLA's all-reduce combiners (the TPU's pass and XLA:CPU's) merge
+#: independent all-reduces into one.  Over the train step that is every
+#: schedule group's, run after the last gradient lands: it undoes the
+#: plan's merging and the DAG issue order.  The plan owns merging, so the
+#: step turns them off wherever it has more than one DP replica.
+KEEP_GROUPS_APART = {"xla_disable_hlo_passes": "all-reduce-combiner,cpu-all-reduce-combiner"}
 
 
 def _tree_size(tree: Pytree) -> int:
@@ -192,6 +201,13 @@ class MGWFBPEngine:
     def dp_world(self, mesh) -> int:
         return int(np.prod([mesh.shape[ax] for ax in self.dp_axes]))
 
+    def _jit_step(self, smapped, mesh, donate_argnums):
+        """``jax.jit`` of a train-step body, with the all-reduce combiners
+        off (:data:`KEEP_GROUPS_APART`) where there is more than one
+        replica to reduce over."""
+        opts = KEEP_GROUPS_APART if self.dp_world(mesh) > 1 else None
+        return jax.jit(smapped, donate_argnums=donate_argnums, compiler_options=opts)
+
     def init_residual(self, params: Pytree, mesh=None) -> Pytree | None:
         """Zero f32 error-feedback residual (``compression='bf16_ef'``),
         None for stateless compression.
@@ -313,7 +329,7 @@ class MGWFBPEngine:
 
     def make_train_step(
         self, optimizer: Optimizer, mesh, *, lr: float = 3e-4,
-        issue: str = "post", recorder=None,
+        issue: str = "post",
     ):
         """Shard-map train step: manual DP axes, auto model axis.
 
@@ -343,11 +359,10 @@ class MGWFBPEngine:
           depends only on gradients already computed when it issues, so
           its wire time hides behind the backward of groups ``g+1..``.
 
-        ``recorder`` (a ``profiler.TraceRecorder``) plants data-dependent
-        span markers: ``bwd_*`` around each backward event and
-        ``wfbp_group*`` around each group's reduction — the spans
-        ``profiler.overlap_report`` turns into a measured overlap
-        fraction.
+        Both orders name their layers with the ``repro.scopes`` names
+        (``fwd_*``, ``bwd_*``, ``optimizer``; each group's reduction is
+        ``wfbp_group*``): ``profiler.scope_spans`` reads them back from a
+        device trace.
         """
         if issue not in ("post", "dag"):
             raise ValueError(f"unknown issue order {issue!r}; known: ('post', 'dag')")
@@ -361,31 +376,15 @@ class MGWFBPEngine:
             batch_spec["tokens"] = P(self.dp_axes, None)
 
         sync = self.sync
-        if recorder is not None:
-            # rebuild the sync closure with markers woven around each psum
-            sync = make_gradient_sync(
-                self.plan.layout, self.plan.schedule, self.dp_axes,
-                self.sync_config, recorder=recorder,
-            )
-
         if issue == "dag":
-            return self._make_dag_step(
-                optimizer, mesh, lr=lr, sync=sync, recorder=recorder,
-                batch_spec=batch_spec,
-            )
+            return self._make_dag_step(optimizer, mesh, lr=lr, sync=sync, batch_spec=batch_spec)
 
         def grads_and_loss(params, batch):
             def loss(p):
-                return loss_fn(p, batch, cfg, segments=self.segments)
+                with jax.named_scope(scopes.FWD_MODEL):
+                    return loss_fn(p, batch, cfg, segments=self.segments)
 
-            (l, metrics), grads = jax.value_and_grad(loss, has_aux=True)(params)
-            if recorder is not None:
-                dev = device_index(self.dp_axes)
-                # one whole-backward span: opens once the loss exists,
-                # closes when the last (embed) gradient lands
-                recorder.span_begin("bwd_backward", l, device=dev)
-                recorder.span_end("bwd_backward", grads["embed"], device=dev)
-            return (l, metrics), grads
+            return jax.value_and_grad(loss, has_aux=True)(params)
 
         if self.stateful:
             # residual leaves carry a leading DP axis; inside the manual
@@ -397,7 +396,8 @@ class MGWFBPEngine:
                 local_res = jax.tree.map(lambda r: r[0], residual)
                 grads, new_res = sync(grads, local_res)
                 new_residual = jax.tree.map(lambda r: r[None], new_res)
-                new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
                 l = jax.lax.pmean(l, self.dp_axes)
                 return new_params, new_opt, new_residual, {"loss": l}
 
@@ -409,12 +409,13 @@ class MGWFBPEngine:
                 axis_names=set(self.dp_axes),
                 check_vma=False,
             )
-            return jax.jit(smapped, donate_argnums=(0, 1, 2))
+            return self._jit_step(smapped, mesh, (0, 1, 2))
 
         def body(params, opt_state, batch):
             (l, metrics), grads = grads_and_loss(params, batch)
             grads = sync(grads)
-            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
             l = jax.lax.pmean(l, self.dp_axes)
             return new_params, new_opt, {"loss": l}
 
@@ -426,9 +427,9 @@ class MGWFBPEngine:
             axis_names=set(self.dp_axes),
             check_vma=False,
         )
-        return jax.jit(smapped, donate_argnums=(0, 1))
+        return self._jit_step(smapped, mesh, (0, 1))
 
-    def _make_dag_step(self, optimizer, mesh, *, lr, sync, recorder, batch_spec):
+    def _make_dag_step(self, optimizer, mesh, *, lr, sync, batch_spec):
         """The DAG-scheduled step body (see ``make_train_step``)."""
         cfg = self.cfg
         segments = self.segments
@@ -444,35 +445,30 @@ class MGWFBPEngine:
 
             Returns ``(reduced_grads, residual, loss, metrics)``."""
             embed_fn, seg_fns, tail_fn, head_fn = staged_loss_fns(cfg, batch, segments)
-            dev = device_index(self.dp_axes) if recorder is not None else 0
-
-            def mark_b(name, dep):
-                if recorder is not None:
-                    recorder.span_begin(name, dep, device=dev)
-
-            def mark_e(name, dep):
-                if recorder is not None:
-                    recorder.span_end(name, dep, device=dev)
 
             # ---- forward: one vjp pullback per unit event --------------
-            x, pb_embed = jax.vjp(embed_fn, params["embed"])
+            with jax.named_scope(scopes.FWD_EMBED):
+                x, pb_embed = jax.vjp(embed_fn, params["embed"])
             seg_pbs, aux_parts = [], []
-            for (start, stop), seg_fn in zip(segments, seg_fns):
-                seg_p = jax.tree.map(lambda a: a[start:stop], params["stages"])
-                (x, aux), pb = jax.vjp(seg_fn, seg_p, x)
+            for j, ((start, stop), seg_fn) in enumerate(zip(segments, seg_fns)):
+                with jax.named_scope(scopes.fwd_seg(j)):
+                    seg_p = jax.tree.map(lambda a: a[start:stop], params["stages"])
+                    (x, aux), pb = jax.vjp(seg_fn, seg_p, x)
                 seg_pbs.append(pb)
                 aux_parts.append(aux)
             pb_tail = None
             if tail_fn is not None:
-                (x, aux), pb_tail = jax.vjp(tail_fn, params["tail"], x)
+                with jax.named_scope(scopes.FWD_TAIL):
+                    (x, aux), pb_tail = jax.vjp(tail_fn, params["tail"], x)
                 aux_parts.append(aux)
-            aux_total = sum(aux_parts)
-            head_p = {"final_norm": params["final_norm"]}
-            if not cfg.tie_embeddings:
-                head_p["head"] = params["head"]
-            l, pb_head, metrics = jax.vjp(
-                head_fn, head_p, params["embed"], x, aux_total, has_aux=True
-            )
+            with jax.named_scope(scopes.FWD_HEAD):
+                aux_total = sum(aux_parts)
+                head_p = {"final_norm": params["final_norm"]}
+                if not cfg.tie_embeddings:
+                    head_p["head"] = params["head"]
+                l, pb_head, metrics = jax.vjp(
+                    head_fn, head_p, params["embed"], x, aux_total, has_aux=True
+                )
 
             # ---- backward: walk pullbacks in reverse, issuing each
             # group's all-reduce the moment its last gradient lands.
@@ -490,35 +486,31 @@ class MGWFBPEngine:
                     out, res = sync.sync_group(gi, acc, out, res)
                 return out, res
 
-            mark_b("bwd_head", l)
-            d_head_p, d_embed_head, dx, daux = pb_head(jnp.ones_like(l))
-            mark_e("bwd_head", (d_head_p, dx))
-            acc["final_norm"] = d_head_p["final_norm"]
-            if not cfg.tie_embeddings:
-                acc["head"] = d_head_p["head"]
+            with jax.named_scope(scopes.BWD_HEAD):
+                d_head_p, d_embed_head, dx, daux = pb_head(jnp.ones_like(l))
+                acc["final_norm"] = d_head_p["final_norm"]
+                if not cfg.tie_embeddings:
+                    acc["head"] = d_head_p["head"]
             out, res = issue_ready("head", out, res)
 
             if pb_tail is not None:
-                mark_b("bwd_tail", dx)
-                d_tail_p, dx = pb_tail((dx, daux))
-                mark_e("bwd_tail", (d_tail_p, dx))
-                acc["tail"] = d_tail_p
+                with jax.named_scope(scopes.BWD_TAIL):
+                    d_tail_p, dx = pb_tail((dx, daux))
+                    acc["tail"] = d_tail_p
                 out, res = issue_ready("tail", out, res)
 
             for j in range(len(segments) - 1, -1, -1):
                 start, stop = segments[j]
-                mark_b(f"bwd_seg{j}", dx)
-                d_seg_p, dx = seg_pbs[j]((dx, daux))
-                mark_e(f"bwd_seg{j}", (d_seg_p, dx))
-                acc["stages"] = jax.tree.map(
-                    lambda g, d: g.at[start:stop].set(d), acc["stages"], d_seg_p
-                )
+                with jax.named_scope(scopes.bwd_seg(j)):
+                    d_seg_p, dx = seg_pbs[j]((dx, daux))
+                    acc["stages"] = jax.tree.map(
+                        lambda g, d: g.at[start:stop].set(d), acc["stages"], d_seg_p
+                    )
                 out, res = issue_ready(("seg", j), out, res)
 
-            mark_b("bwd_embed", dx)
-            (d_embed_lookup,) = pb_embed(dx)
-            mark_e("bwd_embed", d_embed_lookup)
-            acc["embed"] = d_embed_head + d_embed_lookup
+            with jax.named_scope(scopes.BWD_EMBED):
+                (d_embed_lookup,) = pb_embed(dx)
+                acc["embed"] = d_embed_head + d_embed_lookup
             out, res = issue_ready("embed", out, res)
             return out, res, l, metrics
 
@@ -529,7 +521,8 @@ class MGWFBPEngine:
                 local_res = jax.tree.map(lambda r: r[0], residual)
                 grads, new_res, l, metrics = dag_grads(params, batch, local_res)
                 new_residual = jax.tree.map(lambda r: r[None], new_res)
-                new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
                 l = jax.lax.pmean(l, self.dp_axes)
                 return new_params, new_opt, new_residual, {"loss": l}
 
@@ -541,11 +534,12 @@ class MGWFBPEngine:
                 axis_names=set(self.dp_axes),
                 check_vma=False,
             )
-            return jax.jit(smapped, donate_argnums=(0, 1, 2))
+            return self._jit_step(smapped, mesh, (0, 1, 2))
 
         def body(params, opt_state, batch):
             grads, _, l, metrics = dag_grads(params, batch, None)
-            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
             l = jax.lax.pmean(l, self.dp_axes)
             return new_params, new_opt, {"loss": l}
 
@@ -557,4 +551,4 @@ class MGWFBPEngine:
             axis_names=set(self.dp_axes),
             check_vma=False,
         )
-        return jax.jit(smapped, donate_argnums=(0, 1))
+        return self._jit_step(smapped, mesh, (0, 1))

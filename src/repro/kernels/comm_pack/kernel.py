@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from .ref import decode_part, encode_part
 
 #: Staging-buffer length in elements (f32: 256 KiB — comfortably inside
@@ -225,6 +226,7 @@ def pack_arena_pallas(
         operands = [parts[i] for i in kin] + ([residuals[i] for i in kin] if ef else [])
         out = pl.pallas_call(
             kernel,
+            name=scopes.COMM_PACK_PACK,
             in_specs=[_ANY] * len(operands),
             out_specs=[_ANY] * len(out_shape),
             out_shape=out_shape,
@@ -311,6 +313,7 @@ def unpack_arena_pallas(
         )
         res = pl.pallas_call(
             kernel,
+            name=scopes.COMM_PACK_UNPACK,
             in_specs=[_ANY, pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM)],
             out_specs=[_ANY] * len(kin),
             out_shape=[jax.ShapeDtypeStruct((slots[i][1],), dtypes[i]) for i in kin],

@@ -11,7 +11,13 @@ inside the custom call that the cache key covers.  With full Python
 tracebacks in those locations the key names every frame that led to the
 compile, so the same step compiled from two call sites (say an
 ahead-of-time memory check, then the launcher's own jit) never hits.
-The helper therefore keeps only the innermost user frame in locations.
+The helper therefore keeps only the innermost user frame in locations
+(a traceback limit of one frame).  It leaves tracebacks in locations
+on: with them off, XLA drops the ``jax.named_scope`` path from each
+instruction's ``op_name``, and the device-trace readers join on it.
+For the same reason the key covers that metadata: by default JAX strips
+it from the key, and a step that differs from a cached one only in its
+scopes would load with the cached step's names.
 """
 
 from __future__ import annotations
@@ -33,7 +39,8 @@ def enable_compile_cache() -> str:
     directory is set in code.  Otherwise the cache goes to
     ``DEFAULT_DIR``.  Either way kernel locations stop naming the caller.
     """
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -108,11 +108,10 @@ class TrainSetup:
             plan=plan,
         )
 
-    def train_step(self, eng: MGWFBPEngine, recorder=None):
+    def train_step(self, eng: MGWFBPEngine):
         """The jitted train step of ``eng`` under this command line."""
         return eng.make_train_step(
-            self.opt, self.mesh, lr=self.args.lr,
-            issue=self.args.issue_order, recorder=recorder,
+            self.opt, self.mesh, lr=self.args.lr, issue=self.args.issue_order,
         )
 
 
@@ -124,41 +123,64 @@ class TrainResult:
     losses: list[float]
 
 
-def _dryrun(args, eng, make_step, init_state, data, mesh) -> None:
-    """Trace-first smoke: run ``args.dryrun`` steps under a span recorder
-    and report how much of the wire the chosen issue order actually hides
-    under backward — measured from the parsed trace, not the model."""
-    from ..core.profiler import TraceRecorder, overlap_report
+def _dryrun(args, eng, step_fn, init_state, data, mesh) -> None:
+    """Trace-first smoke: compile the step, run one warm-up step, trace the
+    other ``args.dryrun - 1`` with ``jax.profiler`` (into ``--trace-out``,
+    else a temporary directory), and report how much of the wire the chosen
+    issue order hides under backward — from the ``bwd_*`` / ``wfbp_group*``
+    scopes of the device trace (``profiler.scope_spans``), not the model —
+    and the device time per step of each layer (``profiler.scope_layers``)."""
+    import glob
+    import os
+    import shutil
+    import tempfile
 
-    rec = TraceRecorder()
-    step_fn = make_step(eng, recorder=rec)
+    from ..core.profiler import GROUP_SPAN_RE, overlap_report, scope_layers, scope_spans
+
     state = init_state()
     batch = jax.tree.map(jnp.asarray, data.batch_at(0))
 
+    def inputs(state):
+        if eng.stateful:
+            return state.params, state.opt_state, state.residual, batch
+        return state.params, state.opt_state, batch
+
+    with jax.set_mesh(mesh):
+        compiled = step_fn.lower(*inputs(state)).compile()
+
     def one(state):
         with jax.set_mesh(mesh):
-            if eng.stateful:
-                p, o, res, m = step_fn(
-                    state.params, state.opt_state, state.residual, batch
-                )
-            else:
-                p, o, m = step_fn(state.params, state.opt_state, batch)
-                res = state.residual
+            out = compiled(*inputs(state))
+        p, o, m = out[0], out[1], out[-1]
+        res = out[2] if eng.stateful else state.residual
         return RunState(step=state.step + 1, params=p, opt_state=o,
                         residual=res), m
 
-    # warm-up step compiles; drop its spans so the report is steady-state
-    state, m = one(state)
-    jax.block_until_ready(state.params)
-    jax.effects_barrier()
-    if args.dryrun > 1:
-        rec.clear()
-        for _ in range(args.dryrun - 1):
-            state, m = one(state)
+    if args.dryrun > 1:  # steady state: the trace holds no first-run work
+        state, m = one(state)
         jax.block_until_ready(state.params)
-        jax.effects_barrier()
+    tdir = args.trace_out or tempfile.mkdtemp(prefix="dryrun_trace_")
+    jax.profiler.start_trace(tdir)
+    for _ in range(max(1, args.dryrun - 1)):
+        state, m = one(state)
+    jax.block_until_ready(state.params)
+    jax.profiler.stop_trace()
+    xplane = sorted(glob.glob(os.path.join(tdir, "**", "*.xplane.pb"), recursive=True),
+                    key=os.path.getmtime)[-1]
+    hlo = compiled.as_text()
+    spans = scope_spans(xplane, hlo, eng.sync.group_wire_bytes)
+    layers = scope_layers(xplane, hlo)
+    if not args.trace_out:
+        shutil.rmtree(tdir, ignore_errors=True)
 
-    report = overlap_report(rec.spans())
+    last = max((s.args["step"] for s in spans), default=0)
+    spans = [s for s in spans if s.args["step"] == last]
+    report = overlap_report(spans)
+    covered: dict[int, list[int]] = {}  # group -> devices with its all-reduce span
+    for s in spans:
+        g = GROUP_SPAN_RE.match(s.name)
+        if g:
+            covered.setdefault(int(g.group(1)), []).append(s.device)
     sched = eng.plan.schedule
     print(f"[dryrun] issue={args.issue_order} loss={float(m['loss']):.4f} "
           f"groups={list(sched.groups)}")
@@ -166,16 +188,18 @@ def _dryrun(args, eng, make_step, init_state, data, mesh) -> None:
           f"({report['windowed_comm_us']:.0f}us of {report['total_comm_us']:.0f}us "
           f"comm inside the backward window; strict concurrent overlap "
           f"{report['hidden_fraction']:.3f}; {report['n_overlapped_starts']}/"
-          f"{report['n_comm_spans']} comm spans start inside backward)")
+          f"{report['n_comm_spans']} comm spans start inside backward; "
+          f"last of {last + 1} traced steps)")
     print("[dryrun] " + json.dumps(
         {k: report[k] for k in ("n_devices", "n_comm_spans", "n_bwd_spans",
                                 "total_comm_us", "windowed_comm_us",
                                 "hidden_comm_us", "overlap_fraction",
                                 "hidden_fraction", "n_overlapped_starts")}
+        | {"comm_span_devices": {str(g): sorted(d) for g, d in sorted(covered.items())}}
     ))
+    print("[dryrun] layers " + json.dumps(layers))
     if args.trace_out:
-        rec.save(args.trace_out)
-        print(f"[dryrun] trace written to {args.trace_out}")
+        print(f"[dryrun] profiler trace written under {args.trace_out}")
 
 
 def _parser() -> argparse.ArgumentParser:
@@ -248,14 +272,14 @@ def _parser() -> argparse.ArgumentParser:
                          "last-gradient event inside backward (dag) — the "
                          "WFBP overlap path (requires scan segments)")
     ap.add_argument("--dryrun", type=int, default=0, metavar="N",
-                    help="trace-first smoke: run N steps with the span "
-                         "recorder, print the measured overlap report "
-                         "(comm hidden under backward, from parsed "
-                         "wfbp_group*/bwd_* spans), and exit — no "
+                    help="trace-first smoke: run N steps, the last N-1 under "
+                         "jax.profiler, print the measured overlap report "
+                         "(comm hidden under backward, from the traced "
+                         "wfbp_group*/bwd_* scopes), and exit — no "
                          "checkpoints, no resilience loop")
     ap.add_argument("--trace-out", default=None,
-                    help="with --dryrun: write the Chrome-trace JSON here "
-                         "(.gz for gzip)")
+                    help="with --dryrun: the jax.profiler output directory "
+                         "(kept; default a temporary one)")
     return ap
 
 
@@ -413,7 +437,7 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
         )
 
     if args.dryrun:
-        _dryrun(args, state_box["eng"], make_step, init_state, data, mesh)
+        _dryrun(args, state_box["eng"], state_box["step_fn"], init_state, data, mesh)
         return None
 
     def maybe_replan(step: int) -> None:
@@ -476,10 +500,17 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
     losses: dict[int, jax.Array] = {}  # by step: a restart overwrites its redo
 
     def do_step(state: RunState, step: int) -> RunState:
-        batch = jax.tree.map(jnp.asarray, data.batch_at(step))
+        # host spans on the profiler's clock: a trace puts each device
+        # idle gap down to what the loop was doing (a few µs untraced)
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            return _do_step(state, step)
+
+    def _do_step(state: RunState, step: int) -> RunState:
+        with jax.profiler.TraceAnnotation("train.batch"):
+            batch = jax.tree.map(jnp.asarray, data.batch_at(step))
         eng = state_box["eng"]
         timer.start()
-        with jax.set_mesh(mesh):
+        with jax.set_mesh(mesh), jax.profiler.TraceAnnotation("train.dispatch"):
             if eng.stateful:
                 p, o, res, m = state_box["step_fn"](
                     state.params, state.opt_state, state.residual, batch
@@ -490,15 +521,19 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
         if track_time:
             # timing needs a host-device sync; skip both when every online
             # check is off so the dispatch pipeline stays async
-            jax.block_until_ready(p)
+            with jax.profiler.TraceAnnotation("train.wait"):
+                jax.block_until_ready(p)
             timer.stop()
             if args.replan_every and step and step % args.replan_every == 0:
-                maybe_replan(step)
+                with jax.profiler.TraceAnnotation("train.replan"):
+                    maybe_replan(step)
             if args.comm_refit_every and step and step % args.comm_refit_every == 0:
-                maybe_refit_comm(step)
+                with jax.profiler.TraceAnnotation("train.refit"):
+                    maybe_refit_comm(step)
         losses[step] = m["loss"]
         if step % 10 == 0:
-            print(f"[train] step {step} loss {float(m['loss']):.4f}")
+            with jax.profiler.TraceAnnotation("train.log"):
+                print(f"[train] step {step} loss {float(m['loss']):.4f}")
         return RunState(step=state.step, params=p, opt_state=o,
                         restarts=state.restarts, residual=res)
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import scopes
 from ..parallel.context import constrain, tp_active, tp_size
 from .common import ArchConfig, Attention, truncated_normal
 
@@ -321,16 +322,17 @@ def attention_block(
             ).astype(ckpos.dtype)
         new_cache = (ck, cv, ckpos)
 
-    o = gqa_attention(
-        q, k, v,
-        causal=causal,
-        q_offset=q_offset,
-        window=window,
-        softcap=att.softcap,
-        q_chunk=cfg.q_chunk,
-        chunk_impl=cfg.chunk_impl,
-        kpos=kpos,
-    )
+    with jax.named_scope(scopes.ATTENTION):
+        o = gqa_attention(
+            q, k, v,
+            causal=causal,
+            q_offset=q_offset,
+            window=window,
+            softcap=att.softcap,
+            q_chunk=cfg.q_chunk,
+            chunk_impl=cfg.chunk_impl,
+            kpos=kpos,
+        )
     out = (o.reshape(B, S, -1).astype(x.dtype)) @ p["wo"]
     return constrain(out.astype(x.dtype), {0: "batch"}), new_cache
 

@@ -32,6 +32,8 @@ import statistics
 import time
 from typing import Any, Callable
 
+import jax
+
 from ..checkpoint import AsyncCheckpointer, latest_step, restore
 
 Pytree = Any
@@ -147,13 +149,14 @@ def resilient_loop(
                 if on_straggler is not None:
                     state = on_straggler(state)
             if state.step % checkpoint_every == 0:
-                ckpt.save(
-                    state.step,
-                    state.checkpoint_tree(),
-                    extra={"restarts": restarts},
-                    plan=plan_provider() if plan_provider is not None else None,
-                    tuner=tuner_provider() if tuner_provider is not None else None,
-                )
+                with jax.profiler.TraceAnnotation("train.checkpoint"):
+                    ckpt.save(
+                        state.step,
+                        state.checkpoint_tree(),
+                        extra={"restarts": restarts},
+                        plan=plan_provider() if plan_provider is not None else None,
+                        tuner=tuner_provider() if tuner_provider is not None else None,
+                    )
         except (KeyboardInterrupt, SystemExit):
             raise  # operator interrupts stop the run, never restart it
         except Exception:

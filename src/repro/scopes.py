@@ -1,0 +1,62 @@
+"""The train step's ``jax.named_scope`` names, defined once.
+
+XLA keeps the scope path of every instruction in the compiled module's
+``metadata={op_name=...}``, through ``jax.vjp`` pullbacks
+(``transpose(jvp(<scope>))``) and ``jax.checkpoint`` recomputes
+(``.../rematted_computation/...``), so each op of a device trace can be
+put down to its layer by instruction name (``core.profiler.scope_spans``
+and ``core.profiler.scope_layers``).  Scopes are metadata only: XLA
+neither fuses nor schedules by them.
+
+This module imports nothing, so the model layers and the kernels can name
+their scopes without depending on the trainer side.
+"""
+
+#: Prefix of every forward scope.
+FWD_PREFIX = "fwd_"
+#: Forward of the DAG step (``issue='dag'``), one per ``jax.vjp`` forward
+#: call: the token lookup; the tail layers after the scan (archs that have
+#: them); final norm, output projection and the loss.  ``fwd_seg{j}``
+#: (:func:`fwd_seg`) is the ``j``-th scan segment.
+FWD_EMBED = "fwd_embed"
+FWD_TAIL = "fwd_tail"
+FWD_HEAD = "fwd_head"
+#: Forward of the ``post`` step: its one loss over the whole model, inside
+#: its ``value_and_grad``; the backward ops then carry
+#: ``transpose(jvp(fwd_model))``.
+FWD_MODEL = "fwd_model"
+#: Prefix of every backward scope of the DAG step.
+BWD_PREFIX = "bwd_"
+#: Backward of the DAG step: each pullback call and the write of its
+#: gradient into the step's accumulator (``bwd_seg{j}``: :func:`bwd_seg`).
+#: A segment's remat recompute runs under its ``bwd_seg{j}``.  The group
+#: all-reduces issued between them carry their own :func:`wfbp_group`
+#: scopes (``core/sync``).
+BWD_HEAD = "bwd_head"
+BWD_TAIL = "bwd_tail"
+BWD_EMBED = "bwd_embed"
+#: The optimizer update, in every train-step body.
+OPTIMIZER = "optimizer"
+#: Scores, mask, softmax and weighted sum of ``models/layers.gqa_attention``
+#: (not the Q/K/V/O projections): what a flash kernel replaces.  Training
+#: and serving alike.
+ATTENTION = "attention"
+#: ``name=`` of the comm_pack kernels' two ``pallas_call``s.
+COMM_PACK_PACK = "comm_pack_pack"
+COMM_PACK_UNPACK = "comm_pack_unpack"
+
+
+def fwd_seg(j: int) -> str:
+    """Forward scope of the DAG step's ``j``-th scan segment."""
+    return f"{FWD_PREFIX}seg{j}"
+
+
+def bwd_seg(j: int) -> str:
+    """Backward scope of the DAG step's ``j``-th scan segment."""
+    return f"{BWD_PREFIX}seg{j}"
+
+
+def wfbp_group(gi: int, lo: int, hi: int) -> str:
+    """Scope of schedule group ``gi`` (backward issue order), which covers
+    layers ``lo..hi``: its pack, all-reduce and unpack."""
+    return f"wfbp_group{gi}_l{lo}_{hi}"

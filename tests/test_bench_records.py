@@ -7,8 +7,9 @@ produces the same numbers yields a zero diff, and nobody hand-edits a
 record into a shape the writer would immediately rewrite.
 
 ``BENCH_overlap.json`` additionally carries the tentpole claim and is
-pinned structurally: the dag issue order overlaps, the post order does
-not, and the two are bit-identical in loss.
+pinned structurally: the dag issue order starts its group all-reduces
+inside backward, the post order fewer of them, each order compiles one
+all-reduce per group, and the two are bit-identical in loss.
 """
 
 import json
@@ -47,8 +48,9 @@ def test_overlap_record_claims():
         side = rec[issue]
         assert side["n_comm_spans"] == rec["n_groups"] * rec["n_devices"]
         assert side["total_comm_us"] > 0
-    # the tentpole: dag hides wire inside backward, post cannot
+        assert rec["n_groups"] <= side["allreduce_ops"] <= rec["n_groups"] + 4
+    # the tentpole: dag hides wire inside backward, post less of it (not
+    # none: XLA:CPU starts the head's group once its gradient is ready)
     assert rec["dag"]["overlap_fraction"] > 0
     assert rec["dag"]["n_overlapped_starts"] > 0
-    assert rec["post"]["n_overlapped_starts"] == 0
-    assert rec["dag"]["overlap_fraction"] > rec["post"]["overlap_fraction"]
+    assert rec["post"]["n_overlapped_starts"] < rec["dag"]["n_overlapped_starts"]

@@ -6,7 +6,9 @@ alignment, VMEM limits), so these tests compile ``pack_arena_pallas`` and
 ``v5e:2x2`` topology — no chip attached — at tinyllama-1.1b's real group
 sizes (the largest and smallest group of the plan ``chip_smoke.py``
 trains under) and at one ragged group, and check that the compiled
-module holds the Mosaic kernel (``tpu_custom_call``).
+module holds the Mosaic kernel (``tpu_custom_call``).  A data-parallel
+train step compiled for the four chips keeps one all-reduce per schedule
+group: the TPU's all-reduce combiner would merge them all into one.
 
 The topology is described only inside a fixture: one process at a time
 may load the TPU library, so describing it while a module is imported
@@ -47,6 +49,16 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """A ``data`` mesh over the described v5e:2x2 topology's four chips."""
+    import numpy as np
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return jax.sharding.Mesh(np.array(topo.devices).reshape(4), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -113,10 +125,55 @@ def test_kernel_payload_independent_of_call_site(one_chip, tmp_path, monkeypatch
         return jax.jit(lambda p: pack_arena_pallas(p, [0, 8192], 12288, jnp.bfloat16)[0]
                        ).lower(parts).as_text()
 
-    was = jax.config.jax_include_full_tracebacks_in_locations
+    was = jax.config.jax_traceback_in_locations_limit
+    was_key = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         enable_compile_cache()
         first = lower()
         assert (lambda: lower())() == first
     finally:
-        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+        jax.config.update("jax_traceback_in_locations_limit", was)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", was_key)
+
+
+def test_dp_step_keeps_one_allreduce_per_group_on_four_chips(four_chips, monkeypatch):
+    """The DAG step of a reduced tinyllama over four v5e chips compiles to
+    one all-reduce per schedule group plus the loss's; with the combiner
+    left on, XLA merges them into fewer, after the last gradient."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_reduced
+    from repro.core import trainer
+    from repro.core.comm_model import AllReduceModel
+    from repro.core.sync import SyncConfig
+    from repro.models.transformer import init_params
+    from repro.optim import make_optimizer
+
+    mesh = four_chips
+    cfg = get_reduced("tinyllama-1.1b")
+    eng = trainer.MGWFBPEngine.build(
+        cfg, param_specs(cfg), dp_axes=("data",), ar_model=AllReduceModel(a=5e-5, b=1e-9),
+        tokens_per_device=1024, method="wfbp", sync_config=SyncConfig(fuse="arena"))
+    opt = make_optimizer("sgd", momentum=0.9)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    rep = NamedSharding(mesh, P())
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), tree)
+
+    tok = jax.ShapeDtypeStruct((8, 64), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))
+    args = (described(params), described(jax.eval_shape(opt.init, params)),
+            {"tokens": tok, "targets": tok})
+
+    def n_allreduce():
+        step = eng.make_train_step(opt, mesh, lr=1e-2, issue="dag")
+        with jax.set_mesh(mesh):
+            text = step.lower(*args).compile().as_text()
+        return len(re.findall(r" all-reduce(-start)?\(", text))
+
+    n_groups = len(eng.schedule.groups)
+    assert n_allreduce() == n_groups + 1
+    monkeypatch.setattr(trainer, "KEEP_GROUPS_APART", None)
+    assert n_allreduce() < n_groups

@@ -126,16 +126,21 @@ def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     was = jax.config.jax_compilation_cache_dir
-    was_tb = jax.config.jax_include_full_tracebacks_in_locations
+    was_tb = jax.config.jax_traceback_in_locations_limit
+    was_key = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         first = compile_cache.enable_compile_cache()
         assert first == compile_cache.enable_compile_cache()
         assert first == str(REPO_ROOT / ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == first
-        assert jax.config.jax_include_full_tracebacks_in_locations is False
+        # the innermost frame alone, with the name scopes still in op_name
+        assert jax.config.jax_traceback_in_locations_limit == 1
+        assert jax.config.jax_include_full_tracebacks_in_locations is True
+        assert jax.config.jax_compilation_cache_include_metadata_in_key is True
     finally:
         jax.config.update("jax_compilation_cache_dir", was)
-        jax.config.update("jax_include_full_tracebacks_in_locations", was_tb)
+        jax.config.update("jax_traceback_in_locations_limit", was_tb)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", was_key)
     ignored = (REPO_ROOT / ".gitignore").read_text().split()
     assert ".jax_cache/" in ignored
 

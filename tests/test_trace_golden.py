@@ -11,9 +11,10 @@ reduced, wfbp policy, fuse=arena, 8 virtual devices).  The suite pins:
     the same (arch, policy, fuse) rebuilt from the planning stack — the
     trace's payload accounting must stay tied to the arena layout;
   * the overlap-report arithmetic, to the float (the fixture is static,
-    so the report is a pure function with golden outputs);
-  * ``TraceRecorder`` pairing/serialization on an injected fake clock
-    (hand-checkable interval arithmetic, no wall clock).
+    so the report is a pure function with golden outputs).
+
+The device-trace path that now produces such spans
+(``profiler.scope_spans``) is pinned by ``tests/test_trace_scopes.py``.
 """
 
 from __future__ import annotations
@@ -32,7 +33,6 @@ from repro.core import stacked_lm_layout
 from repro.core.comm_model import AllReduceModel
 from repro.core.profiler import (
     GROUP_SPAN_RE,
-    TraceRecorder,
     overlap_report,
     parse_trace_spans,
 )
@@ -142,45 +142,3 @@ class TestOverlapReport:
         assert rep["n_comm_spans"] == 0
         assert rep["overlap_fraction"] == 0.0
         assert rep["groups"] == []
-
-
-class TestRecorderFakeClock:
-    def test_pairing_and_arithmetic(self, tmp_path):
-        """Deterministic recorder run on an injected ns clock: spans pair
-        FIFO per (name, device) and the report arithmetic is checkable by
-        hand (all times in µs after the 1e3 conversion)."""
-        ticks = iter([0, 100_000, 10_000, 60_000, 120_000, 150_000])
-        rec = TraceRecorder(clock_ns=lambda: next(ticks))
-        # backward 0..100us; comm group0 10..60us (inside), group1
-        # 120..150us (after backward ends)
-        rec._mark("bwd_backward", "B", 0, 0)
-        rec._mark("bwd_backward", "E", 0, 0)
-        rec._mark("wfbp_group0_l2_2", "B", 64, 0)
-        rec._mark("wfbp_group0_l2_2", "E", 64, 0)
-        rec._mark("wfbp_group1_l1_1", "B", 32, 0)
-        rec._mark("wfbp_group1_l1_1", "E", 32, 0)
-        spans = rec.spans()
-        assert len(spans) == 3 and len(rec) == 6
-        rep = overlap_report(spans)
-        assert rep["total_comm_us"] == pytest.approx(80.0)
-        assert rep["windowed_comm_us"] == pytest.approx(50.0)
-        assert rep["hidden_comm_us"] == pytest.approx(50.0)
-        assert rep["overlap_fraction"] == pytest.approx(50.0 / 80.0)
-        assert rep["n_overlapped_starts"] == 1
-        g0, g1 = rep["groups"]
-        assert g0["starts_before_bwd_end"] and not g1["starts_before_bwd_end"]
-        assert g0["bytes"] == 64 and g1["bytes"] == 32
-        # chrome-trace round trip (plain + gzip) preserves the spans
-        for name in ("t.json", "t.json.gz"):
-            p = tmp_path / name
-            rec.save(p)
-            assert parse_trace_spans(p) == spans
-
-    def test_clear_resets(self):
-        ticks = iter(range(0, 10_000_000, 1_000))
-        rec = TraceRecorder(clock_ns=lambda: next(ticks))
-        rec._mark("wfbp_group0_l1_1", "B", 8, 0)
-        rec._mark("wfbp_group0_l1_1", "E", 8, 0)
-        assert len(rec.spans()) == 1
-        rec.clear()
-        assert len(rec) == 0 and rec.spans() == []
