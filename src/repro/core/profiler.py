@@ -575,9 +575,16 @@ def is_attention(op_name: str) -> bool:
     return any(_core(c) == scopes.ATTENTION for c in op_name.split("/")) if op_name else False
 
 
+def is_flash(op_name: str) -> bool:
+    """An op of one of the flash attention kernels (``flash_fwd``,
+    ``flash_dq``, ``flash_dkv``: the ``name=`` of their ``pallas_call``)."""
+    return any(_core(c) in scopes.FLASH_KERNELS for c in op_name.split("/")) if op_name else False
+
+
 def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
     """Device milliseconds per step of each of :data:`LAYERS`
-    (``<layer>_ms``), of attention (``attention_ms``) and of all busy time
+    (``<layer>_ms``), of attention (``attention_ms``), of attention inside
+    the flash kernels (``attention_flash_ms``) and of all busy time
     (``busy_ms``), averaged over devices and steps, from executed ops
     ``(device, run, start_ns, end_ns, instruction)`` (:func:`trace_ops`) of
     the compiled module ``hlo_text``.  Where ops of several layers run at
@@ -587,16 +594,17 @@ def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
     its ops' intervals.  None where no op carries a train-step scope."""
     names = hlo_op_names(hlo_text)
     allreduces = hlo_allreduces(hlo_text)
-    runs: dict[tuple[int, int], list[tuple[int, int, int, bool]]] = {}
+    runs: dict[tuple[int, int], list[tuple[int, int, int, bool, bool]]] = {}
     for dev, run, start, end, op in ops:
         path = names.get(op, "")
         runs.setdefault((dev, run), []).append(
-            (start, end, LAYERS.index(op_layer(path, op in allreduces)), is_attention(path)))
+            (start, end, LAYERS.index(op_layer(path, op in allreduces)), is_attention(path),
+             is_flash(path)))
     total = [0.0] * len(LAYERS)
-    attention = 0.0
+    attention = flash = 0.0
     for tagged in runs.values():
-        edges = sorted([(s, 1, k) for s, e, k, _ in tagged if e > s]
-                       + [(e, -1, k) for s, e, k, _ in tagged if e > s])
+        edges = sorted([(s, 1, k) for s, e, k, *_ in tagged if e > s]
+                       + [(e, -1, k) for s, e, k, *_ in tagged if e > s])
         active, prev = [0] * len(LAYERS), None
         for t, d, k in edges:
             if prev is not None and t > prev:
@@ -605,12 +613,14 @@ def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
                     total[first] += t - prev
             active[k] += d
             prev = t
-        attention += _union_len([(s, e) for s, e, _, a in tagged if a])
+        attention += _union_len([(s, e) for s, e, _, a, _ in tagged if a])
+        flash += _union_len([(s, e) for s, e, _, a, f in tagged if a and f])
     if not any(total[LAYERS.index(k)] for k in ("remat", "backward", "forward", "optimizer")):
         return None
     per = 1e-6 / len(runs)  # ns summed over (device, step) -> ms per step
     out = {f"{k}_ms": v * per for k, v in zip(LAYERS, total)}
-    return out | {"attention_ms": attention * per, "busy_ms": sum(total) * per}
+    return out | {"attention_ms": attention * per, "attention_flash_ms": flash * per,
+                  "busy_ms": sum(total) * per}
 
 
 def scope_layers(xplane_path, hlo_text: str) -> dict[str, float] | None:

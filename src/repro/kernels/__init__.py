@@ -9,7 +9,7 @@ tests sweep shapes/dtypes in interpret mode against the oracles.
 """
 
 from .comm_pack import pack_arena, pack_arena_ref, unpack_arena, unpack_arena_ref
-from .flash_attention import attention_ref, flash_attention, flash_attention_fwd
+from .flash_attention import attention_ref, flash_attention_fwd, flash_attention_train
 from .rglru import rglru, rglru_pallas, rglru_ref
 from .rwkv6_wkv import wkv, wkv_pallas, wkv_ref
 
@@ -19,8 +19,8 @@ __all__ = [
     "pack_arena_ref",
     "unpack_arena",
     "unpack_arena_ref",
-    "flash_attention",
     "flash_attention_fwd",
+    "flash_attention_train",
     "rglru",
     "rglru_pallas",
     "rglru_ref",

@@ -15,33 +15,13 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..per_device import per_device
 from .kernel import pack_arena_pallas, unpack_arena_pallas
 from .ref import pack_arena_ref, unpack_arena_ref
 
 
 def _use_pallas(use_pallas: bool | None) -> bool:
     return jax.default_backend() == "tpu" if use_pallas is None else use_pallas
-
-
-def _per_device(f):
-    """Run ``f`` once per device over the mesh axes left to the compiler.
-
-    GSPMD cannot partition a Mosaic kernel, so inside a ``shard_map``
-    that keeps some mesh axes automatic (the train step's model axis)
-    the kernel is wrapped in one more ``shard_map``, its operands
-    replicated across those axes.  That ``shard_map`` names every mesh
-    axis, the outer manual ones too: under ``jax.set_mesh`` it lowers
-    against the concrete mesh, which does not know the outer axes are
-    manual, and Mosaic refuses a kernel unless all axes are.
-    """
-    mesh = jax.sharding.get_abstract_mesh()
-    if not set(mesh.axis_names) - set(mesh.manual_axes):
-        return f
-    P = jax.sharding.PartitionSpec
-    return jax.shard_map(
-        f, mesh=mesh, in_specs=P(), out_specs=P(), axis_names=set(mesh.axis_names),
-        check_vma=False,
-    )
 
 
 def pack_arena(
@@ -67,7 +47,7 @@ def pack_arena(
     res_flat = None if residuals is None else [r.reshape(-1) for r in residuals]
     if _use_pallas(use_pallas) or interpret:
         kw = {} if chunk is None else {"chunk": chunk}
-        arena, new_res = _per_device(
+        arena, new_res = per_device(
             lambda flat, res_flat: pack_arena_pallas(
                 flat, offsets, size, comm_dtype, res_flat, interpret=interpret, **kw
             )
@@ -94,7 +74,7 @@ def unpack_arena(
     fused); parts come back in their original shapes/dtypes."""
     if _use_pallas(use_pallas) or interpret:
         kw = {} if chunk is None else {"chunk": chunk}
-        out = _per_device(
+        out = per_device(
             lambda arena, scale: unpack_arena_pallas(
                 arena, slots, dtypes, scale, interpret=interpret, **kw
             )

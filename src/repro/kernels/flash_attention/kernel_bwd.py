@@ -4,15 +4,20 @@ Standard two-kernel decomposition (FlashAttention-2 style):
 
   * ``_dq_kernel``  — grid (B·Hq, nq, nk), KV axis sequential; fp32
     dQ accumulator (block_q, hd) persists across KV blocks;
-  * ``_dkv_kernel`` — grid (B·Hq, nk, nq), Q axis sequential; fp32
-    dK/dV accumulators (block_k, hd) persist across Q blocks.  Gradients
-    are produced per *query* head and group-summed to KV heads outside
-    (GQA), trading G× transient memory for perfectly regular tiles.
+  * ``_dkv_kernel`` — grid (B·Hkv, nk, G·nq), the (query head of the
+    group, Q block) axis sequential; fp32 dK/dV accumulators (block_k, hd)
+    persist across the G query heads that share the KV head (GQA) and
+    their Q blocks, so dK/dV leave the kernel already summed per KV head.
+    It works on transposed (block_k, block_q) tiles, so no tile is
+    transposed on the way into the MXU.
 
 Both recompute p = exp(s − L) from the forward's saved row logsumexp
-L = m + log l — no S×S residuals are ever written to HBM.  Softcap
-backward chains d tanh = 1 − (s/cap)².  VMEM per program ≈
-(q + k + v + dO + dQ) blocks ≈ 5·block·hd·4B ≲ 1 MB at 256×128.
+L = m + log l — no S×S residuals are ever written to HBM.  As in the
+forward, the MXU takes the operands' own dtype (p and ds are cast to it),
+accumulates in f32, and the tiles the causal mask or the window leaves
+empty are skipped, with their index maps clamped so no DMA is issued for
+them; the element mask runs only on the tiles an edge crosses.  Softcap
+backward chains d tanh = 1 − (s/cap)².
 """
 
 from __future__ import annotations
@@ -24,28 +29,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _scores(q, k, sm_scale, softcap):
-    s_raw = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if softcap is not None:
-        t = jnp.tanh(s_raw / softcap)
-        return t * softcap, (1.0 - t * t)  # value, d(softcap)/d(raw)
-    return s_raw, None
-
-
-def _mask(iq, ik, block_q, block_k, causal, window):
-    qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    m = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        m &= qpos >= kpos
-    if window is not None:
-        m &= (qpos - kpos) < window
-    return m
+from ... import scopes
+from .kernel import (
+    LANES,
+    block_sizes,
+    effective_window,
+    element_mask,
+    kv_range,
+    lanes,
+    q_range,
+    scores,
+    tile_flags,
+)
 
 
 def _dq_kernel(
@@ -58,131 +53,129 @@ def _dq_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].astype(jnp.float32)
-    lse = lse_ref[...].astype(jnp.float32)[:, :1]  # (block_q, 1)
-    delta = delta_ref[...].astype(jnp.float32)[:, :1]
+    def step(masked: bool):
+        k = k_ref[...]
+        s, dcap = scores(q_ref[...], k, sm_scale, softcap)  # (block_q, block_k)
+        p = jnp.exp(s - lanes(lse_ref[...], block_k))
+        if masked:
+            p = jnp.where(element_mask(iq, ik, block_q, block_k, causal, window), p, 0.0)
+        dp = jax.lax.dot_general(
+            do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - lanes(delta_ref[...], block_k))
+        if dcap is not None:
+            ds = ds * dcap
+        acc_ref[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    s, dcap = _scores(q, k, sm_scale, softcap)
-    mask = _mask(iq, ik, block_q, block_k, causal, window)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta)
-    if dcap is not None:
-        ds = ds * dcap
-    ds = ds * sm_scale
-    acc_ref[...] += jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    needed, edge = tile_flags(iq, ik, block_q, block_k, num_kv_blocks, causal, window)
+    pl.when(needed & edge)(lambda: step(True))
+    pl.when(needed & jnp.logical_not(edge))(lambda: step(False))
 
     @pl.when(ik == num_kv_blocks - 1)
     def _done():
-        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[...] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, sm_scale, causal, window, softcap, block_q, block_k, num_q_blocks,
+    num_kv_blocks, group,
 ):
-    ik, iq = pl.program_id(1), pl.program_id(2)
+    """Works on the transposed tile (block_k, block_q), so every matmul
+    takes its operands as they lie: s^T = k q^T, dV += p^T dO,
+    dp^T = v dO^T, dK += ds^T q.  ``lse_ref`` and ``delta_ref`` hold the
+    q block's statistics along lanes, (1, block_q)."""
+    ik, j = pl.program_id(1), pl.program_id(2)
+    iq = j % num_q_blocks
 
-    @pl.when(iq == 0)
+    @pl.when(j == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].astype(jnp.float32)
-    lse = lse_ref[...].astype(jnp.float32)[:, :1]
-    delta = delta_ref[...].astype(jnp.float32)[:, :1]
+    def step(masked: bool):
+        q, do = q_ref[...], do_ref[...]
+        st, dcap = scores(k_ref[...], q, sm_scale, softcap)  # (block_k, block_q)
+        pt = jnp.exp(st - lse_ref[...])
+        if masked:
+            mask = element_mask(iq, ik, block_q, block_k, causal, window, transposed=True)
+            pt = jnp.where(mask, pt, 0.0)
+        dv_acc[...] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            v_ref[...], do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dst = pt * (dpt - delta_ref[...])
+        if dcap is not None:
+            dst = dst * dcap
+        dk_acc[...] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    s, dcap = _scores(q, k, sm_scale, softcap)
-    mask = _mask(iq, ik, block_q, block_k, causal, window)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    needed, edge = tile_flags(iq, ik, block_q, block_k, num_kv_blocks, causal, window)
+    pl.when(needed & edge)(lambda: step(True))
+    pl.when(needed & jnp.logical_not(edge))(lambda: step(False))
 
-    dv_acc[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta)
-    if dcap is not None:
-        ds = ds * dcap
-    ds = ds * sm_scale
-    dk_acc[...] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(iq == num_q_blocks - 1)
+    @pl.when(j == group * num_q_blocks - 1)
     def _done():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "softcap", "block_q", "block_k", "interpret"),
-)
-def flash_attention_bwd(
-    q: jax.Array,  # (B, Sq, Hq, hd)
-    k: jax.Array,  # (B, Sk, Hkv, hd)
-    v: jax.Array,
-    o: jax.Array,  # forward output
-    lse: jax.Array,  # (B, Sq, Hq) row logsumexp from forward
-    do: jax.Array,  # cotangent of o
+def bwd_heads(
+    qt: jax.Array,  # (B·Hq, Sq, hd)
+    kt: jax.Array,  # (B·Hkv, Sk, hd)
+    vt: jax.Array,
+    ot: jax.Array,  # forward output, (B·Hq, Sq, hd)
+    lse: jax.Array,  # (B·Hq, Sq, 128) f32: the forward's row logsumexp
+    dot: jax.Array,  # cotangent of ot
     *,
-    causal: bool = True,
-    window: int | None = None,
-    softcap: float | None = None,
-    block_q: int = 256,
-    block_k: int = 256,
+    causal: bool,
+    window: int | None,
+    softcap: float | None,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    B, Sq, Hq, hd = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
-    G = Hq // Hkv
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    while Sq % block_q:
-        block_q //= 2
-    while Sk % block_k:
-        block_k //= 2
+    """dQ, dK, dV in the kernels' layout (that of ``qt``, ``kt``, ``vt``)."""
+    BHq, Sq, hd = qt.shape
+    BHkv, Sk = kt.shape[:2]
+    G = BHq // BHkv
+    auto_q, auto_k = block_sizes(Sq, Sk, hd)
+    block_q, block_k = block_q or auto_q, block_k or auto_k
     nq, nk = Sq // block_q, Sk // block_k
+    window = effective_window(window, Sq)
 
-    qt = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, hd)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, hd)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, hd)
-    dot = do.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, hd)
-    ot = o.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, hd)
-    lset = lse.transpose(0, 2, 1).reshape(B * Hq, Sq)
-    delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)  # (B·Hq, Sq)
+    lanes = (BHq, Sq, LANES)  # the dQ kernel's: per q row, lane-replicated
+    delta_rows = jnp.broadcast_to(delta[..., None], lanes)
+    lse_t, delta_t = lse[:, None, :, 0], delta[:, None, :]  # the dK/dV kernel's: (B·Hq, 1, Sq)
 
-    LANES = 128
-    lse2 = jnp.broadcast_to(lset[..., None], lset.shape + (LANES,))
-    delta2 = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
+    common = dict(
+        sm_scale=hd**-0.5, causal=causal, window=window, softcap=softcap,
+        block_q=block_q, block_k=block_k, num_kv_blocks=nk,
+    )
+    params = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     def q_map_q(bh, iq, ik):
         return (bh, iq, 0)
 
     def kv_map_q(bh, iq, ik):
-        b, h = bh // Hq, bh % Hq
-        return (b * Hkv + h // G, ik, 0)
+        lo, hi = kv_range(iq, block_q, block_k, nk, causal, window)
+        return (bh // G, jnp.minimum(jnp.maximum(ik, lo), hi), 0)
 
-    common = dict(
-        sm_scale=hd**-0.5, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k,
-    )
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, num_kv_blocks=nk, **common),
-        grid=(B * Hq, nq, nk),
+        functools.partial(_dq_kernel, **common),
+        name=scopes.FLASH_DQ,
+        grid=(BHq, nq, nk),
         in_specs=[
             pl.BlockSpec((None, block_q, hd), q_map_q),
             pl.BlockSpec((None, block_k, hd), kv_map_q),
@@ -192,52 +185,48 @@ def flash_attention_bwd(
             pl.BlockSpec((None, block_q, LANES), q_map_q),
         ],
         out_specs=pl.BlockSpec((None, block_q, hd), q_map_q),
-        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BHq, Sq, hd), qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=params,
         interpret=interpret,
-    )(qt, kt, vt, dot, lse2, delta2)
+    )(qt, kt, vt, dot, lse, delta_rows)
 
-    def k_map(bh, ik, iq):
-        b, h = bh // Hq, bh % Hq
-        return (b * Hkv + h // G, ik, 0)
+    def kv_map_k(bkv, ik, j):
+        return (bkv, ik, 0)
 
-    def q_map_k(bh, ik, iq):
-        return (bh, iq, 0)
+    def q_map_k(bkv, ik, j):
+        lo, hi = q_range(ik, block_q, block_k, nq, causal, window)
+        return (bkv * G + j // nq, jnp.minimum(jnp.maximum(j % nq, lo), hi), 0)
 
-    dk_e, dv_e = pl.pallas_call(
-        functools.partial(_dkv_kernel, num_q_blocks=nq, **common),
-        grid=(B * Hq, nk, nq),
+    def q_row_map_k(bkv, ik, j):
+        h, iq, _ = q_map_k(bkv, ik, j)
+        return (h, 0, iq)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, num_q_blocks=nq, group=G, **common),
+        name=scopes.FLASH_DKV,
+        grid=(BHkv, nk, G * nq),
         in_specs=[
             pl.BlockSpec((None, block_q, hd), q_map_k),
-            pl.BlockSpec((None, block_k, hd), k_map),
-            pl.BlockSpec((None, block_k, hd), k_map),
+            pl.BlockSpec((None, block_k, hd), kv_map_k),
+            pl.BlockSpec((None, block_k, hd), kv_map_k),
             pl.BlockSpec((None, block_q, hd), q_map_k),
-            pl.BlockSpec((None, block_q, LANES), q_map_k),
-            pl.BlockSpec((None, block_q, LANES), q_map_k),
+            pl.BlockSpec((None, 1, block_q), q_row_map_k),
+            pl.BlockSpec((None, 1, block_q), q_row_map_k),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_k, hd), lambda bh, ik, iq: (bh, ik, 0)),
-            pl.BlockSpec((None, block_k, hd), lambda bh, ik, iq: (bh, ik, 0)),
+            pl.BlockSpec((None, block_k, hd), kv_map_k),
+            pl.BlockSpec((None, block_k, hd), kv_map_k),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * Hq, Sk, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, Sk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((BHkv, Sk, hd), kt.dtype),
+            jax.ShapeDtypeStruct((BHkv, Sk, hd), vt.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=params,
         interpret=interpret,
-    )(qt, kt, vt, dot, lse2, delta2)
-
-    # group-sum the per-q-head dK/dV back to KV heads
-    dk = dk_e.reshape(B, Hkv, G, Sk, hd).sum(axis=2).transpose(0, 2, 1, 3).astype(k.dtype)
-    dv = dv_e.reshape(B, Hkv, G, Sk, hd).sum(axis=2).transpose(0, 2, 1, 3).astype(v.dtype)
-    dq_out = dq.reshape(B, Hq, Sq, hd).transpose(0, 2, 1, 3)
-    return dq_out, dk, dv
+    )(qt, kt, vt, dot, lse_t, delta_t)
+    return dq, dk, dv

@@ -1,10 +1,10 @@
-"""Public ops: flash attention forward and the differentiable training op.
+"""The differentiable training op over the Pallas flash kernels.
 
-``flash_attention`` dispatches to the Pallas TPU kernel on TPU backends
-(or in interpret mode for validation) and to the dense jnp oracle
-otherwise.  ``flash_attention_train`` is the custom-VJP op whose forward
-saves only (o, lse) and whose backward runs the Pallas dQ/dKV kernels —
-no S×S residuals in HBM (kernel_bwd.py).
+``flash_attention_train`` is a custom VJP whose forward saves only the
+kernels' operands, (o, lse) — no S×S residuals in HBM — and whose
+backward runs the Pallas dQ and dKV kernels (kernel_bwd.py).  Both rules
+work in the kernels' (B·H, S, hd) layout and run the kernels once per
+device where the mesh leaves axes to the compiler (``per_device``).
 """
 
 from __future__ import annotations
@@ -12,74 +12,37 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
-from .kernel import flash_attention_fwd
-from .kernel_bwd import flash_attention_bwd
-from .ref import attention_ref
-
-
-def _use_pallas(explicit: bool | None) -> bool:
-    if explicit is not None:
-        return explicit
-    return jax.default_backend() == "tpu"
+from ..per_device import per_device
+from .kernel import fwd_heads, from_heads, to_heads
+from .kernel_bwd import bwd_heads
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "softcap", "use_pallas", "interpret"),
-)
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: int | None = None,
-    softcap: float | None = None,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    if _use_pallas(use_pallas) or interpret:
-        return flash_attention_fwd(
-            q, k, v,
-            causal=causal, window=window, softcap=softcap, interpret=interpret,
-        )
-    return attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)
-
-
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
-)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_train(
-    q, k, v,
+    q, k, v,  # (B, S, Hq, hd), (B, S, Hkv, hd), (B, S, Hkv, hd)
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
     interpret: bool = False,
 ):
-    o, _ = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, softcap=softcap,
-        interpret=interpret, return_lse=True,
-    )
-    return o
+    return _fat_fwd(q, k, v, causal, window, softcap, interpret)[0]
 
 
 def _fat_fwd(q, k, v, causal, window, softcap, interpret):
-    o, lse = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, softcap=softcap,
-        interpret=interpret, return_lse=True,
-    )
-    return o, (q, k, v, o, lse)
+    qt, kt, vt = to_heads(q), to_heads(k), to_heads(v)
+    fwd = functools.partial(fwd_heads, causal=causal, window=window, softcap=softcap,
+                            interpret=interpret)
+    ot, lse = per_device(fwd)(qt, kt, vt)
+    return from_heads(ot, q.shape[0]), (qt, kt, vt, ot, lse)
 
 
 def _fat_bwd(causal, window, softcap, interpret, res, do):
-    q, k, v, o, lse = res
-    dq, dk, dv = flash_attention_bwd(
-        q, k, v, o, lse, do,
-        causal=causal, window=window, softcap=softcap, interpret=interpret,
-    )
-    return dq, dk, dv
+    qt, kt, vt, ot, lse = res
+    bwd = functools.partial(bwd_heads, causal=causal, window=window, softcap=softcap,
+                            interpret=interpret)
+    grads = per_device(bwd)(qt, kt, vt, ot, lse, to_heads(do))
+    return tuple(from_heads(g, do.shape[0]) for g in grads)
 
 
 flash_attention_train.defvjp(_fat_fwd, _fat_bwd)

@@ -76,7 +76,6 @@ class ArchConfig:
     moe_token_chunk: int = 2048  # legacy knob (grouped dispatch supersedes)
     rec_chunk: int = 128  # time chunk for chunked linear recurrences
     chunk_impl: str = "map"  # 'map' (memory-realistic) | 'unroll' (exact cost)
-    attn_impl: str = "jnp"  # 'jnp' | 'pallas' (TPU)
     remat: str = "full"  # 'full' | 'dots' | 'none'
 
     @property

@@ -1,11 +1,13 @@
 """Transformer building blocks: norms, RoPE/M-RoPE, GQA attention (full /
 sliding-window / softcap), dense & gated MLPs, logit softcap.
 
-All attention here is the **jnp fallback path** used for CPU dry-runs and
-smoke tests: query-chunked online attention with bounded memory.  The TPU
-production path swaps in the Pallas flash kernel (``repro.kernels``) via
-``ArchConfig.attn_impl = 'pallas'`` — same signature, same semantics, no
-S×S HBM materialization at all.
+``gqa_attention`` runs self-attention over freshly projected K/V (training,
+and prefill without a cache) through the Pallas flash kernels
+(``kernels.flash_attention.flash_attention_train``) on a TPU, where the
+shapes suit them and no tensor parallelism splits the heads: no S×S
+scores reach HBM.  Everything else — the CPU, decode and attention
+against a KV cache, the TP and sequence-TP paths — runs the jnp path:
+query-chunked masked attention with bounded score memory.
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import scopes
+from ..kernels.flash_attention import flash_attention_train
 from ..parallel.context import constrain, tp_active, tp_size
 from .common import ArchConfig, Attention, truncated_normal
 
@@ -104,7 +107,7 @@ def sinusoidal_embedding(seq_len: int, dim: int, offset: int = 0) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# GQA attention (jnp chunked fallback)
+# GQA attention
 # ---------------------------------------------------------------------------
 
 
@@ -137,6 +140,26 @@ def _chunk_iter(fn, n_chunks: int, mode: str):
     return jax.lax.map(fn, jnp.arange(n_chunks))
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_applies(S: int, T: int, hd: int, q_offset, kpos) -> bool:
+    """Whether the flash kernels take this attention call: self-attention
+    over freshly projected K/V (no cache positions, no query offset) on a
+    TPU, at shapes the kernels tile, with no tensor parallelism (GSPMD
+    cannot partition a Mosaic kernel)."""
+    return (
+        _on_tpu()
+        and kpos is None
+        and isinstance(q_offset, int) and q_offset == 0
+        and S == T
+        and S % 128 == 0
+        and hd in (64, 128, 256)
+        and not tp_active()
+    )
+
+
 def gqa_attention(
     q: jax.Array,  # (B, S, Hq, hd) — rope already applied
     k: jax.Array,  # (B, T, Hkv, hd)
@@ -150,22 +173,20 @@ def gqa_attention(
     chunk_impl: str = "map",
     kpos: jax.Array | None = None,  # absolute key positions (ring caches)
 ) -> jax.Array:
-    """Query-chunked masked attention with bounded score memory.
+    """Masked GQA attention; returns (B, S, Hq, hd).
 
-    Returns (B, S, Hq, hd).  Flash-equivalent numerics (full softmax per
-    row — each chunk sees every key, so no online rescaling is needed; the
-    Pallas kernel is the tiled-KV variant).  ``kpos`` carries absolute key
-    positions for ring-buffer KV caches; unwritten slots hold a large
-    sentinel so the causal mask hides them.
+    On a TPU, self-attention over fresh K/V runs the flash kernels
+    (:func:`_flash_applies`).  Otherwise: sequence-TP prefill, or
+    query-chunked attention with bounded score memory (full softmax per
+    row — each chunk sees every key, so no online rescaling is needed).
+    ``kpos`` carries absolute key positions for ring-buffer KV caches;
+    unwritten slots hold a large sentinel so the causal mask hides them.
     """
     B, S, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
     scale = hd ** -0.5
-
-    if kpos is None:
-        kpos = jnp.arange(T)
 
     # Sequence-TP (prefill on non-EP archs): the model axis is otherwise
     # idle (batch < chips), so q is reshaped into model_size row-blocks
@@ -182,7 +203,7 @@ def gqa_attention(
     if ctx is not None and ctx.model_axis is not None and ctx.batch_axes:
         _b_loc = max(1, B // ctx.data_size)
         _seq_tp_bytes = _b_loc * Hq * (S // ctx.model_size) * T * 4
-    if (
+    seq_tp = (
         ctx is not None
         and ctx.prefer == "seq_tp"
         and ctx.model_axis is not None
@@ -190,7 +211,13 @@ def gqa_attention(
         and S > 1
         and S == T  # self-attention prefill only
         and 0 < _seq_tp_bytes < 8 * 2**30
-    ):
+    )
+    if not seq_tp and _flash_applies(S, T, hd, q_offset, kpos):
+        return flash_attention_train(q, k, v, causal, window, softcap)
+
+    if kpos is None:
+        kpos = jnp.arange(T)
+    if seq_tp:
         nc = ctx.model_size
         chunk = S // nc
         qb = constrain(qg.reshape(B, nc, chunk, Hkv, G, hd), {0: "batch", 1: "model"})

@@ -41,6 +41,12 @@ OPTIMIZER = "optimizer"
 #: (not the Q/K/V/O projections): what a flash kernel replaces.  Training
 #: and serving alike.
 ATTENTION = "attention"
+#: ``name=`` of the flash attention kernels' three ``pallas_call``s
+#: (forward; backward dQ and dK/dV), under ``attention``.
+FLASH_FWD = "flash_fwd"
+FLASH_DQ = "flash_dq"
+FLASH_DKV = "flash_dkv"
+FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 #: ``name=`` of the comm_pack kernels' two ``pallas_call``s.
 COMM_PACK_PACK = "comm_pack_pack"
 COMM_PACK_UNPACK = "comm_pack_unpack"

@@ -6,7 +6,9 @@ alignment, VMEM limits), so these tests compile ``pack_arena_pallas`` and
 ``v5e:2x2`` topology — no chip attached — at tinyllama-1.1b's real group
 sizes (the largest and smallest group of the plan ``chip_smoke.py``
 trains under) and at one ragged group, and check that the compiled
-module holds the Mosaic kernel (``tpu_custom_call``).  A data-parallel
+module holds the Mosaic kernel (``tpu_custom_call``).  The flash
+attention kernels compile, forward and backward, at the starcoder2-3b
+benchmark cell's attention shape.  A data-parallel
 train step compiled for the four chips keeps one all-reduce per schedule
 group: the TPU's all-reduce combiner would merge them all into one.
 
@@ -16,6 +18,9 @@ would break parallel test workers.  All such tests live in this file.
 """
 
 import os
+import pathlib
+import re
+import sys
 
 import pytest
 
@@ -23,8 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import scopes
+from repro.core import profiler
 from repro.core.bucketing import tree_get
 from repro.kernels.comm_pack import pack_arena_pallas, unpack_arena_pallas
+from repro.kernels.flash_attention import flash_attention_train
 from repro.launch import train
 from repro.launch.specs import param_specs
 
@@ -177,3 +185,32 @@ def test_dp_step_keeps_one_allreduce_per_group_on_four_chips(four_chips, monkeyp
     assert n_allreduce() == n_groups + 1
     monkeypatch.setattr(trainer, "KEEP_GROUPS_APART", None)
     assert n_allreduce() < n_groups
+
+
+def test_flash_kernels_compile_for_v5e_at_the_cell_shape(one_chip):
+    """Forward and backward of ``flash_attention_train`` in bf16 at the
+    sc2-3b.train.dp1 cell's attention (1 x 4096 tokens, 24 query heads over
+    2 KV heads of 128): Mosaic takes the bf16 tiles and their VMEM, the
+    module holds the three named kernels, and none of them looks like a
+    comm_pack kernel to the benchmark's trace reader."""
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from bench.trace import Op, is_comm_pack
+
+    q = jax.ShapeDtypeStruct((1, 4096, 24, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 2, 128), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_bwd(q, k, v):
+        def attend(*a):
+            with jax.named_scope(scopes.ATTENTION):  # as models/layers names it
+                return flash_attention_train(*a, True, None, None)
+
+        o, pullback = jax.vjp(attend, q, k, v)
+        return o, pullback(o)
+
+    text = jax.jit(fwd_bwd).lower(q, kv, kv).compile().as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    paths = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert sorted(next(c for c in p.split("/") if c in scopes.FLASH_KERNELS) for p in paths) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    assert all(profiler.is_attention(p) and profiler.is_flash(p) for p in paths)
+    assert not any(is_comm_pack(Op(0, 1, line.strip())) for line in calls)

@@ -235,3 +235,77 @@ def test_flash_attention_fwd_lse():
     s = jnp.where(mask[None, None], s, -jnp.inf)
     lse_ref = jax.nn.logsumexp(s, axis=-1).transpose(0, 2, 1)  # (B, S, H)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref), rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# flash attention in bf16: several q and k blocks, so skipped tiles, clamped
+# index maps and diagonal / window-edge tiles all run
+# ---------------------------------------------------------------------------
+
+
+def _bf16_qkv(seed, B, S, Hq, Hkv, hd):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v, w = (jax.random.normal(kk, (B, S, h, hd), jnp.float32).astype(jnp.bfloat16)
+                  for kk, h in zip(ks, (Hq, Hkv, Hkv, Hq)))
+    return q, k, v, w
+
+
+def assert_bf16_close(got, want, name=""):
+    """Within bf16 rounding: every element to 2e-2 of the reference's
+    largest magnitude, and the whole to 1e-2 in norm."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = float(np.max(np.abs(want)))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-2 * scale, err_msg=name)
+    assert np.linalg.norm(got - want) <= 1e-2 * np.linalg.norm(want), name
+
+
+@pytest.mark.parametrize(
+    "S,Hq,Hkv,hd,causal,window,bq,bk",
+    [
+        (512, 4, 2, 64, True, None, 128, 128),  # 4x4 tiles, 6 skipped
+        (512, 4, 1, 128, True, None, 256, 128),  # MQA, q blocks larger than k
+        (512, 6, 2, 64, True, None, 128, 256),  # k blocks larger than q
+        (512, 4, 2, 64, True, 200, 128, 128),  # window: tiles skipped on both sides
+        (512, 4, 4, 64, False, 160, 128, 128),  # window without causal
+        (384, 6, 2, 128, False, None, 128, 128),  # no skipping at all
+    ],
+)
+def test_flash_attention_fwd_bf16(S, Hq, Hkv, hd, causal, window, bq, bk):
+    q, k, v, _ = _bf16_qkv(11, 1, S, Hq, Hkv, hd)
+    out = flash_attention_fwd(q, k, v, causal=causal, window=window, block_q=bq,
+                              block_k=bk, interpret=True)
+    assert out.dtype == jnp.bfloat16
+    ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+                        causal=causal, window=window)
+    assert_bf16_close(out, ref)
+
+
+@pytest.mark.parametrize(
+    "S,Hq,Hkv,hd,causal,window,softcap",
+    [
+        (1536, 4, 2, 64, True, None, None),  # 3x3 blocks of 512: 3 tiles skipped
+        (1536, 4, 2, 64, True, 256, None),  # and the window skips tile (2, 0)
+        (1024, 6, 2, 128, True, 700, 30.0),  # G = 3, window edge and softcap
+        (1024, 2, 1, 64, False, None, None),  # nothing skipped, GQA sum
+    ],
+)
+def test_flash_attention_train_bf16_grads(S, Hq, Hkv, hd, causal, window, softcap):
+    from repro.kernels.flash_attention import flash_attention_train
+
+    q, k, v, w = _bf16_qkv(12, 1, S, Hq, Hkv, hd)
+    wf = w.astype(jnp.float32)
+
+    def loss_kernel(q, k, v):
+        o = flash_attention_train(q, k, v, causal, window, softcap, True)
+        return jnp.sum(o.astype(jnp.float32) * wf)
+
+    def loss_ref(q, k, v):
+        o = attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)
+        return jnp.sum(o * wf)
+
+    gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, b, name in zip(gk, gr, ("dq", "dk", "dv")):
+        assert a.dtype == jnp.bfloat16, name
+        assert_bf16_close(a, b, name)

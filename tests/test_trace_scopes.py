@@ -283,6 +283,37 @@ def test_layer_split_by_hand():
     assert got["attention_ms"] == pytest.approx((10 + 5 + 5 + 20) * ms)
 
 
+FLASH_HLO = """\
+HloModule jit_body, entry_computation_layout={()->()}
+
+ENTRY %main (p: bf16[24,512,128]) -> bf16[24,512,128] {
+  %p = bf16[24,512,128]{2,1,0} parameter(0)
+  %flash_fwd.1 = (bf16[24,512,128]{2,1,0}, f32[24,512,128]{2,1,0}) custom-call(bf16[24,512,128]{2,1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/attention/flash_fwd/pallas_call"}
+  %transpose.2 = bf16[24,512,128]{2,1,0} transpose(bf16[24,512,128]{2,1,0} %p), dimensions={0,1,2}, metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/attention/transpose"}
+  %flash_fwd.3 = (bf16[24,512,128]{2,1,0}, f32[24,512,128]{2,1,0}) custom-call(bf16[24,512,128]{2,1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/attention/flash_fwd/pallas_call"}
+  %flash_dq.4 = bf16[24,512,128]{2,1,0} custom-call(bf16[24,512,128]{2,1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/attention/flash_dq/pallas_call"}
+  %flash_dkv.5 = (bf16[2,512,128]{2,1,0}, bf16[2,512,128]{2,1,0}) custom-call(bf16[24,512,128]{2,1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/attention/flash_dkv/pallas_call"}
+  ROOT %dot.6 = bf16[24,512,128]{2,1,0} dot(bf16[24,512,128]{2,1,0} %p, bf16[24,512,128]{2,1,0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/dot_general"}
+}
+"""
+
+
+def test_layer_split_counts_the_flash_kernels():
+    """``attention_flash_ms`` is the attention time inside the three flash
+    kernels, forward, recompute and backward; attention's other ops (the
+    layout changes around them) count in ``attention_ms`` alone."""
+    ops = [(0, 0, s, e, n) for n, s, e in [
+        ("flash_fwd.1", 0, 10), ("transpose.2", 10, 12), ("dot.6", 12, 20),
+        ("flash_fwd.3", 20, 30), ("flash_dq.4", 30, 45), ("flash_dkv.5", 45, 65)]]
+    got = profiler.layer_split(ops, FLASH_HLO)
+    ms = 1e-6
+    assert got["attention_ms"] == pytest.approx((10 + 2 + 10 + 15 + 20) * ms)
+    assert got["attention_flash_ms"] == pytest.approx((10 + 10 + 15 + 20) * ms)
+    assert got["forward_ms"] == pytest.approx(20 * ms)
+    assert got["remat_ms"] == pytest.approx(10 * ms)
+    assert got["backward_ms"] == pytest.approx(35 * ms)
+
+
 def test_layer_split_of_a_program_without_scopes_is_none():
     bare = "\n".join(line.split(", metadata=")[0] for line in LAYER_HLO.splitlines())
     assert profiler.layer_split(layer_ops(), bare) is None
@@ -391,7 +422,9 @@ def test_dryrun_prints_the_layers_of_its_trace(dryruns):
     with four replicas the wire has time of its own."""
     out, _, _ = dryruns
     for issue, (_, _, layers) in out.items():
-        assert set(layers) == {f"{k}_ms" for k in profiler.LAYERS} | {"attention_ms", "busy_ms"}
+        assert set(layers) == {f"{k}_ms" for k in profiler.LAYERS} | {
+            "attention_ms", "attention_flash_ms", "busy_ms"}
+        assert layers["attention_flash_ms"] == 0  # the CPU runs the jnp attention
         assert sum(layers[f"{k}_ms"] for k in profiler.LAYERS) == pytest.approx(layers["busy_ms"])
         for k in ("wire", "backward", "forward", "optimizer"):
             assert layers[f"{k}_ms"] > 0, (issue, k)
