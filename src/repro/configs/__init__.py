@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs (+ reduced smoke variants)
+"""Architecture registry: the 10 assigned archs and SDAR-30B-A3B (+ reduced smoke variants)
 and the paper's own CNN layer profiles (GoogleNet / ResNet-50).
 
 ``get_config(name)`` returns the full ArchConfig; ``get_reduced(name)`` a
@@ -23,6 +23,7 @@ _MODULES = {
     "rwkv6-7b": "rwkv6_7b",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "qwen2-vl-2b": "qwen2_vl_2b",
+    "sdar-30b-a3b": "sdar_30b_a3b",
 }
 
 ARCH_NAMES = tuple(_MODULES)

@@ -38,5 +38,4 @@ def reduced() -> ArchConfig:
         attention=Attention(n_heads=4, n_kv_heads=2, head_dim=32, window=64),
         moe=MoE(n_experts=4, top_k=2),
         q_chunk=32,
-        moe_token_chunk=256,
     )

@@ -38,6 +38,7 @@ LONG_CONTEXT_SKIP = frozenset(
         "starcoder2-3b",
         "dbrx-132b",
         "qwen2-vl-2b",
+        "sdar-30b-a3b",
     }
 )
 

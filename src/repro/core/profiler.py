@@ -581,27 +581,42 @@ def is_flash(op_name: str) -> bool:
     return any(_core(c) in scopes.FLASH_KERNELS for c in op_name.split("/")) if op_name else False
 
 
+def is_moe(op_name: str) -> bool:
+    """An op of the held-expert MoE layer (a component ``moe`` or one that
+    wraps it): routing, dispatch, the experts and the combine."""
+    return any(_core(c) == scopes.MOE for c in op_name.split("/")) if op_name else False
+
+
+def is_moe_gmm(op_name: str) -> bool:
+    """An op of the MoE grouped-matmul kernels (``moe_gmm``, ``moe_tgmm``:
+    the ``name=`` of their ``pallas_call``)."""
+    return any(_core(c) in scopes.MOE_KERNELS for c in op_name.split("/")) if op_name else False
+
+
 def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
     """Device milliseconds per step of each of :data:`LAYERS`
     (``<layer>_ms``), of attention (``attention_ms``), of attention inside
-    the flash kernels (``attention_flash_ms``) and of all busy time
+    the flash kernels (``attention_flash_ms``), of the MoE layer
+    (``moe_ms``) and of its grouped-matmul kernels (``moe_gmm_ms``), and of
+    all busy time
     (``busy_ms``), averaged over devices and steps, from executed ops
     ``(device, run, start_ns, end_ns, instruction)`` (:func:`trace_ops`) of
     the compiled module ``hlo_text``.  Where ops of several layers run at
     once (XLA:CPU runs them side by side; a chip seldom does) the time
     counts once, for the first of them in :data:`LAYERS`, so the layers add
-    up to the busy time.  Attention cuts across the layers: the union of
-    its ops' intervals.  None where no op carries a train-step scope."""
+    up to the busy time.  Attention and the MoE layer cut across the
+    layers: the union of their ops' intervals.  None where no op carries a
+    train-step scope."""
     names = hlo_op_names(hlo_text)
     allreduces = hlo_allreduces(hlo_text)
-    runs: dict[tuple[int, int], list[tuple[int, int, int, bool, bool]]] = {}
+    runs: dict[tuple[int, int], list[tuple]] = {}
     for dev, run, start, end, op in ops:
         path = names.get(op, "")
         runs.setdefault((dev, run), []).append(
             (start, end, LAYERS.index(op_layer(path, op in allreduces)), is_attention(path),
-             is_flash(path)))
+             is_flash(path), is_moe(path), is_moe_gmm(path)))
     total = [0.0] * len(LAYERS)
-    attention = flash = 0.0
+    attention = flash = moe = gmm = 0.0
     for tagged in runs.values():
         edges = sorted([(s, 1, k) for s, e, k, *_ in tagged if e > s]
                        + [(e, -1, k) for s, e, k, *_ in tagged if e > s])
@@ -613,14 +628,16 @@ def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
                     total[first] += t - prev
             active[k] += d
             prev = t
-        attention += _union_len([(s, e) for s, e, _, a, _ in tagged if a])
-        flash += _union_len([(s, e) for s, e, _, a, f in tagged if a and f])
+        attention += _union_len([(s, e) for s, e, _, a, *_ in tagged if a])
+        flash += _union_len([(s, e) for s, e, _, a, f, *_ in tagged if a and f])
+        moe += _union_len([(s, e) for s, e, *_, m, _ in tagged if m])
+        gmm += _union_len([(s, e) for s, e, *_, g in tagged if g])
     if not any(total[LAYERS.index(k)] for k in ("remat", "backward", "forward", "optimizer")):
         return None
     per = 1e-6 / len(runs)  # ns summed over (device, step) -> ms per step
     out = {f"{k}_ms": v * per for k, v in zip(LAYERS, total)}
     return out | {"attention_ms": attention * per, "attention_flash_ms": flash * per,
-                  "busy_ms": sum(total) * per}
+                  "moe_ms": moe * per, "moe_gmm_ms": gmm * per, "busy_ms": sum(total) * per}
 
 
 def scope_layers(xplane_path, hlo_text: str) -> dict[str, float] | None:

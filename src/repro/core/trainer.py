@@ -34,6 +34,7 @@ import numpy as np
 from .. import scopes
 from ..models import loss_fn, staged_loss_fns
 from ..models.common import ArchConfig
+from ..models.moe import sum_aux
 from ..optim.optimizers import Optimizer
 from ..planning import AnalyticCosts, CostSource, build_plan, replan_if_drifted
 from ..planning import build_schedule as _registry_build_schedule
@@ -88,16 +89,16 @@ def lm_unit_costs(
 
     t = tokens_per_device
     units = [cost("embed", embed_p, 2.0 * t * cfg.d_model, 2.0 * t * cfg.d_model)]
-    active = 1.0
+    # weights a token multiplies through in one stage: all of them, but of
+    # the held experts only the expected share, top_k·held/E experts' worth
+    # (top_k/E of the held experts' weights)
+    active_p = stage_p
     if cfg.moe is not None:
-        # only top-k of E experts run per token
-        active = cfg.moe.top_k / cfg.moe.n_experts
-        # attn part of the stage is dense; approximate with the active mix
-        active = 0.25 + 0.75 * active if active < 1 else 1.0
+        expert_p = sum(_tree_size(w) for sub in param_shapes["stages"].values() if "moe" in sub
+                       for name, w in sub["moe"].items() if name != "router") // cfg.n_stages
+        active_p = stage_p - expert_p + expert_p * cfg.moe.top_k / cfg.moe.n_experts
     for i in range(cfg.n_stages):
-        units.append(
-            cost(f"stage_{i}", stage_p, 4.0 * stage_p * t * active, 2.0 * stage_p * t * active)
-        )
+        units.append(cost(f"stage_{i}", stage_p, 4.0 * active_p * t, 2.0 * active_p * t))
     if tail_p:
         units.append(cost("tail", tail_p, 4.0 * tail_p * t, 2.0 * tail_p * t))
     head_flops_p = norm_p + cfg.d_model * cfg.vocab  # tied: head matmul still runs
@@ -200,6 +201,17 @@ class MGWFBPEngine:
 
     def dp_world(self, mesh) -> int:
         return int(np.prod([mesh.shape[ax] for ax in self.dp_axes]))
+
+    def _moe_counters(self, metrics: dict) -> dict:
+        """An MoE model's step counters over all replicas: the rows routed
+        to held experts, summed over layers (``moe_held_rows``), and the
+        most rows one held expert took (``moe_max_expert_rows``).  None
+        for a dense model."""
+        if self.cfg.moe is None:
+            return {}
+        return {"moe_held_rows": jax.lax.psum(metrics["moe_held_rows"], self.dp_axes),
+                "moe_max_expert_rows": jax.lax.pmax(metrics["moe_max_expert_rows"],
+                                                    self.dp_axes)}
 
     def _jit_step(self, smapped, mesh, donate_argnums):
         """``jax.jit`` of a train-step body, with the all-reduce combiners
@@ -399,7 +411,8 @@ class MGWFBPEngine:
                 with jax.named_scope(scopes.OPTIMIZER):
                     new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
                 l = jax.lax.pmean(l, self.dp_axes)
-                return new_params, new_opt, new_residual, {"loss": l}
+                counters = self._moe_counters(metrics)
+                return new_params, new_opt, new_residual, {"loss": l, **counters}
 
             smapped = jax.shard_map(
                 body_ef,
@@ -417,7 +430,7 @@ class MGWFBPEngine:
             with jax.named_scope(scopes.OPTIMIZER):
                 new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
             l = jax.lax.pmean(l, self.dp_axes)
-            return new_params, new_opt, {"loss": l}
+            return new_params, new_opt, {"loss": l, **self._moe_counters(metrics)}
 
         smapped = jax.shard_map(
             body,
@@ -462,7 +475,7 @@ class MGWFBPEngine:
                     (x, aux), pb_tail = jax.vjp(tail_fn, params["tail"], x)
                 aux_parts.append(aux)
             with jax.named_scope(scopes.FWD_HEAD):
-                aux_total = sum(aux_parts)
+                aux_total = sum_aux(aux_parts)
                 head_p = {"final_norm": params["final_norm"]}
                 if not cfg.tie_embeddings:
                     head_p["head"] = params["head"]
@@ -524,7 +537,8 @@ class MGWFBPEngine:
                 with jax.named_scope(scopes.OPTIMIZER):
                     new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
                 l = jax.lax.pmean(l, self.dp_axes)
-                return new_params, new_opt, new_residual, {"loss": l}
+                counters = self._moe_counters(metrics)
+                return new_params, new_opt, new_residual, {"loss": l, **counters}
 
             smapped = jax.shard_map(
                 body_ef,
@@ -541,7 +555,7 @@ class MGWFBPEngine:
             with jax.named_scope(scopes.OPTIMIZER):
                 new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
             l = jax.lax.pmean(l, self.dp_axes)
-            return new_params, new_opt, {"loss": l}
+            return new_params, new_opt, {"loss": l, **self._moe_counters(metrics)}
 
         smapped = jax.shard_map(
             body,

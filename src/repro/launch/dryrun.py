@@ -104,12 +104,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, fsdp_data=True,
     rules = rules_for_arch(cfg, mesh, fsdp_data=fsdp_data)
     n_dev = mesh.devices.size
 
-    # GShard groups: multiple of the token-shard count, tg ~ 4096
-    from repro.launch.specs import moe_groups_for
-    seq_for_groups = shape.seq_len if shape.kind != "decode" else 1
-    cfg = dataclasses.replace(
-        cfg, moe_groups=moe_groups_for(rules, shape.global_batch, seq_for_groups)
-    )
     rec = {
         "arch": arch,
         "shape": shape_name,
@@ -299,11 +293,6 @@ def segment_costs(arch: str, shape_name: str, mesh, rules, overrides=None) -> di
     if overrides:
         cfg = dataclasses.replace(cfg, **{k: v for k, v in overrides.items()
                                           if k != "chunk_impl"})
-    from repro.launch.specs import moe_groups_for
-    seq_for_groups = shape.seq_len if shape.kind != "decode" else 1
-    cfg = dataclasses.replace(
-        cfg, moe_groups=moe_groups_for(rules, shape.global_batch, seq_for_groups)
-    )
     B, S = shape.global_batch, shape.seq_len
     out = {}
     if shape.kind == "train":

@@ -113,7 +113,7 @@ def stage_train_segment(
             return y, aux
 
         (y, aux), vjp = jax.vjp(f, stage_p, x)
-        dsp, dx = vjp((dy, jnp.zeros((), jnp.float32)))
+        dsp, dx = vjp((dy, jax.tree.map(jnp.zeros_like, aux)))
         return y, dsp, dx
 
     with jax.set_mesh(mesh):

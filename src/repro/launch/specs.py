@@ -56,37 +56,15 @@ def decode_input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict:
     return {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32)}
 
 
-def moe_groups_for(rules, global_batch: int, seq_len: int, target_tg: int = 4096) -> int:
-    """GShard group count: a multiple of the token-shard count keeping
-    tokens-per-group near ``target_tg`` — the dispatch one-hot einsums
-    cost 2·T·E·C·D with C ∝ tg, so large groups make dispatch dominate
-    expert compute (tg/3F ratio; see models/moe.py)."""
-    ba = rules.batch_axes(global_batch)
-    shards = rules._axes_size(ba) if ba else 1
-    tokens = global_batch * seq_len
-    per_shard = tokens // shards
-    m = max(1, per_shard // target_tg)
-    while m > 1 and per_shard % m != 0:
-        m -= 1
-    return shards * m
-
-
 def arch_config_for_shape(arch: str, shape_name: str, cost_mode: bool = False) -> ArchConfig:
     """Config tuned per shape: chunk sizes that bound dry-run memory in
     'map' mode, or 'unroll' for exact cost accounting in segments."""
     shape = SHAPES[shape_name]
     cfg = get_config(arch)
     q_chunk = 512 if shape.kind == "train" else 2048
-    # the model sees *global* shapes under GSPMD: chunk counts must be set
-    # from global token counts (map: ~16 chunks bounds per-chunk buffers;
-    # unroll: ~4 keeps the cost-mode HLO small enough to compile)
-    global_tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
-    n_chunks = 4 if cost_mode else 16
-    moe_chunk = max(2048, global_tokens // n_chunks)
     overrides = dict(
         q_chunk=min(q_chunk, shape.seq_len),
         chunk_impl="unroll" if cost_mode else "map",
-        moe_token_chunk=min(moe_chunk, global_tokens),
         rec_chunk=128,
         remat="full" if shape.kind == "train" else "none",
     )

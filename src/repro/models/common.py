@@ -26,11 +26,23 @@ class Attention:
 
 @dataclasses.dataclass(frozen=True)
 class MoE:
-    """Mixture-of-experts options (None on the config = dense FFN)."""
+    """Mixture-of-experts options (None on the config = dense FFN).
+
+    The router spans all ``n_experts``; this device holds ``held`` of them
+    (all by default), ids ``first .. first + held - 1``: under expert
+    parallelism the others lie on further devices, and each layer
+    computes only the held experts' part (``models/moe``).  The top-k
+    gates are renormalised to sum to 1."""
 
     n_experts: int
     top_k: int
-    capacity_factor: float = 1.25
+    held: int | None = None
+    first: int = 0
+    aux_coef: float = 0.01  # weight of the load-balancing term in the loss
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.held is None else self.held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,8 +84,6 @@ class ArchConfig:
     local_window: int = 4096
     # implementation knobs (not architecture):
     q_chunk: int = 256  # query chunk for the jnp attention fallback
-    moe_groups: int = 1  # GShard dispatch groups (= token shards in prod)
-    moe_token_chunk: int = 2048  # legacy knob (grouped dispatch supersedes)
     rec_chunk: int = 128  # time chunk for chunked linear recurrences
     chunk_impl: str = "map"  # 'map' (memory-realistic) | 'unroll' (exact cost)
     remat: str = "full"  # 'full' | 'dots' | 'none'

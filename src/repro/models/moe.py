@@ -1,19 +1,28 @@
-"""Mixture-of-Experts FFN: top-k routing with GShard-style *grouped*
-capacity dispatch [arXiv:2006.16668].
+"""Mixture-of-Experts FFN: the held-expert, dropless layer.
 
-Tokens are laid out as (G groups, T/G tokens); each group routes its own
-tokens into per-group expert buffers with capacity C = (T/G)·k·cf/E, so
-the dispatch one-hot is (G, T/G, E, C) — linear in total tokens for a
-fixed group size.  The launcher sets G to the number of token shards:
+The router spans all ``n_experts``, and this device computes the part of
+the layer that its ``held`` experts (ids ``first .. first + held - 1``)
+give, for every (token, choice) pair routed to them.  Under expert
+parallelism the other experts lie on further devices; their part is left
+out here, and the partial result goes on to the next layer.  Training,
+prefill and decode all run :func:`held_moe_block`, whose steps are each
+under their own scope:
 
-  * every group is then shard-local (no cross-shard reductions in the
-    dispatch einsums), and
-  * with experts sharded over 'model' (EP — dbrx: 16 experts on the
-    16-way axis) the (G@batch, E@model) buffer resharding lowers to the
-    classic MoE all-to-all; without EP (mixtral: 8 experts don't divide
-    16) expert weights are FSDP-gathered per layer instead.
+1. ``moe_route``: router logits in float32 over all experts, softmax,
+   top-k, the top-k gates renormalised to sum to 1;
+2. ``moe_dispatch``: the (token, choice) pairs sorted by expert, the held
+   experts' first, and their tokens gathered into a buffer of
+   T·min(k, held) rows: the most that can land on the held experts, so
+   no pair is dropped;
+3. ``moe_experts``: the held experts' SwiGLU as grouped matmuls over
+   those rows (``kernels/grouped_matmul``: Pallas kernels on a TPU, whose
+   grids stop at the last routed row);
+4. ``moe_combine``: each token's expert rows summed back, weighted by
+   their gates.
 
-G=1 for smoke tests / single device.
+The gathers of steps 2 and 4 carry their own gradients, which are
+gathers too: the transpose of each is the other's pattern, so backward
+scatters nothing.
 """
 
 from __future__ import annotations
@@ -21,86 +30,178 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.context import constrain
+from .. import scopes
+from ..kernels.grouped_matmul import grouped_matmul
 from .common import ArchConfig, MoE, truncated_normal
+
+#: The per-layer statistics of the held-expert layer, summed over layers
+#: (``max_expert_rows``: the largest, see :func:`add_aux`).
+AUX_KEYS = ("loss", "held_rows", "max_expert_rows")
 
 
 def init_moe(key, cfg: ArchConfig) -> dict:
     moe = cfg.moe
-    d, f, e = cfg.d_model, cfg.d_ff, moe.n_experts
+    d, f, e = cfg.d_model, cfg.d_ff, moe.n_held
     ks = jax.random.split(key, 4)
     std_in, std_out = d ** -0.5, f ** -0.5
     return {
-        "router": truncated_normal(ks[0], (d, e), jnp.float32, std_in),
+        "router": truncated_normal(ks[0], (d, moe.n_experts), jnp.float32, std_in),
         "w_gate": truncated_normal(ks[1], (e, d, f), cfg.param_dtype, std_in),
         "w_up": truncated_normal(ks[2], (e, d, f), cfg.param_dtype, std_in),
         "w_down": truncated_normal(ks[3], (e, f, d), cfg.param_dtype, std_out),
     }
 
 
-def _capacity(tokens_per_group: int, moe: MoE) -> int:
-    c = int(tokens_per_group * moe.top_k * moe.capacity_factor / moe.n_experts)
-    return max(4, min(tokens_per_group, (c + 3) // 4 * 4))
+# ---------------------------------------------------------------------------
+# Held-expert, dropless layer
+# ---------------------------------------------------------------------------
 
 
-def moe_block(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out, aux_loss)."""
+def route(p: dict, x: jax.Array, moe: MoE) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``x`` (T, D) -> the top-k gates (T, k) f32, expert ids (T, k) and
+    the load-balancing term (0 where ``aux_coef`` is 0)."""
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ p["router"], axis=-1)
+    gates, idx = jax.lax.top_k(probs, moe.top_k)
+    gates = gates / jnp.sum(gates, -1, keepdims=True)
+    if moe.aux_coef == 0:
+        return gates, idx, jnp.zeros((), jnp.float32)
+    frac = jnp.mean(jnp.sum(jax.nn.one_hot(idx, moe.n_experts, dtype=jnp.float32), axis=1), 0)
+    return gates, idx, moe.n_experts * jnp.sum(frac * jnp.mean(probs, 0))
+
+
+def sort_rows(idx: jax.Array, moe: MoE):
+    """The buffer order of the (token, choice) pairs ``idx`` (T, k) routes.
+
+    Returns ``(token_of, slot_of, sizes)``: for each of the buffer's
+    T·min(k, held) rows the token it holds (rows past the held ones hold
+    some token, and are never read back); for each pair its row, or the
+    buffer's length where its expert is not held; and the rows of each
+    held expert (int32), which fill the buffer from its start, in expert
+    order."""
+    T, k = idx.shape
+    H = moe.n_held
+    M = T * min(k, H)
+    local = idx.reshape(-1) - moe.first
+    held = (local >= 0) & (local < H)
+    key = jnp.where(held, local, H)
+    order = jnp.argsort(key, stable=True)[:M]
+    slot = jnp.zeros(T * k, jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
+    slot_of = jnp.where(held, slot, M).reshape(T, k)
+    sizes = jnp.sum(key[:, None] == jnp.arange(H)[None, :], axis=0, dtype=jnp.int32)
+    return (order // k).astype(jnp.int32), slot_of, sizes
+
+
+def _rows_of(y: jax.Array, slot_of: jax.Array) -> jax.Array:
+    """(T, k, D): row ``slot_of`` of ``y``, zeros where it is ``len(y)``."""
+    g = jnp.take(y, slot_of, axis=0, mode="clip")
+    return jnp.where((slot_of < y.shape[0])[..., None], g, 0)
+
+
+@jax.custom_vjp
+def dispatch(x: jax.Array, token_of: jax.Array, slot_of: jax.Array) -> jax.Array:
+    """The buffer: row ``r`` is token ``token_of[r]`` of ``x`` (T, D)."""
+    return jnp.take(x, token_of, axis=0, mode="clip")
+
+
+def _dispatch_fwd(x, token_of, slot_of):
+    return dispatch(x, token_of, slot_of), slot_of
+
+
+def _dispatch_bwd(slot_of, dxs):
+    # each token's gradient: the sum of its held rows' gradients
+    dx = jnp.sum(_rows_of(dxs, slot_of).astype(jnp.float32), axis=1)
+    return dx.astype(dxs.dtype), None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(y: jax.Array, gates: jax.Array, token_of: jax.Array, slot_of: jax.Array):
+    """(T, D): each token's held rows of ``y`` (M, D), weighted by their
+    ``gates`` (T, k) and summed in f32, in ``y``'s dtype."""
+    rows = _rows_of(y, slot_of).astype(jnp.float32)
+    return jnp.sum(rows * gates[..., None], axis=1).astype(y.dtype)
+
+
+def _combine_fwd(y, gates, token_of, slot_of):
+    return combine(y, gates, token_of, slot_of), (y, gates, token_of, slot_of)
+
+
+def _combine_bwd(res, dout):
+    y, gates, token_of, slot_of = res
+    M = y.shape[0]
+    # the gate of each buffer row (0 past the held rows), then the rows'
+    # gradient: its token's output gradient times its gate
+    row_gate = jnp.zeros(M + 1, jnp.float32).at[slot_of.reshape(-1)].set(
+        gates.reshape(-1))[:M]
+    dy = (jnp.take(dout, token_of, axis=0, mode="clip").astype(jnp.float32)
+          * row_gate[:, None])
+    dgates = jnp.sum(_rows_of(y, slot_of).astype(jnp.float32)
+                     * dout.astype(jnp.float32)[:, None, :], axis=-1)
+    return dy.astype(y.dtype), dgates, None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def held_moe_block(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
+    """x: (B, S, D) -> (the held experts' part of the layer, its
+    statistics: the load-balancing term, the rows routed to held experts,
+    the most rows one held expert took)."""
     moe = cfg.moe
     B, S, D = x.shape
-    T = B * S
-    G = cfg.moe_groups if T % cfg.moe_groups == 0 else 1
-    tg = T // G
-    C = _capacity(tg, moe)
-    E = moe.n_experts
+    xt = x.reshape(B * S, D)
+    with jax.named_scope(scopes.MOE):
+        with jax.named_scope(scopes.MOE_ROUTE):
+            gates, idx, aux = route(p, xt, moe)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            token_of, slot_of, sizes = sort_rows(idx, moe)
+            xs = dispatch(xt, token_of, slot_of)
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            h = jax.nn.silu(grouped_matmul(xs, p["w_gate"], sizes))
+            h = h * grouped_matmul(xs, p["w_up"], sizes)
+            ys = grouped_matmul(h, p["w_down"], sizes)
+        with jax.named_scope(scopes.MOE_COMBINE):
+            out = combine(ys, gates, token_of, slot_of)
+    stats = {"loss": aux, "held_rows": jnp.sum(sizes).astype(jnp.float32),
+             "max_expert_rows": jnp.max(sizes).astype(jnp.float32)}
+    return out.reshape(B, S, D), stats
 
-    # decode-EP: at tiny token counts, gathering the data-dim shards of the
-    # expert tables per step is the cost (GB/token); instead replicate the
-    # few tokens and shard the weight-CONTRACTION dims over the data axes —
-    # every resulting psum is activation-sized (KB at decode shapes).
-    decode_ep = T <= 4096 and E >= 2
-    if decode_ep:
-        xt = constrain(x.reshape(G, tg, D), {2: "data"})
-    else:
-        xt = constrain(x.reshape(G, tg, D), {0: "batch"})
-    logits = xt.astype(jnp.float32) @ p["router"]  # (G, tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, moe.top_k)  # (G, tg, k)
-    gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
-    # position of each (token, k) choice within its expert's capacity
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # (G, tg, k, E)
-    flat = onehot.reshape(G, tg * moe.top_k, E)
-    pos = (jnp.cumsum(flat, axis=1) - flat).reshape(G, tg, moe.top_k, E)
-    pos = jnp.sum(pos * onehot, axis=-1)  # (G, tg, k)
-    keep = pos < C
+# ---------------------------------------------------------------------------
+# The layers' statistics through the stage scans
+# ---------------------------------------------------------------------------
 
-    disp = (
-        jax.nn.one_hot(gate_idx, E, dtype=x.dtype)[..., None]
-        * jax.nn.one_hot(jnp.where(keep, pos, C), C + 1, dtype=x.dtype)[..., None, :-1]
-    )  # (G, tg, k, E, C)
-    combine = jnp.sum(disp * gate_vals[..., None, None].astype(x.dtype), axis=2)
-    disp = jnp.sum(disp, axis=2)  # (G, tg, E, C)
 
-    # dispatch -> (G, E, C, D); EP reshards G@batch -> E@model (all-to-all)
-    if decode_ep:
-        xe = constrain(jnp.einsum("gtec,gtd->gecd", disp, xt), {1: "expert", 3: "data"})
-        h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe, p["w_gate"]))
-        h = constrain(
-            h * jnp.einsum("gecd,edf->gecf", xe, p["w_up"]), {1: "expert", 3: "data"}
-        )
-        ye = constrain(jnp.einsum("gecf,efd->gecd", h, p["w_down"]), {1: "expert"})
-    else:
-        xe = constrain(jnp.einsum("gtec,gtd->gecd", disp, xt), {0: "batch", 1: "expert"})
-        h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe, p["w_gate"]))
-        h = constrain(
-            h * jnp.einsum("gecd,edf->gecf", xe, p["w_up"]), {0: "batch", 1: "expert"}
-        )
-        ye = constrain(jnp.einsum("gecf,efd->gecd", h, p["w_down"]), {0: "batch", 1: "expert"})
-    out = jnp.einsum("gecd,gtec->gtd", ye, combine)
-    out = constrain(out, {0: "batch"}).reshape(B, S, D)
+def zero_aux(cfg: ArchConfig):
+    """A layer's statistics where it has no experts: the dense models'
+    scalar 0, a dict of zeros (:data:`AUX_KEYS`) in an MoE model."""
+    if cfg.moe is None:
+        return jnp.zeros((), jnp.float32)
+    return {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
 
-    # load-balance aux (Switch/GShard)
-    frac = jnp.mean(jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.float32), axis=2), axis=(0, 1))
-    mean_prob = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac * mean_prob)
-    return out, aux
+
+def add_aux(a, b):
+    """Two layers' statistics together: sums, ``max_expert_rows`` the
+    larger."""
+    if not isinstance(a, dict):
+        return a + b
+    return {k: jnp.maximum(a[k], b[k]) if k == "max_expert_rows" else a[k] + b[k] for k in a}
+
+
+def reduce_aux(aux):
+    """The statistics of layers stacked on a leading axis (a scan's)."""
+    if not isinstance(aux, dict):
+        return jnp.sum(aux)
+    return {k: jnp.max(v) if k == "max_expert_rows" else jnp.sum(v) for k, v in aux.items()}
+
+
+def sum_aux(parts: list):
+    """The statistics of several scans and tails together."""
+    if not isinstance(parts[0], dict):
+        return sum(parts)
+    out = parts[0]
+    for p in parts[1:]:
+        out = add_aux(out, p)
+    return out

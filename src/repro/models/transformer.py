@@ -41,13 +41,34 @@ from .layers import (
     sinusoidal_embedding,
     softcap_logits,
 )
-from .moe import init_moe, moe_block
+from .moe import (
+    add_aux,
+    held_moe_block,
+    init_moe,
+    reduce_aux,
+    sum_aux,
+    zero_aux,
+)
 from .rglru import init_rglru_block, init_rglru_state, rglru_block
 from .rwkv6 import init_rwkv6_block, init_rwkv6_state, rwkv6_block
 
 Pytree = Any
 
+#: The load-balancing weight the dense models' (zero) statistic is added
+#: with, as it always was: their compiled step stays as it is.  An MoE
+#: model weighs its term by its own ``MoE.aux_coef``.
 MOE_AUX_COEF = 0.01
+
+
+def with_aux(cfg: ArchConfig, ce: jax.Array, aux) -> tuple[jax.Array, dict]:
+    """The training loss from the token cross-entropy and the layers'
+    statistics (``models/moe``), and its metrics; an MoE model's carry
+    the rows routed to its held experts and the largest expert load."""
+    if cfg.moe is None:
+        return ce + MOE_AUX_COEF * aux, {"ce": ce, "moe_aux": aux}
+    return ce + cfg.moe.aux_coef * aux["loss"], {
+        "ce": ce, "moe_aux": aux["loss"], "moe_held_rows": aux["held_rows"],
+        "moe_max_expert_rows": aux["max_expert_rows"]}
 
 
 # ---------------------------------------------------------------------------
@@ -127,8 +148,8 @@ def apply_sublayer(
     cache: Pytree | None = None,
     q_offset: jax.Array | int = 0,
 ) -> tuple[jax.Array, Pytree | None, jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (x, new_cache, aux): the layer's statistics (:func:`zero_aux`)."""
+    aux = zero_aux(cfg)
     if kind == "rwkv":
         x, new_state = rwkv6_block(p, x, cfg, cache)
         return x, new_state, aux
@@ -154,7 +175,7 @@ def apply_sublayer(
 
     h = apply_norm(cfg, p["norm2"], x)
     if kind == "moe":
-        h, aux = moe_block(p["moe"], h, cfg)
+        h, aux = held_moe_block(p["moe"], h, cfg)
     else:
         h = mlp_block(p["mlp"], h, cfg)
     if cfg.post_norm:
@@ -180,7 +201,7 @@ def apply_stage(
     caches: Pytree | None = None,
     q_offset: jax.Array | int = 0,
 ) -> tuple[jax.Array, Pytree | None, jax.Array]:
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = zero_aux(cfg)
     new_caches = {} if caches is not None else None
     for i, kind in enumerate(pattern):
         key = f"{kind}_{i}"
@@ -189,7 +210,7 @@ def apply_stage(
             stage_p[key], x, cfg, kind,
             positions=positions, cache=cache, q_offset=q_offset,
         )
-        aux_total = aux_total + aux
+        aux_total = add_aux(aux_total, aux)
         if new_caches is not None:
             new_caches[key] = nc
     return x, new_caches, aux_total
@@ -239,7 +260,7 @@ def forward(
         segments = ((0, cfg.n_stages),)
     constrain = act_sharding_constraint or (lambda a: a)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = zero_aux(cfg)
     new_stage_caches = None
 
     def stage_body(x, stage_p_and_cache):
@@ -267,7 +288,7 @@ def forward(
         x, (seg_new_caches, seg_aux) = jax.lax.scan(
             stage_body, x, (seg_params, seg_caches)
         )
-        aux_parts.append(jnp.sum(seg_aux))
+        aux_parts.append(reduce_aux(seg_aux))
         if caches is not None:
             collected_caches.append(seg_new_caches)
 
@@ -294,7 +315,7 @@ def forward(
     elif caches is not None:
         new_caches = {"stages": new_stage_caches}
 
-    aux_total = sum(aux_parts) if aux_parts else aux_total
+    aux_total = sum_aux(aux_parts) if aux_parts else aux_total
 
     x = apply_norm(cfg, params["final_norm"], x)
     if return_hidden:
@@ -403,8 +424,7 @@ def loss_fn(
         cfg, head, x, batch["targets"], mask=batch.get("mask"),
         logits_sharding_constraint=logits_sharding_constraint,
     )
-    total = ce + MOE_AUX_COEF * aux
-    return total, {"ce": ce, "moe_aux": aux}
+    return with_aux(cfg, ce, aux)
 
 
 def staged_loss_fns(
@@ -473,7 +493,7 @@ def staged_loss_fns(
                 return stage_fn(sp, constrain(xx))
 
             x, auxs = jax.lax.scan(body, x, seg_params)
-            return x, jnp.sum(auxs)
+            return x, reduce_aux(auxs)
 
         return seg_fn
 
@@ -497,8 +517,7 @@ def staged_loss_fns(
             cfg, head, x, targets, mask=batch.get("mask"),
             logits_sharding_constraint=logits_sharding_constraint,
         )
-        total = ce + MOE_AUX_COEF * aux
-        return total, {"ce": ce, "moe_aux": aux}
+        return with_aux(cfg, ce, aux)
 
     return embed_fn, seg_fns, tail_fn, head_fn
 

@@ -102,7 +102,7 @@ def rules_for_mesh(mesh: jax.sharding.Mesh, fsdp_data: bool = False) -> Sharding
 def rules_for_arch(cfg: ArchConfig, mesh: jax.sharding.Mesh, fsdp_data: bool = False) -> ShardingRules:
     """Arch-aware rules: EP archs reserve the model axis for experts."""
     rules = rules_for_mesh(mesh, fsdp_data)
-    ep = cfg.moe is not None and cfg.moe.n_experts % rules.model_size == 0
+    ep = cfg.moe is not None and cfg.moe.n_held % rules.model_size == 0
     return dataclasses.replace(rules, reserve_model=ep)
 
 

@@ -47,6 +47,22 @@ FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+#: The held-expert MoE layer of ``models/moe`` and its four parts: routing
+#: over all experts, sorting the (token, choice) rows into the held
+#: experts' groups, the experts' grouped matmuls, and the gated scatter
+#: back to the tokens.
+MOE = "moe"
+MOE_ROUTE = "moe_route"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+#: ``name=`` of the grouped-matmul kernels' ``pallas_call``s
+#: (``kernels/grouped_matmul``): rows x (d -> f) per group (forward and
+#: activation gradient), and per-group x^T dy (weight gradient); under
+#: ``moe_experts``.
+MOE_GMM = "moe_gmm"
+MOE_TGMM = "moe_tgmm"
+MOE_KERNELS = (MOE_GMM, MOE_TGMM)
 #: ``name=`` of the comm_pack kernels' two ``pallas_call``s.
 COMM_PACK_PACK = "comm_pack_pack"
 COMM_PACK_UNPACK = "comm_pack_unpack"

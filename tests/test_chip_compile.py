@@ -8,7 +8,8 @@ sizes (the largest and smallest group of the plan ``chip_smoke.py``
 trains under) and at one ragged group, and check that the compiled
 module holds the Mosaic kernel (``tpu_custom_call``).  The flash
 attention kernels compile, forward and backward, at the starcoder2-3b
-benchmark cell's attention shape.  A data-parallel
+benchmark cell's attention shape, and the sdar-30b-a3b cell's step with
+its grouped-matmul kernels.  A data-parallel
 train step compiled for the four chips keeps one all-reduce per schedule
 group: the TPU's all-reduce combiner would merge them all into one.
 
@@ -214,3 +215,57 @@ def test_flash_kernels_compile_for_v5e_at_the_cell_shape(one_chip):
         "flash_dkv", "flash_dq", "flash_fwd"]
     assert all(profiler.is_attention(p) and profiler.is_flash(p) for p in paths)
     assert not any(is_comm_pack(Op(0, 1, line.strip())) for line in calls)
+
+
+def test_sdar_cell_step_compiles_with_named_moe_kernels(one_chip, monkeypatch):
+    """The sdar-30b-a3b.train.dp1 cell's train step, built as the
+    benchmark builds it (2 x 8192 tokens, 16 of 128 experts at the
+    published widths) with its depth cut to one layer, compiles for a v5e
+    with the grouped-matmul kernels: ``moe_gmm`` three times forward, three
+    times in the recompute and three times against the weights transposed,
+    ``moe_tgmm`` three times,
+    each under the ``moe`` scope; none of them looks like a comm_pack
+    kernel to the benchmark's trace reader.  The described chip is not
+    the process's backend, so the kernels' dispatch is told it runs on a
+    TPU."""
+    import dataclasses
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import repro.kernels.grouped_matmul.ops as gmm_ops
+    import repro.models.layers as layers
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from bench import harness
+    from bench.trace import Op, is_comm_pack
+
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gmm_ops, "_on_tpu", lambda: True)
+    cell = harness.load_cell("sdar-30b-a3b.train.dp1")
+    cfg = {**cell.config, "num_hidden_layers": 1}
+    seq, rows = int(cell.traffic["seq"]), cell.rows
+    ts = train.setup(train.parse_args([
+        "--arch", cfg["arch"], "--batch", str(rows), "--seq", str(seq), "--lr", "0.1",
+        "--replan-every", "0", *cell.spec["launcher"]]))
+    mesh = Mesh(np.array([one_chip.device_set.pop()]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    ts = dataclasses.replace(ts, cfg=cell.family.program_config(cfg, ts.cfg), mesh=mesh)
+    step = ts.train_step(ts.engine())
+    rep = NamedSharding(mesh, P())
+    specs = jax.eval_shape(functools.partial(cell.family.init, cfg), jax.random.PRNGKey(0))
+    on = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), t)
+    tok = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=NamedSharding(mesh, P("data")))
+    with jax.set_mesh(mesh):
+        text = step.lower(on(specs), on(jax.eval_shape(ts.opt.init, specs)),
+                          {"tokens": tok, "targets": tok}).compile().as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    paths = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    moe = [p for p in paths if profiler.is_moe_gmm(p)]
+    kernels = sorted(next(c for c in p.split("/") if c in scopes.MOE_KERNELS) for p in moe)
+    assert kernels == ["moe_gmm"] * 9 + ["moe_tgmm"] * 3
+    assert all(profiler.is_moe(p) for p in moe)
+    moe_calls = [line for line, p in zip(calls, paths) if profiler.is_moe_gmm(p)]
+    assert not any(is_comm_pack(Op(0, 1, line.strip())) for line in moe_calls)

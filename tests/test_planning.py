@@ -255,6 +255,24 @@ class TestMeasuredReplan:
             MeasuredCosts.from_unit_times(list(plan.costs), [1.0] * 3)
 
 
+def test_moe_stage_costs_its_expected_held_share():
+    """A MoE stage is priced by the weights a token multiplies through:
+    all but the held experts', and of these top_k·held/E experts' worth
+    (reduced SDAR: 8 x 16 / 128 = one expert of the 16 held)."""
+    from repro.configs import get_reduced
+    from repro.core.trainer import lm_unit_costs
+    from repro.launch.specs import param_specs
+
+    cfg = get_reduced("sdar-30b-a3b")
+    shapes = param_specs(cfg)
+    stage = next(c for c in lm_unit_costs(cfg, shapes, tokens_per_device=64)
+                 if c.name == "stage_0")
+    moe = shapes["stages"]["moe_0"]["moe"]
+    experts = sum(moe[k].size for k in ("w_gate", "w_up", "w_down")) // cfg.n_stages
+    active = stage.params - experts + experts / cfg.moe.n_held * 8 * 16 / 128
+    assert stage.bwd_flops == pytest.approx(4.0 * active * 64)
+
+
 class TestEnginePlan:
     """MGWFBPEngine accepts/produces a Plan and rebuilds identically from
     the serialized artifact."""

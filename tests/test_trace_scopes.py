@@ -11,9 +11,11 @@ through the launcher's own path and traced on the CPU:
     chip-form module with an unnamed fusion, an asynchronous copy and an
     asynchronous all-reduce), a group's span being its all-reduce;
   * ``profiler.layer_split`` puts each traced op down to its layer (wire,
-    remat, backward, forward, optimizer, unscoped; attention across
-    them), by hand and on a traced step, the layers adding up to the
-    busy time;
+    remat, backward, forward, optimizer, unscoped; attention and the MoE
+    layer across them), by hand and on a traced step, the layers adding
+    up to the busy time;
+  * an MoE step (reduced SDAR, reduced mixtral) names the MoE layer's
+    parts and counts the rows routed to its held experts;
   * ``launch/train.py --dryrun`` on four virtual devices prints the
     overlap report of the trace it writes under ``--trace-out``: the DAG
     step's group all-reduces start inside backward, more of them than
@@ -314,6 +316,77 @@ def test_layer_split_counts_the_flash_kernels():
     assert got["backward_ms"] == pytest.approx(35 * ms)
 
 
+MOE_HLO = """\
+HloModule jit_body, entry_computation_layout={()->()}
+
+ENTRY %main (p: bf16[1024,256]) -> bf16[1024,256] {
+  %p = bf16[1024,256]{1,0} parameter(0)
+  %sort.1 = s32[1024]{0} sort(s32[1024]{0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_dispatch/sort"}
+  %gather.2 = bf16[1024,256]{1,0} gather(bf16[1024,256]{1,0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_dispatch/jit(dispatch)/gather"}
+  %moe_gmm.3 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_experts/moe_gmm/pallas_call"}
+  %moe_gmm.4 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/moe/moe_experts/moe_gmm/pallas_call"}
+  %moe_tgmm.5 = bf16[16,256,256]{2,1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/moe/moe_experts/moe_tgmm/pallas_call"}
+  ROOT %dot.6 = bf16[1024,256]{1,0} dot(bf16[1024,256]{1,0} %p, bf16[1024,256]{1,0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/dot_general"}
+}
+"""
+
+
+def test_layer_split_counts_the_moe_layer_and_its_kernels():
+    """``moe_ms`` is the time of every op under the ``moe`` scope
+    (routing, dispatch, experts, combine), ``moe_gmm_ms`` its part inside
+    the grouped-matmul kernels, forward and backward."""
+    ops = [(0, 0, s, e, n) for n, s, e in [
+        ("sort.1", 0, 4), ("gather.2", 4, 10), ("moe_gmm.3", 10, 30), ("dot.6", 30, 40),
+        ("moe_gmm.4", 40, 60), ("moe_tgmm.5", 60, 85)]]
+    got = profiler.layer_split(ops, MOE_HLO)
+    ms = 1e-6
+    assert got["moe_ms"] == pytest.approx((4 + 6 + 20 + 20 + 25) * ms)
+    assert got["moe_gmm_ms"] == pytest.approx((20 + 20 + 25) * ms)
+    assert got["attention_ms"] == 0
+    assert got["forward_ms"] == pytest.approx(40 * ms)
+    assert got["backward_ms"] == pytest.approx(45 * ms)
+
+
+def moe_step(arch: str):
+    """One DAG step of ``arch --reduced`` through the launcher: its
+    compiled module and its metrics."""
+    argv = ["--arch", arch, "--reduced", "--batch", "2", "--seq", "32", "--policy", "wfbp",
+            "--fuse", "arena", "--optimizer", "sgd", "--replan-every", "0",
+            "--issue-order", "dag"]
+    ts = train.setup(train.parse_args(argv))
+    params = init_params(jax.random.PRNGKey(0), ts.cfg)
+    key = jax.random.PRNGKey(1)
+    batch = {"tokens": jax.random.randint(key, (2, 32), 0, ts.cfg.vocab),
+             "targets": jax.random.randint(key, (2, 32), 0, ts.cfg.vocab)}
+    with jax.set_mesh(ts.mesh):
+        compiled = ts.train_step(ts.engine()).lower(params, ts.opt.init(params), batch).compile()
+    _, _, metrics = compiled(params, ts.opt.init(params), batch)
+    return ts.cfg, compiled, {k: float(v) for k, v in metrics.items()}
+
+
+def test_moe_step_carries_the_moe_scopes_and_counts_its_rows():
+    """The reduced SDAR step (16 of 128 experts, top-8) names the MoE
+    layer's four parts; its metrics count the rows routed to the held
+    experts over the layers and the largest load of one of them."""
+    cfg, compiled, m = moe_step("sdar-30b-a3b")
+    parts = {profiler._core(c) for c in components(op_names(compiled))}
+    assert {scopes.MOE, scopes.MOE_ROUTE, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS,
+            scopes.MOE_COMBINE} <= parts
+    tokens, k = 2 * 32, cfg.moe.top_k
+    assert 0 < m["moe_held_rows"] <= cfg.n_layers * tokens * k
+    assert 0 < m["moe_max_expert_rows"] <= tokens
+
+
+def test_moe_counters_of_a_model_holding_every_expert():
+    """Holding all its experts, the reduced mixtral takes every token's
+    top-2 rows in every layer; a dense model's step has no counters."""
+    cfg, _, m = moe_step("mixtral-8x7b")
+    assert m["moe_held_rows"] == cfg.n_layers * 2 * 32 * cfg.moe.top_k
+    assert 2 * 32 * cfg.moe.top_k / cfg.moe.n_experts <= m["moe_max_expert_rows"] <= 2 * 32
+    _, _, dense = moe_step("tinyllama-1.1b")
+    assert set(dense) == {"loss"}
+
+
 def test_layer_split_of_a_program_without_scopes_is_none():
     bare = "\n".join(line.split(", metadata=")[0] for line in LAYER_HLO.splitlines())
     assert profiler.layer_split(layer_ops(), bare) is None
@@ -423,7 +496,8 @@ def test_dryrun_prints_the_layers_of_its_trace(dryruns):
     out, _, _ = dryruns
     for issue, (_, _, layers) in out.items():
         assert set(layers) == {f"{k}_ms" for k in profiler.LAYERS} | {
-            "attention_ms", "attention_flash_ms", "busy_ms"}
+            "attention_ms", "attention_flash_ms", "moe_ms", "moe_gmm_ms", "busy_ms"}
+        assert layers["moe_ms"] == layers["moe_gmm_ms"] == 0  # a dense model
         assert layers["attention_flash_ms"] == 0  # the CPU runs the jnp attention
         assert sum(layers[f"{k}_ms"] for k in profiler.LAYERS) == pytest.approx(layers["busy_ms"])
         for k in ("wire", "backward", "forward", "optimizer"):
