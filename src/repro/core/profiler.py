@@ -593,12 +593,33 @@ def is_moe_gmm(op_name: str) -> bool:
     return any(_core(c) in scopes.MOE_KERNELS for c in op_name.split("/")) if op_name else False
 
 
+def is_moe_rows(op_name: str) -> bool:
+    """An op of the MoE row kernels (``moe_rows_pack``,
+    ``moe_rows_gather``, ``moe_rows_sum``: the ``name=`` of their
+    ``pallas_call``)."""
+    return any(_core(c) in scopes.MOE_ROW_KERNELS for c in op_name.split("/")) if op_name else False
+
+
+#: The MoE layer's parts (``models/moe.held_moe_block``), each read by
+#: :func:`layer_split` as ``<part>_ms``.
+MOE_PARTS = (scopes.MOE_ROUTE, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.MOE_COMBINE)
+
+
+def moe_part(op_name: str) -> str | None:
+    """The part of the MoE layer (:data:`MOE_PARTS`) an op belongs to, by
+    its scope path, or None."""
+    cores = {_core(c) for c in op_name.split("/")} if op_name else set()
+    return next((p for p in MOE_PARTS if p in cores), None)
+
+
 def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
     """Device milliseconds per step of each of :data:`LAYERS`
     (``<layer>_ms``), of attention (``attention_ms``), of attention inside
     the flash kernels (``attention_flash_ms``), of the MoE layer
-    (``moe_ms``) and of its grouped-matmul kernels (``moe_gmm_ms``), and of
-    all busy time
+    (``moe_ms``), of each of its parts (``moe_route_ms``,
+    ``moe_dispatch_ms``, ``moe_experts_ms``, ``moe_combine_ms``), of its
+    grouped-matmul kernels (``moe_gmm_ms``) and of its row kernels
+    (``moe_rows_ms``), and of all busy time
     (``busy_ms``), averaged over devices and steps, from executed ops
     ``(device, run, start_ns, end_ns, instruction)`` (:func:`trace_ops`) of
     the compiled module ``hlo_text``.  Where ops of several layers run at
@@ -614,9 +635,10 @@ def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
         path = names.get(op, "")
         runs.setdefault((dev, run), []).append(
             (start, end, LAYERS.index(op_layer(path, op in allreduces)), is_attention(path),
-             is_flash(path), is_moe(path), is_moe_gmm(path)))
+             is_flash(path), is_moe(path), is_moe_gmm(path), is_moe_rows(path), moe_part(path)))
     total = [0.0] * len(LAYERS)
-    attention = flash = moe = gmm = 0.0
+    attention = flash = moe = gmm = rows = 0.0
+    parts = dict.fromkeys(MOE_PARTS, 0.0)
     for tagged in runs.values():
         edges = sorted([(s, 1, k) for s, e, k, *_ in tagged if e > s]
                        + [(e, -1, k) for s, e, k, *_ in tagged if e > s])
@@ -630,14 +652,18 @@ def layer_split(ops, hlo_text: str) -> dict[str, float] | None:
             prev = t
         attention += _union_len([(s, e) for s, e, _, a, *_ in tagged if a])
         flash += _union_len([(s, e) for s, e, _, a, f, *_ in tagged if a and f])
-        moe += _union_len([(s, e) for s, e, *_, m, _ in tagged if m])
-        gmm += _union_len([(s, e) for s, e, *_, g in tagged if g])
+        moe += _union_len([(s, e) for s, e, _, _, _, m, *_ in tagged if m])
+        gmm += _union_len([(s, e) for s, e, *_, g, _, _ in tagged if g])
+        rows += _union_len([(s, e) for s, e, *_, r, _ in tagged if r])
+        for part in parts:
+            parts[part] += _union_len([(s, e) for s, e, *_, q in tagged if q == part])
     if not any(total[LAYERS.index(k)] for k in ("remat", "backward", "forward", "optimizer")):
         return None
     per = 1e-6 / len(runs)  # ns summed over (device, step) -> ms per step
     out = {f"{k}_ms": v * per for k, v in zip(LAYERS, total)}
     return out | {"attention_ms": attention * per, "attention_flash_ms": flash * per,
-                  "moe_ms": moe * per, "moe_gmm_ms": gmm * per, "busy_ms": sum(total) * per}
+                  "moe_ms": moe * per, "moe_gmm_ms": gmm * per, "moe_rows_ms": rows * per,
+                  **{f"{k}_ms": v * per for k, v in parts.items()}, "busy_ms": sum(total) * per}
 
 
 def scope_layers(xplane_path, hlo_text: str) -> dict[str, float] | None:

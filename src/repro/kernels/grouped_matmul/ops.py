@@ -1,5 +1,8 @@
 """The grouped matmul of the MoE layer: ``grouped_matmul(x, w, sizes)``,
-row block ``g`` of ``x`` (m, k) times ``w[g]`` (k, n), differentiable.
+row block ``g`` of ``x`` (m, k) times ``w[g]`` (k, n), differentiable;
+and the row kernels that move the layer's routed rows (``rows.py``):
+:func:`row_tiling` says where they run, :func:`pack_rows`,
+:func:`gather_rows` and :func:`sum_rows` run them once per device.
 
 Dispatch: on a TPU, where :func:`.kernel.tilings` tiles the shapes
 (every dimension a multiple of 128), the Pallas kernels run under a
@@ -20,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..per_device import per_device
+from . import rows
 from .kernel import gmm, tgmm, tilings
 
 
@@ -60,3 +64,36 @@ def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array, *,
     if use_kernels and tiles is not None:
         return _kernels(x, w, sizes, tiles, interpret)
     return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def row_tiling(t: int, d: int, k: int, dtype) -> rows.RowTiles | None:
+    """The tiling of the row kernels for ``t`` tokens of width ``d`` with
+    ``k`` choices each, or None where the jnp path runs instead: off a
+    TPU, and where the shapes do not tile (:func:`.rows.tiles`: decode's
+    few tokens, widths not a multiple of 128 lanes)."""
+    return rows.tiles(t, d, k, dtype) if _on_tpu() else None
+
+
+def pack_rows(a, n_rows, b=None, *, tiling: rows.RowTiles, interpret: bool = False):
+    def f(a, n_rows, b):
+        return rows.rows_pack(a, n_rows, b, tm=tiling.tm, interpret=interpret)
+
+    return per_device(f)(a, n_rows, b)
+
+
+def gather_rows(src, token_of, n_rows, scale=None, ys=None, *, dtype, tiling: rows.RowTiles,
+                interpret: bool = False):
+    def f(src, token_of, n_rows, scale, ys):
+        return rows.rows_gather(src, token_of, n_rows, scale, ys, dtype=dtype, tiling=tiling,
+                                interpret=interpret)
+
+    return per_device(f)(src, token_of, n_rows, scale, ys)
+
+
+def sum_rows(buf_rows, slot_of, lists, w=None, *, dtype, tiling: rows.RowTiles,
+             interpret: bool = False):
+    def f(buf_rows, slot_of, lists, w):
+        return rows.rows_sum(buf_rows, slot_of, lists, w, dtype=dtype, tiling=tiling,
+                             interpret=interpret)
+
+    return per_device(f)(buf_rows, slot_of, lists, w)

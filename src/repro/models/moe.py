@@ -22,16 +22,25 @@ under their own scope:
 
 The gathers of steps 2 and 4 carry their own gradients, which are
 gathers too: the transpose of each is the other's pattern, so backward
-scatters nothing.
+scatters nothing.  On a TPU, where the shapes tile
+(``grouped_matmul.row_tiling``), steps 2 and 4 and their gradients run
+the row kernels (``kernels/grouped_matmul/rows``), which read and write
+only the routed rows (:func:`dispatch_rows`, :func:`combine_rows`);
+elsewhere the jnp gathers below (:func:`dispatch`, :func:`combine`)
+run over the whole buffer.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from .. import scopes
-from ..kernels.grouped_matmul import grouped_matmul
+from ..kernels.grouped_matmul import grouped_matmul, held_lists, row_tiling
+from ..kernels.grouped_matmul.ops import gather_rows, pack_rows, sum_rows
+from ..kernels.grouped_matmul.rows import RowTiles
 from .common import ArchConfig, MoE, truncated_normal
 
 #: The per-layer statistics of the held-expert layer, summed over layers
@@ -72,12 +81,12 @@ def route(p: dict, x: jax.Array, moe: MoE) -> tuple[jax.Array, jax.Array, jax.Ar
 def sort_rows(idx: jax.Array, moe: MoE):
     """The buffer order of the (token, choice) pairs ``idx`` (T, k) routes.
 
-    Returns ``(token_of, slot_of, sizes)``: for each of the buffer's
-    T·min(k, held) rows the token it holds (rows past the held ones hold
-    some token, and are never read back); for each pair its row, or the
-    buffer's length where its expert is not held; and the rows of each
-    held expert (int32), which fill the buffer from its start, in expert
-    order."""
+    Returns ``(pair_of, slot_of, sizes)``: for each of the buffer's
+    T·min(k, held) rows the (token, choice) pair it holds, as
+    ``token · k + choice`` (rows past the held ones hold some pair, and
+    are never read back); for each pair its row, or the buffer's length
+    where its expert is not held; and the rows of each held expert
+    (int32), which fill the buffer from its start, in expert order."""
     T, k = idx.shape
     H = moe.n_held
     M = T * min(k, H)
@@ -88,7 +97,7 @@ def sort_rows(idx: jax.Array, moe: MoE):
     slot = jnp.zeros(T * k, jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
     slot_of = jnp.where(held, slot, M).reshape(T, k)
     sizes = jnp.sum(key[:, None] == jnp.arange(H)[None, :], axis=0, dtype=jnp.int32)
-    return (order // k).astype(jnp.int32), slot_of, sizes
+    return order.astype(jnp.int32), slot_of, sizes
 
 
 def _rows_of(y: jax.Array, slot_of: jax.Array) -> jax.Array:
@@ -145,25 +154,104 @@ def _combine_bwd(res, dout):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def held_moe_block(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, dict]:
+# ---------------------------------------------------------------------------
+# The same two steps through the row kernels
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def dispatch_rows(x, pair_of, slot_of, lists, n_rows, tiling, interpret):
+    """:func:`dispatch` through the row kernels: rows ``0 .. n_rows - 1``
+    of the buffer, the rest left unwritten.  The buffer is returned twice,
+    once for each grouped matmul that reads it, so that backward gets the
+    two cotangents apart and adds only their held rows."""
+    k = slot_of.shape[1]
+    src = pack_rows(x, x.shape[0], tiling=tiling, interpret=interpret)
+    xs = gather_rows(src, pair_of // k, n_rows, dtype=x.dtype, tiling=tiling,
+                     interpret=interpret)
+    return xs, xs
+
+
+def _dispatch_rows_fwd(x, pair_of, slot_of, lists, n_rows, tiling, interpret):
+    return dispatch_rows(x, pair_of, slot_of, lists, n_rows, tiling, interpret), (
+        slot_of, lists, n_rows)
+
+
+def _dispatch_rows_bwd(tiling, interpret, res, cts):
+    slot_of, lists, n_rows = res
+    dxg, dxu = cts
+    # the two cotangents added over the routed rows alone, then each
+    # token's held rows summed in f32
+    both = pack_rows(dxg, n_rows, dxu, tiling=tiling, interpret=interpret)
+    dx = sum_rows(both, slot_of, lists, dtype=dxg.dtype, tiling=tiling, interpret=interpret)
+    return dx, None, None, None, None
+
+
+dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def combine_rows(y, gates, pair_of, slot_of, lists, n_rows, tiling, interpret):
+    """:func:`combine` through the row kernels: only the held rows of
+    ``y``'s first ``n_rows`` are read."""
+    rows = pack_rows(y, n_rows, tiling=tiling, interpret=interpret)
+    return sum_rows(rows, slot_of, lists, gates, dtype=y.dtype, tiling=tiling,
+                    interpret=interpret)
+
+
+def _combine_rows_fwd(y, gates, pair_of, slot_of, lists, n_rows, tiling, interpret):
+    out = combine_rows(y, gates, pair_of, slot_of, lists, n_rows, tiling, interpret)
+    return out, (y, gates, pair_of, slot_of, n_rows)
+
+
+def _combine_rows_bwd(tiling, interpret, res, dout):
+    y, gates, pair_of, slot_of, n_rows = res
+    M, k = y.shape[0], slot_of.shape[1]
+    # each buffer row: its token's output gradient times the row's gate,
+    # and the row's dot product with that gradient, the gate's gradient
+    src = pack_rows(dout, dout.shape[0], tiling=tiling, interpret=interpret)
+    row_gate = gates.reshape(-1)[pair_of]
+    dy, dot = gather_rows(src, pair_of // k, n_rows, row_gate, y, dtype=y.dtype,
+                          tiling=tiling, interpret=interpret)
+    dgates = jnp.where(slot_of < M, jnp.take(dot, slot_of, mode="clip"), 0.0)
+    return dy, dgates, None, None, None, None
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def held_moe_block(p: dict, x: jax.Array, cfg: ArchConfig, *, row_tiles: RowTiles | None = None,
+                   interpret: bool = False) -> tuple[jax.Array, dict]:
     """x: (B, S, D) -> (the held experts' part of the layer, its
     statistics: the load-balancing term, the rows routed to held experts,
-    the most rows one held expert took)."""
+    the most rows one held expert took).  The row kernels run at
+    ``row_tiles``, by default the tiling ``row_tiling`` gives (None off a
+    TPU: the jnp path); tests give a tiling of their own, and
+    ``interpret`` runs the kernels in Pallas's interpreter."""
     moe = cfg.moe
     B, S, D = x.shape
     xt = x.reshape(B * S, D)
+    tiling = row_tiles or row_tiling(B * S, D, moe.top_k, x.dtype)
     with jax.named_scope(scopes.MOE):
         with jax.named_scope(scopes.MOE_ROUTE):
             gates, idx, aux = route(p, xt, moe)
         with jax.named_scope(scopes.MOE_DISPATCH):
-            token_of, slot_of, sizes = sort_rows(idx, moe)
-            xs = dispatch(xt, token_of, slot_of)
+            pair_of, slot_of, sizes = sort_rows(idx, moe)
+            if tiling is None:
+                xg = xu = dispatch(xt, pair_of // moe.top_k, slot_of)
+            else:
+                n_rows = jnp.sum(sizes)
+                lists = held_lists(slot_of, pair_of.shape[0], tiling.tt)
+                xg, xu = dispatch_rows(xt, pair_of, slot_of, lists, n_rows, tiling, interpret)
         with jax.named_scope(scopes.MOE_EXPERTS):
-            h = jax.nn.silu(grouped_matmul(xs, p["w_gate"], sizes))
-            h = h * grouped_matmul(xs, p["w_up"], sizes)
+            h = jax.nn.silu(grouped_matmul(xg, p["w_gate"], sizes))
+            h = h * grouped_matmul(xu, p["w_up"], sizes)
             ys = grouped_matmul(h, p["w_down"], sizes)
         with jax.named_scope(scopes.MOE_COMBINE):
-            out = combine(ys, gates, token_of, slot_of)
+            if tiling is None:
+                out = combine(ys, gates, pair_of // moe.top_k, slot_of)
+            else:
+                out = combine_rows(ys, gates, pair_of, slot_of, lists, n_rows, tiling, interpret)
     stats = {"loss": aux, "held_rows": jnp.sum(sizes).astype(jnp.float32),
              "max_expert_rows": jnp.max(sizes).astype(jnp.float32)}
     return out.reshape(B, S, D), stats

@@ -63,6 +63,16 @@ MOE_COMBINE = "moe_combine"
 MOE_GMM = "moe_gmm"
 MOE_TGMM = "moe_tgmm"
 MOE_KERNELS = (MOE_GMM, MOE_TGMM)
+#: ``name=`` of the row kernels' ``pallas_call``s
+#: (``kernels/grouped_matmul/rows``), which move only the routed rows:
+#: rows written in the layout a row DMA can address; buffer rows gathered
+#: from token rows (dispatch, and the combine's gradient); token rows
+#: summed from their held buffer rows (combine, and the dispatch's
+#: gradient).  Under ``moe_dispatch`` and ``moe_combine``.
+MOE_ROWS_PACK = "moe_rows_pack"
+MOE_ROWS_GATHER = "moe_rows_gather"
+MOE_ROWS_SUM = "moe_rows_sum"
+MOE_ROW_KERNELS = (MOE_ROWS_PACK, MOE_ROWS_GATHER, MOE_ROWS_SUM)
 #: ``name=`` of the comm_pack kernels' two ``pallas_call``s.
 COMM_PACK_PACK = "comm_pack_pack"
 COMM_PACK_UNPACK = "comm_pack_unpack"

@@ -224,8 +224,9 @@ def test_sdar_cell_step_compiles_with_named_moe_kernels(one_chip, monkeypatch):
     with the grouped-matmul kernels: ``moe_gmm`` three times forward, three
     times in the recompute and three times against the weights transposed,
     ``moe_tgmm`` three times,
-    each under the ``moe`` scope; none of them looks like a comm_pack
-    kernel to the benchmark's trace reader.  The described chip is not
+    each under the ``moe`` scope, and the row kernels that move the routed
+    rows in dispatch, combine and their gradients; none of them looks like
+    a comm_pack kernel to the benchmark's trace reader.  The described chip is not
     the process's backend, so the kernels' dispatch is told it runs on a
     TPU."""
     import dataclasses
@@ -269,3 +270,13 @@ def test_sdar_cell_step_compiles_with_named_moe_kernels(one_chip, monkeypatch):
     assert all(profiler.is_moe(p) for p in moe)
     moe_calls = [line for line, p in zip(calls, paths) if profiler.is_moe_gmm(p)]
     assert not any(is_comm_pack(Op(0, 1, line.strip())) for line in moe_calls)
+    # the row kernels: dispatch forward and in the recompute (a pack and a
+    # gather each), combine forward (a pack and a sum; the recompute does
+    # not need it), and their gradients (a pack and a gather for the
+    # combine's, a pack and a sum for the dispatch's)
+    rows = [p for p in paths if profiler.is_moe_rows(p)]
+    names = sorted(next(c for c in p.split("/") if c in scopes.MOE_ROW_KERNELS) for p in rows)
+    assert names == ["moe_rows_gather"] * 3 + ["moe_rows_pack"] * 5 + ["moe_rows_sum"] * 2
+    assert all(profiler.is_moe(p) and not profiler.is_moe_gmm(p) for p in rows)
+    row_calls = [line for line, p in zip(calls, paths) if profiler.is_moe_rows(p)]
+    assert not any(is_comm_pack(Op(0, 1, line.strip())) for line in row_calls)

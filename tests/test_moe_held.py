@@ -10,6 +10,11 @@ seeded random weights:
   * ``moe_gmm`` and ``moe_tgmm`` in interpret mode give the jnp path's
     products, with empty groups, uneven groups and rows past the last
     group, and the custom VJP over them the jnp path's gradients;
+  * the row kernels (``moe_rows_pack``, ``moe_rows_gather``,
+    ``moe_rows_sum``) in interpret mode move the same rows as the jnp
+    gathers, bit for bit, and give their sums, never reading a buffer row
+    past the routed ones; the layer's gradients through them are the jnp
+    path's;
   * the whole reduced SDAR train step, through the launcher's path
     (``repro.launch.train``) and the benchmark's harness, gives the
     reference's losses, per-leaf gradients and SGD update;
@@ -31,7 +36,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_reduced
-from repro.kernels.grouped_matmul import gmm, grouped_matmul, tgmm
+from repro.kernels.grouped_matmul import (gmm, grouped_matmul, held_lists, rows_gather, rows_pack,
+                                          rows_sum, tgmm)
+from repro.kernels.grouped_matmul.rows import RowTiles
+from repro.models import moe as moe_layer
 from repro.models.moe import held_moe_block, sort_rows
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -137,15 +145,15 @@ def test_rows_are_sorted_by_held_expert():
     each pair at its own slot; pairs of absent experts get no slot."""
     cfg = program(4, 2)
     idx = jnp.array([[2, 9], [5, 3], [3, 0], [1, 2]], jnp.int32)  # held: 2, 3, 4, 5
-    token_of, slot_of, sizes = sort_rows(idx, cfg.moe)
+    pair_of, slot_of, sizes = sort_rows(idx, cfg.moe)
     assert sizes.tolist() == [2, 2, 0, 1]
-    assert token_of.shape == (4 * 2,)
+    assert pair_of.shape == (4 * 2,)
     M = 8
     held = [(t, j) for t in range(4) for j in range(2) if 2 <= int(idx[t, j]) < 6]
     assert sorted(int(slot_of[t, j]) for t, j in held) == list(range(5))
     assert all(int(slot_of[t, j]) == M for t in range(4) for j in range(2) if (t, j) not in held)
     for t, j in held:
-        assert int(token_of[int(slot_of[t, j])]) == t
+        assert int(pair_of[int(slot_of[t, j])]) == t * 2 + j
     experts = [int(idx[t, j]) for t, j in sorted(held, key=lambda tj: int(slot_of[tj]))]
     assert experts == sorted(experts)
 
@@ -220,6 +228,121 @@ def test_custom_vjp_matches_the_jnp_gradients():
     for got, want in ((gx[:n], wx[:n]), (gw, ww)):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         np.testing.assert_allclose(got, want, atol=float(np.max(np.abs(want))) * 2.0 ** -7)
+
+
+# --- row kernels -------------------------------------------------------------
+
+ROW_TILES = RowTiles(tm=128, tt=128, ti=128)
+ROW_T, ROW_K = 128, 4  # 512 (token, choice) pairs for the buffer's M_ROWS rows
+
+
+def routing(name: str, seed: int = 0):
+    """A buffer of ``M_ROWS`` rows filled as ``SIZES[name]`` says: the
+    routed rows' pairs drawn at random from the ``ROW_T · ROW_K``, the
+    rest of the buffer holding the unheld pairs.  ``(pair_of, slot_of,
+    n_routed)``."""
+    n = sum(SIZES[name])
+    pair_of = np.random.default_rng(seed).permutation(ROW_T * ROW_K).astype(np.int32)
+    slot = np.full(ROW_T * ROW_K, M_ROWS, np.int32)
+    slot[pair_of[:n]] = np.arange(n)
+    return jnp.asarray(pair_of), jnp.asarray(slot.reshape(ROW_T, ROW_K)), n
+
+
+def row_operands(seed: int = 0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+
+    def bf(key, shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+
+    gates = jax.random.uniform(ks[4], (ROW_T, ROW_K), jnp.float32)
+    return (bf(ks[0], (ROW_T, K)), bf(ks[1], (M_ROWS, K)), bf(ks[2], (M_ROWS, K)),
+            bf(ks[3], (ROW_T, K)), gates)
+
+
+def bits(a):
+    return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+
+
+def close_to(got, want):
+    """Half a bf16 unit of the largest of ``want``."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, atol=float(np.max(np.abs(want))) * 2.0 ** -8 + 1e-6)
+
+
+def row_sums(buf, slot_of, n, w=None, plus=None):
+    """``moe_rows_sum`` over rows ``0 .. n - 1`` of ``buf`` (+ ``plus``)."""
+    packed = rows_pack(buf, n, plus, tm=ROW_TILES.tm, interpret=True)
+    return rows_sum(packed, slot_of, held_lists(slot_of, M_ROWS, ROW_TILES.tt), w,
+                    dtype=buf.dtype, tiling=ROW_TILES, interpret=True)
+
+
+@pytest.mark.parametrize("name", sorted(SIZES))
+def test_row_kernels_match_the_jnp_path(name):
+    """In interpret mode at 128-row tiles: ``moe_rows_gather`` moves the
+    jnp gathers' rows bit for bit (the dispatch's, and the combine
+    gradient's, scaled by each row's gate in f32), and its dot products
+    give the jnp path's gate gradients; ``moe_rows_sum`` gives the
+    combine (gated) and the dispatch's gradient (the two cotangents
+    added), each within half a bf16 unit of the largest."""
+    pair_of, slot_of, n = routing(name)
+    token_of = pair_of // ROW_K
+    x, ys, dxu, dout, gates = row_operands()
+    src = rows_pack(x, ROW_T, tm=ROW_TILES.tm, interpret=True)
+    xs = rows_gather(src, token_of, n, dtype=x.dtype, tiling=ROW_TILES, interpret=True)
+    np.testing.assert_array_equal(bits(xs[:n]), bits(moe_layer.dispatch(x, token_of, slot_of)[:n]))
+
+    want_dy, want_dgates, *_ = moe_layer._combine_bwd((ys, gates, token_of, slot_of), dout)
+    src = rows_pack(dout, ROW_T, tm=ROW_TILES.tm, interpret=True)
+    dy, dot = rows_gather(src, token_of, n, gates.reshape(-1)[pair_of], ys, dtype=ys.dtype,
+                          tiling=ROW_TILES, interpret=True)
+    np.testing.assert_array_equal(bits(dy[:n]), bits(want_dy[:n]))
+    dgates = jnp.where(slot_of < M_ROWS, jnp.take(dot, slot_of, mode="clip"), 0.0)
+    close_to(dgates, want_dgates)
+
+    close_to(row_sums(ys, slot_of, n, gates), moe_layer.combine(ys, gates, token_of, slot_of))
+    want_dx, *_ = moe_layer._dispatch_bwd(slot_of, ys + dxu)
+    close_to(row_sums(ys, slot_of, n, plus=dxu), want_dx)
+
+
+@pytest.mark.parametrize("name", ["rows_past_the_end", "uneven", "tile_aligned"])
+def test_row_sums_never_read_rows_past_the_routed_ones(name):
+    """Buffer rows at and past the routed ones hold NaN (the grouped
+    matmuls leave them unwritten): the combine's and the dispatch
+    gradient's sums stay finite and equal the jnp path's."""
+    pair_of, slot_of, n = routing(name, seed=1)
+    token_of = pair_of // ROW_K
+    _, ys, dxu, _, gates = row_operands(1)
+    ys, dxu = ys.at[n:].set(jnp.nan), dxu.at[n:].set(jnp.nan)
+    out = row_sums(ys, slot_of, n, gates)
+    dx = row_sums(ys, slot_of, n, plus=dxu)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.isfinite(np.asarray(dx, np.float32)).all()
+    close_to(out, moe_layer.combine(ys, gates, token_of, slot_of))
+    close_to(dx, moe_layer._dispatch_bwd(slot_of, ys + dxu)[0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_layer_gradients_through_the_row_kernels(dtype):
+    """``held_moe_block`` with the row kernels forced on (interpret mode,
+    128-row tiles) against the jnp path (the CPU's default): the output, ``dx``, the router's
+    gradient (through the gates' gradient) and the experts' gradients."""
+    cfg = program()
+    cfg = dataclasses.replace(cfg, param_dtype=dtype)
+    p = jax.tree.map(lambda a: a.astype(dtype) if a.ndim == 3 else a,
+                     layer_weights(cfg, 16, 0))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, ROW_T // 2, cfg.d_model)).astype(dtype)
+    c = jax.random.normal(jax.random.PRNGKey(4), x.shape, jnp.float32)
+
+    def loss(p, x, tiles):
+        out, _ = held_moe_block(p, x, cfg, row_tiles=tiles, interpret=True)
+        return jnp.sum(out.astype(jnp.float32) * c), out
+
+    (_, got), g_got = jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x, ROW_TILES)
+    (_, want), g_want = jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x, None)
+    tol = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
+    for a, b in [(got, want), *zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want))]:
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=float(np.max(np.abs(b))) * tol)
 
 
 # --- the whole train step ----------------------------------------------------

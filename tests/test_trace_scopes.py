@@ -326,25 +326,35 @@ ENTRY %main (p: bf16[1024,256]) -> bf16[1024,256] {
   %moe_gmm.3 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_experts/moe_gmm/pallas_call"}
   %moe_gmm.4 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/moe/moe_experts/moe_gmm/pallas_call"}
   %moe_tgmm.5 = bf16[16,256,256]{2,1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/moe/moe_experts/moe_tgmm/pallas_call"}
+  %dot.7 = f32[1024,128]{1,0} dot(bf16[1024,256]{1,0} %p, bf16[1024,256]{1,0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_route/dot_general"}
+  %moe_rows_sum.8 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/moe/moe_combine/moe_rows_sum/pallas_call"}
+  %moe_rows_gather.9 = bf16[1024,256]{1,0} custom-call(bf16[1024,256]{1,0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(body)/shard_map/bwd_seg0/transpose(jvp())/while/body/closed_call/checkpoint/moe/moe_combine/moe_rows_gather/pallas_call"}
   ROOT %dot.6 = bf16[1024,256]{1,0} dot(bf16[1024,256]{1,0} %p, bf16[1024,256]{1,0} %p), metadata={op_name="jit(body)/shard_map/fwd_seg0/jvp()/while/body/closed_call/dot_general"}
 }
 """
 
 
 def test_layer_split_counts_the_moe_layer_and_its_kernels():
-    """``moe_ms`` is the time of every op under the ``moe`` scope
-    (routing, dispatch, experts, combine), ``moe_gmm_ms`` its part inside
-    the grouped-matmul kernels, forward and backward."""
+    """``moe_ms`` is the time of every op under the ``moe`` scope, split
+    by scope into routing, dispatch, experts and combine; ``moe_gmm_ms``
+    its part inside the grouped-matmul kernels, ``moe_rows_ms`` inside the
+    row kernels, forward and backward."""
     ops = [(0, 0, s, e, n) for n, s, e in [
         ("sort.1", 0, 4), ("gather.2", 4, 10), ("moe_gmm.3", 10, 30), ("dot.6", 30, 40),
-        ("moe_gmm.4", 40, 60), ("moe_tgmm.5", 60, 85)]]
+        ("moe_gmm.4", 40, 60), ("moe_tgmm.5", 60, 85), ("dot.7", 85, 88),
+        ("moe_rows_sum.8", 88, 95), ("moe_rows_gather.9", 95, 97)]]
     got = profiler.layer_split(ops, MOE_HLO)
     ms = 1e-6
-    assert got["moe_ms"] == pytest.approx((4 + 6 + 20 + 20 + 25) * ms)
+    assert got["moe_ms"] == pytest.approx((4 + 6 + 20 + 20 + 25 + 3 + 7 + 2) * ms)
     assert got["moe_gmm_ms"] == pytest.approx((20 + 20 + 25) * ms)
+    assert got["moe_rows_ms"] == pytest.approx((7 + 2) * ms)
+    assert got["moe_route_ms"] == pytest.approx(3 * ms)
+    assert got["moe_dispatch_ms"] == pytest.approx((4 + 6) * ms)
+    assert got["moe_experts_ms"] == pytest.approx((20 + 20 + 25) * ms)
+    assert got["moe_combine_ms"] == pytest.approx((7 + 2) * ms)
     assert got["attention_ms"] == 0
-    assert got["forward_ms"] == pytest.approx(40 * ms)
-    assert got["backward_ms"] == pytest.approx(45 * ms)
+    assert got["forward_ms"] == pytest.approx((40 + 3 + 7) * ms)
+    assert got["backward_ms"] == pytest.approx((45 + 2) * ms)
 
 
 def moe_step(arch: str):
@@ -496,8 +506,9 @@ def test_dryrun_prints_the_layers_of_its_trace(dryruns):
     out, _, _ = dryruns
     for issue, (_, _, layers) in out.items():
         assert set(layers) == {f"{k}_ms" for k in profiler.LAYERS} | {
-            "attention_ms", "attention_flash_ms", "moe_ms", "moe_gmm_ms", "busy_ms"}
-        assert layers["moe_ms"] == layers["moe_gmm_ms"] == 0  # a dense model
+            "attention_ms", "attention_flash_ms", "moe_ms", "moe_gmm_ms", "moe_rows_ms",
+            "busy_ms"} | {f"{p}_ms" for p in profiler.MOE_PARTS}
+        assert layers["moe_ms"] == layers["moe_gmm_ms"] == layers["moe_rows_ms"] == 0  # dense
         assert layers["attention_flash_ms"] == 0  # the CPU runs the jnp attention
         assert sum(layers[f"{k}_ms"] for k in profiler.LAYERS) == pytest.approx(layers["busy_ms"])
         for k in ("wire", "backward", "forward", "optimizer"):
