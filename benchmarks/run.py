@@ -509,17 +509,16 @@ def serve_exec() -> list[str]:
 
 
 def wire_layout() -> list[str]:
-    """Wire-layout sweep: concat vs variadic vs arena × fp32 vs bf16.
+    """Arena wire sweep: fp32 vs bf16 on the wire.
 
-    Lowers + compiles the bucketed sync for each (fuse, comm dtype) cell
-    under shard_map on 8 virtual devices, then reads the truth out of the
-    compiled HLO with ``profiler.parse_collectives``: all-reduce op
-    count, all-reduce payload bytes (bytes moved per device per step),
-    and concatenate op count (the copy tax of the concat layout, zero on
-    the arena path).  A numeric check (distinct per-rank scaling, exact
-    expected average) rides along so a cell that mis-packs can never
-    publish.  Full records go to
-    ``benchmarks/results/BENCH_wire_layout.json``.
+    Lowers + compiles the bucketed sync for each wire dtype under
+    shard_map on 8 virtual devices, then reads the truth out of the
+    lowered HLO with ``profiler.parse_collectives``: all-reduce op count
+    (one per schedule group), all-reduce payload bytes (bytes moved per
+    device per step) and concatenate op count (zero: the arena packs in
+    place).  A numeric check (distinct per-rank scaling, exact expected
+    average) rides along so a cell that mis-packs can never publish.
+    Full records go to ``benchmarks/results/BENCH_wire_layout.json``.
     """
     import jax
     import jax.numpy as jnp
@@ -529,7 +528,6 @@ def wire_layout() -> list[str]:
     from repro.core import (
         AllReduceModel,
         SyncConfig,
-        count_expected_allreduces,
         group_arenas,
         make_gradient_sync,
         parse_collectives,
@@ -561,55 +559,44 @@ def wire_layout() -> list[str]:
 
     rows = ["table=wire_layout"]
     records = []
-    for fuse in ("concat", "variadic", "arena"):
-        for comp in (None, "bf16"):
-            cfg = SyncConfig(fuse=fuse, compression=comp)
-            sync = make_gradient_sync(layout, schedule, ("data",), cfg)
+    for name, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
+        sync = make_gradient_sync(layout, schedule, ("data",), SyncConfig(comm_dtype=dtype))
 
-            def body(g):
-                r = jax.lax.axis_index("data").astype(jnp.float32)
-                return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
+        def body(g):
+            r = jax.lax.axis_index("data").astype(jnp.float32)
+            return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
 
-            f = jax.jit(
-                jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+        f = jax.jit(
+            jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                           axis_names={"data"}, check_vma=False)
+        )
+        # lowered (stablehlo) text: the wire dtype is truthful there
+        # (compiled CPU modules upcast bf16 collectives to f32)
+        stats = parse_collectives(f.lower(grads).as_text())
+        got = f(grads)
+        # rank r ships (r+1)·g, so the average is mean(1..n_dev)·g
+        expect = jax.tree.map(lambda x: (n_dev + 1) / 2 * x, grads)
+        max_diff = max(
+            jax.tree.leaves(
+                jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect)
             )
-            # lowered (stablehlo) text: the wire dtype is truthful there
-            # (compiled CPU modules upcast bf16 collectives to f32)
-            stats = parse_collectives(f.lower(grads).as_text())
-            got = f(grads)
-            # rank r ships (r+1)·g, so the average is mean(1..n_dev)·g
-            expect = jax.tree.map(lambda x: (n_dev + 1) / 2 * x, grads)
-            max_diff = max(
-                jax.tree.leaves(
-                    jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect)
-                )
-            )
-            rec = {
-                "fuse": fuse,
-                "comm_dtype": "bf16" if comp else "f32",
-                "n_groups": len(schedule.groups),
-                "allreduce_ops": stats.counts.get("all-reduce", 0),
-                "expected_allreduce_ops": count_expected_allreduces(schedule, cfg, layout),
-                "wire_bytes": stats.bytes_by_kind.get("all-reduce", 0),
-                "concat_ops": stats.concat_ops,
-                "max_diff": max_diff,
-            }
-            if fuse == "arena":
-                rec["arena_bytes"] = sum(
-                    a.nbytes
-                    for a in group_arenas(
-                        layout, schedule, shapes,
-                        jnp.bfloat16 if comp else jnp.float32,
-                    )
-                )
-            records.append(rec)
-            rows.append(
-                f"{fuse},{rec['comm_dtype']},groups={rec['n_groups']},"
-                f"allreduce_ops={rec['allreduce_ops']},"
-                f"wire_bytes={rec['wire_bytes']},concat_ops={rec['concat_ops']},"
-                f"max_diff={max_diff:.2e}"
-            )
+        )
+        rec = {
+            "comm_dtype": name,
+            "n_groups": len(schedule.groups),
+            "allreduce_ops": stats.counts.get("all-reduce", 0),
+            "wire_bytes": stats.bytes_by_kind.get("all-reduce", 0),
+            "arena_bytes": sum(a.nbytes for a in group_arenas(layout, schedule, shapes, dtype)),
+            "concat_ops": stats.concat_ops,
+            "max_diff": max_diff,
+        }
+        records.append(rec)
+        rows.append(
+            f"arena,{name},groups={rec['n_groups']},"
+            f"allreduce_ops={rec['allreduce_ops']},"
+            f"wire_bytes={rec['wire_bytes']},concat_ops={rec['concat_ops']},"
+            f"max_diff={max_diff:.2e}"
+        )
     write_bench("wire_layout", records, rows)
     return rows
 
@@ -654,7 +641,6 @@ def overlap() -> list[str]:
     from repro.configs import get_reduced
     from repro.core.comm_model import AllReduceModel
     from repro.core.profiler import GROUP_SPAN_RE, overlap_report, scope_spans
-    from repro.core.sync import SyncConfig
     from repro.core.trainer import MGWFBPEngine
     from repro.launch.specs import param_specs
     from repro.models.transformer import init_params
@@ -668,7 +654,6 @@ def overlap() -> list[str]:
         cfg, param_specs(cfg), dp_axes=("data",),
         ar_model=AllReduceModel(a=5e-5, b=1e-9),
         tokens_per_device=1024, method="wfbp",  # one group per unit
-        sync_config=SyncConfig(fuse="arena"),
     )
     n_groups = len(eng.schedule.groups)
     opt = make_optimizer("sgd", momentum=0.9)

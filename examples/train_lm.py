@@ -1,5 +1,5 @@
 """End-to-end training driver: a small LM trained with the full MG-WFBP
-stack — schedule computation, bucket-segmented scan, variadic-psum
+stack — schedule computation, bucket-segmented scan, packed-arena
 gradient sync inside shard_map, synthetic data pipeline, async atomic
 checkpointing, and fault-tolerant restart.
 

@@ -8,10 +8,10 @@ Public surface:
                 exhaustive exact optimum
   bucketing   — param-pytree <-> schedule-bucket mapping (leaf + stacked
                 units) + the per-group wire plan and flat arena layouts
-  sync        — the unified bucketed reducer: one all-reduce per schedule
-                group inside shard_map, concat | variadic | arena wire
-                layouts (see also repro.planning for the Plan artifact /
-                policy registry / cost sources)
+  sync        — the bucketed reducer: one all-reduce per schedule group
+                inside shard_map over the packed-arena wire (see also
+                repro.planning for the Plan artifact / policy registry /
+                cost sources)
   profiler    — HLO segment cost extraction + collective-traffic parser
 """
 
@@ -57,7 +57,6 @@ from .bucketing import (
 from .schedule import dp_optimal_schedule
 from .sync import (
     SyncConfig,
-    count_expected_allreduces,
     make_gradient_sync,
     wire_entries,
 )
@@ -105,7 +104,6 @@ __all__ = [
     "tree_set",
     "dp_optimal_schedule",
     "SyncConfig",
-    "count_expected_allreduces",
     "make_gradient_sync",
     "wire_entries",
     "CollectiveStats",

@@ -19,8 +19,8 @@ The scheduler (``core.schedule``) works on the paper's flat layer list
     (leaf entries + contiguous ``[a:b)`` scan-slice entries) and
     ``group_arenas`` lays each group out as a flat **arena** — exact
     element offset/size per unit, zero padding, so the arena wire buffer
-    is byte-identical in size to a concatenation of the group while
-    letting ``fuse='arena'`` pack/unpack in place (kernels/comm_pack);
+    holds exactly the group's gradient elements and is packed and
+    unpacked in place (kernels/comm_pack);
   * stacked-layer models re-bucket by slicing the leading axis, which is
     also how checkpoints are converted when the schedule changes between
     runs (elastic restarts — a different N gives a different α–β model,
@@ -122,7 +122,7 @@ class ParamLayout:
     def group_arenas(
         self, schedule: Schedule, shapes: Any, comm_dtype: Any = "float32"
     ) -> "list[GroupArena]":
-        """Per-group flat wire layout for ``fuse='arena'`` (see
+        """Per-group flat wire layout of the arena wire (see
         ``group_arenas`` below for the shape-source contract)."""
         return group_arenas(self, schedule, shapes, comm_dtype)
 
@@ -341,9 +341,8 @@ class GroupArena:
     """Flat wire layout of one schedule group.
 
     Offsets are exact-packed (no per-slot padding): ``size`` equals the
-    sum of slot sizes, so the arena's psum payload is byte-identical to
-    the concat layout's — the arena only removes copies, never adds wire
-    traffic.
+    sum of slot sizes, so the arena's psum payload is exactly the group's
+    gradient elements in the wire dtype — no padding on the wire.
     """
 
     slots: tuple[ArenaSlot, ...]

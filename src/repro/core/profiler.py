@@ -81,9 +81,9 @@ class CollectiveStats:
     """Aggregated collective traffic of one compiled module (per device).
 
     ``concat_ops`` counts ``concatenate`` ops — not a collective, but the
-    tell-tale of the copy-based merged-buffer wire layout: the arena
-    layout (``core/sync.py`` ``fuse='arena'``) must lower with zero of
-    them, and the wire-layout benchmark reports them per fuse mode.  It
+    tell-tale of a copy-based merged buffer: the arena wire
+    (``core/sync.py``) must lower with zero of them, and the wire-layout
+    benchmark reports them per wire dtype.  It
     is kept out of ``counts``/``total_bytes`` so roofline collective
     traffic is unchanged.
     """

@@ -193,12 +193,6 @@ class MGWFBPEngine:
     def segments(self) -> tuple[tuple[int, int], ...]:
         return self.plan.segments
 
-    @property
-    def stateful(self) -> bool:
-        """True when the sync carries error-feedback state: the train step
-        then takes and returns the residual pytree."""
-        return self.sync_config.compression == "bf16_ef"
-
     def dp_world(self, mesh) -> int:
         return int(np.prod([mesh.shape[ax] for ax in self.dp_axes]))
 
@@ -212,32 +206,6 @@ class MGWFBPEngine:
         return {"moe_held_rows": jax.lax.psum(metrics["moe_held_rows"], self.dp_axes),
                 "moe_max_expert_rows": jax.lax.pmax(metrics["moe_max_expert_rows"],
                                                     self.dp_axes)}
-
-    def _jit_step(self, smapped, mesh, donate_argnums):
-        """``jax.jit`` of a train-step body, with the all-reduce combiners
-        off (:data:`KEEP_GROUPS_APART`) where there is more than one
-        replica to reduce over."""
-        opts = KEEP_GROUPS_APART if self.dp_world(mesh) > 1 else None
-        return jax.jit(smapped, donate_argnums=donate_argnums, compiler_options=opts)
-
-    def init_residual(self, params: Pytree, mesh=None) -> Pytree | None:
-        """Zero f32 error-feedback residual (``compression='bf16_ef'``),
-        None for stateless compression.
-
-        The residual is *per-device* state (each device carries the
-        quantization error of its own local gradient contribution), so
-        every leaf gets a leading DP axis of the mesh's data-parallel
-        world size — sharded over ``dp_axes`` through the train step and
-        stored whole in checkpoints (a restart at a different world size
-        fails the shape check and re-initializes, like any elastic
-        restart).  ``mesh=None`` means world size 1.
-        """
-        if not self.stateful:
-            return None
-        world = self.dp_world(mesh) if mesh is not None else 1
-        return jax.tree.map(
-            lambda x: jnp.zeros((world, *x.shape), jnp.float32), params
-        )
 
     @classmethod
     def build(
@@ -271,11 +239,7 @@ class MGWFBPEngine:
         if plan is None:
             if ar_model is None:
                 raise ValueError("either a plan or an ar_model is required")
-            comm_bytes = (
-                jnp.dtype(sync_config.comm_dtype).itemsize
-                if sync_config.compression is None
-                else 2
-            )
+            comm_bytes = jnp.dtype(sync_config.comm_dtype).itemsize
             layout = stacked_lm_layout(
                 param_shapes, cfg.n_stages,
                 comm_dtype_bytes=comm_bytes, model_shards=model_shards,
@@ -343,17 +307,9 @@ class MGWFBPEngine:
         self, optimizer: Optimizer, mesh, *, lr: float = 3e-4,
         issue: str = "post",
     ):
-        """Shard-map train step: manual DP axes, auto model axis.
-
-        Stateless sync: ``step(params, opt_state, batch) -> (params,
-        opt_state, metrics)``.  With ``compression='bf16_ef'`` the
-        error-feedback residual threads through: ``step(params, opt_state,
-        residual, batch) -> (params, opt_state, residual, metrics)`` —
-        seed it with ``init_residual(params, mesh)`` and checkpoint it
-        beside the optimizer state so EF survives restarts.  The residual
-        is per-device state: its leaves carry a leading DP axis sharded
-        over ``dp_axes`` (each device reads and writes only its own
-        slice), never falsely claimed replicated.
+        """Shard-map train step: manual DP axes, auto model axis,
+        ``step(params, opt_state, batch) -> (params, opt_state, metrics)``
+        in either issue order.
 
         ``issue`` selects the communication issue order
         (``core.timeline.MODES`` maps onto it: ``'dag'`` executes what
@@ -387,46 +343,19 @@ class MGWFBPEngine:
         else:
             batch_spec["tokens"] = P(self.dp_axes, None)
 
-        sync = self.sync
         if issue == "dag":
-            return self._make_dag_step(optimizer, mesh, lr=lr, sync=sync, batch_spec=batch_spec)
+            grads_and_loss = self._dag_grads()
+        else:
+            def grads_and_loss(params, batch):
+                def loss(p):
+                    with jax.named_scope(scopes.FWD_MODEL):
+                        return loss_fn(p, batch, cfg, segments=self.segments)
 
-        def grads_and_loss(params, batch):
-            def loss(p):
-                with jax.named_scope(scopes.FWD_MODEL):
-                    return loss_fn(p, batch, cfg, segments=self.segments)
-
-            return jax.value_and_grad(loss, has_aux=True)(params)
-
-        if self.stateful:
-            # residual leaves carry a leading DP axis; inside the manual
-            # region each device sees its own (1, ...) slice
-            res_spec = P(self.dp_axes)
-
-            def body_ef(params, opt_state, residual, batch):
-                (l, metrics), grads = grads_and_loss(params, batch)
-                local_res = jax.tree.map(lambda r: r[0], residual)
-                grads, new_res = sync(grads, local_res)
-                new_residual = jax.tree.map(lambda r: r[None], new_res)
-                with jax.named_scope(scopes.OPTIMIZER):
-                    new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
-                l = jax.lax.pmean(l, self.dp_axes)
-                counters = self._moe_counters(metrics)
-                return new_params, new_opt, new_residual, {"loss": l, **counters}
-
-            smapped = jax.shard_map(
-                body_ef,
-                mesh=mesh,
-                in_specs=(P(), P(), res_spec, batch_spec),
-                out_specs=(P(), P(), res_spec, P()),
-                axis_names=set(self.dp_axes),
-                check_vma=False,
-            )
-            return self._jit_step(smapped, mesh, (0, 1, 2))
+                (l, metrics), grads = jax.value_and_grad(loss, has_aux=True)(params)
+                return self.sync(grads), l, metrics
 
         def body(params, opt_state, batch):
-            (l, metrics), grads = grads_and_loss(params, batch)
-            grads = sync(grads)
+            grads, l, metrics = grads_and_loss(params, batch)
             with jax.named_scope(scopes.OPTIMIZER):
                 new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
             l = jax.lax.pmean(l, self.dp_axes)
@@ -440,10 +369,14 @@ class MGWFBPEngine:
             axis_names=set(self.dp_axes),
             check_vma=False,
         )
-        return self._jit_step(smapped, mesh, (0, 1))
+        # the all-reduce combiners off (KEEP_GROUPS_APART) where there is
+        # more than one replica to reduce over
+        opts = KEEP_GROUPS_APART if self.dp_world(mesh) > 1 else None
+        return jax.jit(smapped, donate_argnums=(0, 1), compiler_options=opts)
 
-    def _make_dag_step(self, optimizer, mesh, *, lr, sync, batch_spec):
-        """The DAG-scheduled step body (see ``make_train_step``)."""
+    def _dag_grads(self):
+        """The DAG order's ``(params, batch) -> (reduced_grads, loss,
+        metrics)`` (see ``make_train_step``)."""
         cfg = self.cfg
         segments = self.segments
         if segments is None:
@@ -451,12 +384,10 @@ class MGWFBPEngine:
         events = group_issue_events(
             self.schedule, cfg.n_stages, segments, has_tail=bool(cfg.tail_pattern)
         )
-        P = jax.sharding.PartitionSpec
+        sync = self.sync
 
-        def dag_grads(params, batch, residual):
-            """Staged fwd -> backward walk with in-backward group issue.
-
-            Returns ``(reduced_grads, residual, loss, metrics)``."""
+        def dag_grads(params, batch):
+            """Staged fwd -> backward walk with in-backward group issue."""
             embed_fn, seg_fns, tail_fn, head_fn = staged_loss_fns(cfg, batch, segments)
 
             # ---- forward: one vjp pullback per unit event --------------
@@ -492,25 +423,24 @@ class MGWFBPEngine:
             # group, so starting from zeros is fully overwritten).
             acc = dict(jax.tree.map(jnp.zeros_like, params))
             out = jax.tree.map(jnp.zeros_like, params)
-            res = residual
 
-            def issue_ready(event, out, res):
+            def issue_ready(event, out):
                 for gi in events.get(event, ()):
-                    out, res = sync.sync_group(gi, acc, out, res)
-                return out, res
+                    out = sync.sync_group(gi, acc, out)
+                return out
 
             with jax.named_scope(scopes.BWD_HEAD):
                 d_head_p, d_embed_head, dx, daux = pb_head(jnp.ones_like(l))
                 acc["final_norm"] = d_head_p["final_norm"]
                 if not cfg.tie_embeddings:
                     acc["head"] = d_head_p["head"]
-            out, res = issue_ready("head", out, res)
+            out = issue_ready("head", out)
 
             if pb_tail is not None:
                 with jax.named_scope(scopes.BWD_TAIL):
                     d_tail_p, dx = pb_tail((dx, daux))
                     acc["tail"] = d_tail_p
-                out, res = issue_ready("tail", out, res)
+                out = issue_ready("tail", out)
 
             for j in range(len(segments) - 1, -1, -1):
                 start, stop = segments[j]
@@ -519,50 +449,12 @@ class MGWFBPEngine:
                     acc["stages"] = jax.tree.map(
                         lambda g, d: g.at[start:stop].set(d), acc["stages"], d_seg_p
                     )
-                out, res = issue_ready(("seg", j), out, res)
+                out = issue_ready(("seg", j), out)
 
             with jax.named_scope(scopes.BWD_EMBED):
                 (d_embed_lookup,) = pb_embed(dx)
                 acc["embed"] = d_embed_head + d_embed_lookup
-            out, res = issue_ready("embed", out, res)
-            return out, res, l, metrics
+            out = issue_ready("embed", out)
+            return out, l, metrics
 
-        if self.stateful:
-            res_spec = P(self.dp_axes)
-
-            def body_ef(params, opt_state, residual, batch):
-                local_res = jax.tree.map(lambda r: r[0], residual)
-                grads, new_res, l, metrics = dag_grads(params, batch, local_res)
-                new_residual = jax.tree.map(lambda r: r[None], new_res)
-                with jax.named_scope(scopes.OPTIMIZER):
-                    new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
-                l = jax.lax.pmean(l, self.dp_axes)
-                counters = self._moe_counters(metrics)
-                return new_params, new_opt, new_residual, {"loss": l, **counters}
-
-            smapped = jax.shard_map(
-                body_ef,
-                mesh=mesh,
-                in_specs=(P(), P(), res_spec, batch_spec),
-                out_specs=(P(), P(), res_spec, P()),
-                axis_names=set(self.dp_axes),
-                check_vma=False,
-            )
-            return self._jit_step(smapped, mesh, (0, 1, 2))
-
-        def body(params, opt_state, batch):
-            grads, _, l, metrics = dag_grads(params, batch, None)
-            with jax.named_scope(scopes.OPTIMIZER):
-                new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
-            l = jax.lax.pmean(l, self.dp_axes)
-            return new_params, new_opt, {"loss": l, **self._moe_counters(metrics)}
-
-        smapped = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(P(), P(), batch_spec),
-            out_specs=(P(), P(), P()),
-            axis_names=set(self.dp_axes),
-            check_vma=False,
-        )
-        return self._jit_step(smapped, mesh, (0, 1))
+        return dag_grads

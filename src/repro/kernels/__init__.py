@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the compute hot-spots the MG-WFBP schedule
 overlaps against — flash attention, the MoE layer's grouped matmuls,
 RWKV6 WKV, RG-LRU — plus the communication-side pack/unpack pair behind
-the arena wire layout (``core/sync.py`` ``fuse='arena'``).
+the packed-arena gradient wire (``core/sync.py``).
 
 Each kernel package ships kernel.py (pl.pallas_call + BlockSpec VMEM
 tiling), ops.py (dispatching wrapper) and ref.py (pure-jnp oracle; the

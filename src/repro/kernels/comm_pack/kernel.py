@@ -5,17 +5,15 @@ parts and the arena live in ``ANY`` (compiler-placed, HBM at these
 sizes); the kernel streams each part through small VMEM staging buffers
 with explicit async copies:
 
-    pack    part[c:c+m] ──DMA──► VMEM ──cast(+EF)──► VMEM ──DMA──► arena[off+c:]
+    pack    part[c:c+m] ──DMA──► VMEM ──cast──► VMEM ──DMA──► arena[off+c:]
     unpack  arena[off+c:] ──DMA──► VMEM ──cast·scale──► VMEM ──DMA──► part[c:]
 
-so the bf16 (or any wire-dtype) cast — and optionally the
-error-feedback residual add/update of ``runtime/compression.py`` — costs
-zero extra HBM round-trips: exactly one read of the gradients and one
-write of the arena, where XLA's concatenate layout pays a full extra
-copy each way.
+so the bf16 (or any wire-dtype) cast costs zero extra HBM round-trips:
+exactly one read of the gradients and one write of the arena, where a
+concatenate of the parts would pay a full extra copy each way.
 
-Slot offsets are exact-packed (element granularity; the wire buffer is
-byte-identical in size to the concat layout).  A TPU DMA, however, only
+Slot offsets are exact-packed (element granularity; the wire buffer holds
+exactly the group's gradient elements, no padding).  A TPU DMA, however, only
 moves whole HBM tiles of a 1-D array (``ALIGN`` elements, offset and
 length alike), so the kernel takes each part's *aligned body*: the part
 must start on a tile boundary of the arena, and the kernel moves its
@@ -134,49 +132,30 @@ def _pack_kernel(
     offsets: tuple[int, ...],
     chunk: int,
     comm_dtype: Any,
-    ef: bool,
 ):
-    n = len(bodies)
-    parts = refs[:n]
-    resid = refs[n : 2 * n] if ef else ()
-    outs = refs[2 * n :] if ef else refs[n:]
-    arena, new_res = outs[0], outs[1:]
+    parts, arena = refs[:-1], refs[-1]
 
     for i, body in enumerate(bodies):
         ck = min(chunk, body)
 
-        def part(src0, src1, wire0, wire1, in_sem, out_sem, res0=None, res1=None,
-                 res_in_sem=None, res_out_sem=None, i=i, body=body, ck=ck):
-            src, wire, res = (src0, src1), (wire0, wire1), (res0, res1)
+        def part(src0, src1, wire0, wire1, in_sem, out_sem, i=i, body=body, ck=ck):
+            src, wire = (src0, src1), (wire0, wire1)
 
             def in_copies(c0, s):
-                cps = [pltpu.make_async_copy(
+                return [pltpu.make_async_copy(
                     parts[i].at[pl.ds(c0, ck)], src[s], in_sem.at[s])]
-                if ef:
-                    cps.append(pltpu.make_async_copy(
-                        resid[i].at[pl.ds(c0, ck)], res[s], res_in_sem.at[s]))
-                return cps
 
             def out_copies(c0, s):
-                cps = [pltpu.make_async_copy(
+                return [pltpu.make_async_copy(
                     wire[s], arena.at[pl.ds(offsets[i] + c0, ck)], out_sem.at[s])]
-                if ef:
-                    cps.append(pltpu.make_async_copy(
-                        res[s], new_res[i].at[pl.ds(c0, ck)], res_out_sem.at[s]))
-                return cps
 
             def compute(s):
-                x = src[s][...].astype(jnp.float32)
-                if ef:
-                    x = x + res[s][...]
-                w = x.astype(comm_dtype)
-                wire[s][...] = w
-                if ef:
-                    res[s][...] = x - w.astype(jnp.float32)
+                wire[s][...] = src[s][...].astype(jnp.float32).astype(comm_dtype)
 
             _pipeline(body, ck, in_copies, out_copies, compute)
 
-        scratch = dict(
+        pl.run_scoped(
+            part,
             src0=pltpu.VMEM((ck,), parts[i].dtype),
             src1=pltpu.VMEM((ck,), parts[i].dtype),
             wire0=pltpu.VMEM((ck,), comm_dtype),
@@ -184,14 +163,6 @@ def _pack_kernel(
             in_sem=pltpu.SemaphoreType.DMA((2,)),
             out_sem=pltpu.SemaphoreType.DMA((2,)),
         )
-        if ef:
-            scratch.update(
-                res0=pltpu.VMEM((ck,), jnp.float32),
-                res1=pltpu.VMEM((ck,), jnp.float32),
-                res_in_sem=pltpu.SemaphoreType.DMA((2,)),
-                res_out_sem=pltpu.SemaphoreType.DMA((2,)),
-            )
-        pl.run_scoped(part, **scratch)
 
 
 def pack_arena_pallas(
@@ -199,14 +170,12 @@ def pack_arena_pallas(
     offsets: Sequence[int],
     size: int,
     comm_dtype: Any,
-    residuals: Sequence[jax.Array] | None = None,  # 1-D f32
     *,
     chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
-) -> tuple[jax.Array, list[jax.Array] | None]:
-    """Fused pack(+cast[+error-feedback]) of one group's wire arena."""
+) -> jax.Array:
+    """Fused pack(+cast) of one group's wire arena."""
     _check_chunk(chunk)
-    ef = residuals is not None
     offsets = tuple(int(o) for o in offsets)
     sizes = tuple(int(p.size) for p in parts)
     bodies = tuple(aligned_body(o, n) for o, n in zip(offsets, sizes))
@@ -218,34 +187,23 @@ def pack_arena_pallas(
             offsets=tuple(offsets[i] for i in kin),
             chunk=chunk,
             comm_dtype=comm_dtype,
-            ef=ef,
         )
-        out_shape = [jax.ShapeDtypeStruct((size,), comm_dtype)]
-        if ef:
-            out_shape += [jax.ShapeDtypeStruct((sizes[i],), jnp.float32) for i in kin]
-        operands = [parts[i] for i in kin] + ([residuals[i] for i in kin] if ef else [])
-        out = pl.pallas_call(
+        (arena,) = pl.pallas_call(
             kernel,
             name=scopes.COMM_PACK_PACK,
-            in_specs=[_ANY] * len(operands),
-            out_specs=[_ANY] * len(out_shape),
-            out_shape=out_shape,
+            in_specs=[_ANY] * len(kin),
+            out_specs=[_ANY],
+            out_shape=[jax.ShapeDtypeStruct((size,), comm_dtype)],
             interpret=interpret,
-        )(*operands)
-        arena = out[0]
-        new_res = dict(zip(kin, out[1:]))
+        )(*[parts[i] for i in kin])
     else:
         arena = jnp.zeros((size,), comm_dtype)
-        new_res = {}
     # the remainder: ragged tails, and parts that start mid-tile
     for i, (off, n, b) in enumerate(zip(offsets, sizes, bodies)):
-        if b == n:
-            continue
-        w, r = encode_part(parts[i][b:], residuals[i][b:] if ef else None, comm_dtype)
-        arena = jax.lax.dynamic_update_slice(arena, w, (off + b,))
-        if ef:
-            new_res[i] = jax.lax.dynamic_update_slice(new_res[i], r, (b,)) if b else r
-    return arena, ([new_res[i] for i in range(len(parts))] if ef else None)
+        if b < n:
+            arena = jax.lax.dynamic_update_slice(
+                arena, encode_part(parts[i][b:], comm_dtype), (off + b,))
+    return arena
 
 
 def _unpack_kernel(

@@ -29,34 +29,25 @@ def pack_arena(
     offsets: Sequence[int],
     size: int,
     comm_dtype: Any,
-    residuals: Sequence[jax.Array] | None = None,
     *,
     use_pallas: bool | None = None,
     interpret: bool = False,
     chunk: int | None = None,
-) -> tuple[jax.Array, list[jax.Array] | None]:
-    """Pack one group's parts into its flat wire arena.
-
-    Fuses the wire-dtype cast, and — when ``residuals`` (f32, same
-    structure) is given — the error-feedback accumulate/update.  Returns
-    ``(arena, new_residuals)``; residuals keep the parts' shapes.
-    ``chunk`` overrides the staging-buffer length (elements) on the
-    Pallas path — tests shrink it to force the multi-chunk DMA pipeline.
+) -> jax.Array:
+    """Pack one group's parts into its flat wire arena, the wire-dtype
+    cast fused in.  ``chunk`` overrides the staging-buffer length
+    (elements) on the Pallas path — tests shrink it to force the
+    multi-chunk DMA pipeline.
     """
     flat = [p.reshape(-1) for p in parts]
-    res_flat = None if residuals is None else [r.reshape(-1) for r in residuals]
     if _use_pallas(use_pallas) or interpret:
         kw = {} if chunk is None else {"chunk": chunk}
-        arena, new_res = per_device(
-            lambda flat, res_flat: pack_arena_pallas(
-                flat, offsets, size, comm_dtype, res_flat, interpret=interpret, **kw
+        return per_device(
+            lambda flat: pack_arena_pallas(
+                flat, offsets, size, comm_dtype, interpret=interpret, **kw
             )
-        )(flat, res_flat)
-    else:
-        arena, new_res = pack_arena_ref(flat, offsets, size, comm_dtype, res_flat)
-    if new_res is not None:
-        new_res = [r.reshape(p.shape) for r, p in zip(new_res, parts)]
-    return arena, new_res
+        )(flat)
+    return pack_arena_ref(flat, offsets, size, comm_dtype)
 
 
 def unpack_arena(

@@ -2,15 +2,12 @@
 
 Pack writes each (flattened) gradient part into its slot of one flat wire
 arena with ``dynamic_update_slice`` — no ``concatenate`` in the lowering,
-which is the whole point of the arena wire layout (``core/sync.py``
-``fuse='arena'``): XLA updates the preallocated buffer in place instead
-of materializing a second copy of every group's gradients.
+which is the whole point of the arena wire (``core/sync.py``): XLA
+updates the preallocated buffer in place instead of materializing a
+second copy of every group's gradients.
 
-The wire-dtype cast is fused into the pack; optionally so is the
-error-feedback residual (``runtime/compression.py``): the carried
-quantization error is re-added *before* the cast and the new residual is
-whatever the cast dropped.  Unpack fuses the inverse cast and the DP
-averaging scale.
+The wire-dtype cast is fused into the pack; unpack fuses the inverse cast
+and the DP averaging scale.
 """
 
 from __future__ import annotations
@@ -21,18 +18,9 @@ import jax
 import jax.numpy as jnp
 
 
-def encode_part(
-    part: jax.Array,  # 1-D gradient span
-    residual: jax.Array | None,  # 1-D f32, same size, or None
-    comm_dtype: Any,
-) -> tuple[jax.Array, jax.Array | None]:
-    """(wire, new_residual) of one span: the cast, with the carried
-    quantization error re-added first when ``residual`` is given."""
-    if residual is None:
-        return part.astype(comm_dtype), None
-    acc = part.astype(jnp.float32) + residual.astype(jnp.float32)
-    wire = acc.astype(comm_dtype)
-    return wire, acc - wire.astype(jnp.float32)
+def encode_part(part: jax.Array, comm_dtype: Any) -> jax.Array:
+    """The wire value of one 1-D gradient span: its cast."""
+    return part.astype(comm_dtype)
 
 
 def decode_part(seg: jax.Array, dtype: Any, scale: jax.Array | float) -> jax.Array:
@@ -45,17 +33,12 @@ def pack_arena_ref(
     offsets: Sequence[int],  # element offset of each part in the arena
     size: int,  # total arena elements (== sum of part sizes)
     comm_dtype: Any,
-    residuals: Sequence[jax.Array] | None = None,  # 1-D f32, same sizes
-) -> tuple[jax.Array, list[jax.Array] | None]:
-    """(arena, new_residuals) — residuals None for the stateless cast."""
+) -> jax.Array:
+    """The group's wire arena: each part cast into its slot."""
     arena = jnp.zeros((size,), comm_dtype)
-    new_res: list[jax.Array] | None = None if residuals is None else []
-    for i, (p, off) in enumerate(zip(parts, offsets)):
-        wire, r = encode_part(p, None if residuals is None else residuals[i], comm_dtype)
-        if new_res is not None:
-            new_res.append(r)
-        arena = jax.lax.dynamic_update_slice(arena, wire, (off,))
-    return arena, new_res
+    for p, off in zip(parts, offsets):
+        arena = jax.lax.dynamic_update_slice(arena, encode_part(p, comm_dtype), (off,))
+    return arena
 
 
 def unpack_arena_ref(

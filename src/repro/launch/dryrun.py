@@ -229,7 +229,7 @@ def plan_record(cfg, shape, segs, mesh, n_dev, comm_fit=None,
     ``MeasuredComm`` sweep, --comm-fit) swaps the analytic α–β model for
     a measured fit.  Restarts and benchmarks reload these records
     instead of recomputing Algorithm 1; each plan carries its per-group
-    arena wire layout (``fuse='arena'`` buffer sizes).
+    arena wire layout (the packed buffer sizes).
     """
     from repro.core.bucketing import stacked_lm_layout
     from repro.fabric import get_fabric

@@ -27,10 +27,10 @@ Planning lifecycle wiring (journal MG-WFBP's online re-planning):
     beside the latest checkpoint, or re-enter the plan search when none
     is stored, through the ``resilient_loop`` hooks;
   * ``--plan-out`` additionally serializes the final plan for elastic
-    restarts, dry-runs, and benchmarks to reuse;
-  * ``--fuse arena`` ships gradients over the packed-arena wire path and
-    ``--compression bf16_ef`` threads the error-feedback residual through
-    the train step and checkpoints (EF survives restarts).
+    restarts, dry-runs, and benchmarks to reuse.
+
+Gradients ship over the packed-arena wire (``core/sync.py``) in f32, or
+in bf16 under ``--comm-dtype bf16``.
 """
 
 from __future__ import annotations
@@ -140,21 +140,13 @@ def _dryrun(args, eng, step_fn, init_state, data, mesh) -> None:
     state = init_state()
     batch = jax.tree.map(jnp.asarray, data.batch_at(0))
 
-    def inputs(state):
-        if eng.stateful:
-            return state.params, state.opt_state, state.residual, batch
-        return state.params, state.opt_state, batch
-
     with jax.set_mesh(mesh):
-        compiled = step_fn.lower(*inputs(state)).compile()
+        compiled = step_fn.lower(state.params, state.opt_state, batch).compile()
 
     def one(state):
         with jax.set_mesh(mesh):
-            out = compiled(*inputs(state))
-        p, o, m = out[0], out[1], out[-1]
-        res = out[2] if eng.stateful else state.residual
-        return RunState(step=state.step + 1, params=p, opt_state=o,
-                        residual=res), m
+            p, o, m = compiled(state.params, state.opt_state, batch)
+        return RunState(step=state.step + 1, params=p, opt_state=o), m
 
     if args.dryrun > 1:  # steady state: the trace holds no first-run work
         state, m = one(state)
@@ -219,18 +211,11 @@ def _parser() -> argparse.ArgumentParser:
                     help="scheduler policy (planning registry; default mg_wfbp). "
                          "With --plan-in, only valid if it matches the plan's policy; "
                          "ignored under --autotune (the sweep picks).")
-    ap.add_argument("--comm-dtype", default="f32", choices=["f32", "bf16"])
-    ap.add_argument("--compression", default=None,
-                    choices=["bf16", "bf16_ef"],
-                    help="wire compression (default: follows --comm-dtype). "
-                         "bf16_ef carries the error-feedback residual through "
-                         "the train step and checkpoints (requires --fuse arena)")
-    ap.add_argument("--fuse", default="concat",
-                    choices=["concat", "variadic", "arena"],
-                    help="wire layout: concat (one flat buffer, copy each way), "
-                         "variadic (zero-copy tuple psum), arena (packed flat "
-                         "buffer via kernels/comm_pack — one all-reduce per "
-                         "group AND no concatenate copies)")
+    ap.add_argument("--comm-dtype", default="f32", choices=["f32", "bf16"],
+                    help="dtype of the gradient wire")
+    ap.add_argument("--fuse", default="arena", choices=["arena"],
+                    help="wire layout: the packed arena (kernels/comm_pack) is "
+                         "the only one; the option sets nothing")
     ap.add_argument("--virtual-dp", type=int, default=32,
                     help="DP size assumed by the α–β schedule model")
     ap.add_argument("--fabric", default="tpu_v5e",
@@ -291,10 +276,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
         ap.error("--plan-in and --autotune are mutually exclusive: the "
                  "tuner's sweep picks the plan (drop --autotune to pin a "
                  "serialized plan)")
-    if args.compression is None and args.comm_dtype == "bf16":
-        args.compression = "bf16"
-    if args.compression == "bf16_ef" and args.fuse != "arena":
-        ap.error("--compression bf16_ef requires --fuse arena")
     return args
 
 
@@ -307,11 +288,7 @@ def setup(args: argparse.Namespace) -> TrainSetup:
     hw = hardware_for(jax.devices()[0])
     n_dev = jax.device_count()
     mesh = make_mesh((n_dev, 1), ("data", "model"))
-    sync_cfg = SyncConfig(
-        comm_dtype=jnp.bfloat16 if args.comm_dtype == "bf16" else jnp.float32,
-        compression=args.compression,
-        fuse=args.fuse,
-    )
+    sync_cfg = SyncConfig(comm_dtype=jnp.bfloat16 if args.comm_dtype == "bf16" else jnp.float32)
 
     if args.measure_comm:
         comm_obs = MeasuredComm.time_psums(mesh, ("data",))
@@ -364,7 +341,7 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
             layout=state_box["eng"].plan.layout,
             n_scan_stages=cfg.n_stages,
             shapes=param_specs(cfg),
-            wire_dtype=jnp.dtype(sync_cfg.wire_dtype).name,
+            wire_dtype=jnp.dtype(sync_cfg.comm_dtype).name,
             provenance={"arch": cfg.name},
         )
         # probe inputs are only materialized (and their jitted probes only
@@ -424,17 +401,11 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
         # the shardings its outputs come back with, and compiles once.
         # Each device draws its own replica, so no device holds an extra
         # unreplicated copy or the draw's f32 temporaries.
-        eng = state_box["eng"]
         replicated = NamedSharding(mesh, P())
         params = jax.jit(init_params, static_argnums=1, out_shardings=replicated)(
             jax.random.PRNGKey(args.seed), cfg)
-        residual = eng.init_residual(params, mesh)
-        return RunState(
-            step=0, params=params,
-            opt_state=jax.device_put(opt.init(params), replicated),
-            residual=None if residual is None
-            else jax.device_put(residual, NamedSharding(mesh, P(eng.dp_axes))),
-        )
+        return RunState(step=0, params=params,
+                        opt_state=jax.device_put(opt.init(params), replicated))
 
     if args.dryrun:
         _dryrun(args, state_box["eng"], state_box["step_fn"], init_state, data, mesh)
@@ -508,16 +479,9 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
     def _do_step(state: RunState, step: int) -> RunState:
         with jax.profiler.TraceAnnotation("train.batch"):
             batch = jax.tree.map(jnp.asarray, data.batch_at(step))
-        eng = state_box["eng"]
         timer.start()
         with jax.set_mesh(mesh), jax.profiler.TraceAnnotation("train.dispatch"):
-            if eng.stateful:
-                p, o, res, m = state_box["step_fn"](
-                    state.params, state.opt_state, state.residual, batch
-                )
-            else:
-                p, o, m = state_box["step_fn"](state.params, state.opt_state, batch)
-                res = state.residual
+            p, o, m = state_box["step_fn"](state.params, state.opt_state, batch)
         if track_time:
             # timing needs a host-device sync; skip both when every online
             # check is off so the dispatch pipeline stays async
@@ -534,8 +498,7 @@ def main(argv: list[str] | None = None) -> TrainResult | None:
         if step % 10 == 0:
             with jax.profiler.TraceAnnotation("train.log"):
                 print(f"[train] step {step} loss {float(m['loss']):.4f}")
-        return RunState(step=state.step, params=p, opt_state=o,
-                        restarts=state.restarts, residual=res)
+        return RunState(step=state.step, params=p, opt_state=o, restarts=state.restarts)
 
     def on_restart(state: RunState) -> RunState:
         # Same-shape restart: resume under the exact plan the checkpoint

@@ -18,7 +18,7 @@ Parameters
 The train/serve step functions take ``segments`` — ``(start, stop)`` stage
 ranges produced by the MG-WFBP schedule (``core.bucketing``); each segment
 is scanned separately so its gradient message is an independent HLO value
-that the sync engine all-reduces as one merged (variadic) collective which
+that the sync engine all-reduces as one merged (arena) collective which
 XLA can overlap with the previous segment's backward compute.
 """
 

@@ -85,7 +85,7 @@ class Plan:
 
     def group_arenas(self, shapes: Any, comm_dtype: Any = "float32") -> list[GroupArena]:
         """Per-group flat wire layouts for this plan's schedule — what
-        ``fuse='arena'`` packs into (``shapes``: the parameter pytree or a
+        the arena wire packs into (``shapes``: the parameter pytree or a
         ``path -> shape`` callable; see ``bucketing.group_arenas``)."""
         return group_arenas(self.layout, self.schedule, shapes, comm_dtype)
 

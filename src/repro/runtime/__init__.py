@@ -1,11 +1,4 @@
 from .fault_tolerance import RunState, StragglerMonitor, resilient_loop
-from .compression import (
-    ErrorFeedbackState,
-    bf16_ef_decode,
-    bf16_ef_encode,
-    compressed_psum_rs_ag,
-    ef_init,
-)
 from .timeline import (
     StepTimer,
     UnitProfile,
@@ -18,11 +11,6 @@ __all__ = [
     "RunState",
     "StragglerMonitor",
     "resilient_loop",
-    "ErrorFeedbackState",
-    "bf16_ef_decode",
-    "bf16_ef_encode",
-    "compressed_psum_rs_ag",
-    "ef_init",
     "StepTimer",
     "UnitProfile",
     "make_unit_probes",

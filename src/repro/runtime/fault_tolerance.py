@@ -47,16 +47,9 @@ class RunState:
     params: Pytree
     opt_state: Pytree
     restarts: int = 0
-    #: error-feedback residual pytree (compression='bf16_ef'); None for
-    #: stateless runs.  Checkpointed beside params/opt_state so EF
-    #: compression survives restarts.
-    residual: Pytree | None = None
 
     def checkpoint_tree(self) -> dict:
-        tree = {"params": self.params, "opt_state": self.opt_state}
-        if self.residual is not None:
-            tree["residual"] = self.residual
-        return tree
+        return {"params": self.params, "opt_state": self.opt_state}
 
 
 class StragglerMonitor:
@@ -189,7 +182,6 @@ def resilient_loop(
                 params=tree["params"],
                 opt_state=tree["opt_state"],
                 restarts=restarts,
-                residual=tree.get("residual"),
             )
             if on_restart is not None:
                 state = on_restart(state)

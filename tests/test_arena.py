@@ -3,13 +3,13 @@
 Covers the PR-2 tentpole end to end:
 
   * pack→unpack numeric round-trip (oracle vs Pallas-interpret), incl.
-    scan-stacked slices, odd-sized tails, and the fused error-feedback
-    residual;
+    scan-stacked slices, odd-sized tails, and the multi-chunk DMA
+    pipeline in both wire dtypes;
   * the plan-time ``group_arenas`` layout: exact packing (zero padding),
     offsets/sizes, scan-slice shapes;
-  * lowered-HLO invariants for all three fuse modes: exact all-reduce op
-    AND byte counts (``profiler.parse_collectives`` on stablehlo), zero
-    concatenate ops on the arena path, bf16 halving wire bytes;
+  * lowered-HLO invariants of the arena wire: exact all-reduce op AND
+    byte counts (``profiler.parse_collectives`` on stablehlo), zero
+    concatenate ops, bf16 halving wire bytes;
   * seeded ``MeasuredComm`` α–β fit recovery;
   * plan-aware checkpointing: the plan JSON rides beside the weights.
 """
@@ -34,10 +34,8 @@ from repro.core import (
     parse_collectives,
     stacked_lm_layout,
 )
-from repro.core.sync import SyncConfig, make_gradient_sync
 from repro.kernels.comm_pack import pack_arena, unpack_arena
 from repro.planning import MeasuredComm, build_schedule
-from repro.runtime import bf16_ef_encode
 
 
 def _parts(seed=0, shapes=((3, 5), (7,), (2, 2, 3), (1,), (11,))):
@@ -52,8 +50,8 @@ class TestPackUnpack:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_round_trip_ref_vs_pallas(self, dtype):
         parts, offsets, sizes, total = self._setup()
-        a_ref, _ = pack_arena(parts, offsets, total, dtype, use_pallas=False)
-        a_pal, _ = pack_arena(parts, offsets, total, dtype, interpret=True)
+        a_ref = pack_arena(parts, offsets, total, dtype, use_pallas=False)
+        a_pal = pack_arena(parts, offsets, total, dtype, interpret=True)
         np.testing.assert_array_equal(
             np.asarray(a_ref, np.float32), np.asarray(a_pal, np.float32)
         )
@@ -77,37 +75,24 @@ class TestPackUnpack:
         # odd 7, 1, 11 behind them go through the oracle's encode/decode
         return _parts(shapes=((3, 1024), (2, 2, 512), (7,), (1,), (11,)))
 
-    @pytest.mark.parametrize("ef", [False, True])
-    def test_multi_chunk_pipeline_matches_oracle(self, ef):
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_multi_chunk_pipeline_matches_oracle(self, dtype):
         """Shrinking ``chunk`` below the part sizes forces the
         double-buffered DMA pipeline (warm-up + cross-chunk slot reuse, a
         last chunk pulled back onto its predecessor, ragged tails, parts
         at mid-tile offsets) — results must stay bit-identical to the
-        oracle."""
+        oracle, on the f32 wire the cells run and on the bf16 one."""
         parts, offsets, sizes, total = _parts(
             seed=3, shapes=((5, 1024), (2500,), (37,), (3, 1024), (1,), (1001,))
         )
-        rng = np.random.default_rng(4)
-        res = (
-            [jnp.asarray(rng.standard_normal(p.shape) * 1e-3, jnp.float32)
-             for p in parts]
-            if ef else None
-        )
-        a_ref, r_ref = pack_arena(
-            parts, offsets, total, jnp.bfloat16, residuals=res, use_pallas=False
-        )
+        a_ref = pack_arena(parts, offsets, total, dtype, use_pallas=False)
         # chunk=2048: the 5120-part takes chunks at 0, 2048 and 3072; the
         # 2500-part one chunk and a 452 tail; the rest start mid-tile
-        a_pal, r_pal = pack_arena(
-            parts, offsets, total, jnp.bfloat16, residuals=res,
-            interpret=True, chunk=2048,
-        )
+        a_pal = pack_arena(parts, offsets, total, dtype, interpret=True, chunk=2048)
+        assert a_ref.dtype == a_pal.dtype == dtype
         np.testing.assert_array_equal(
             np.asarray(a_ref, np.float32), np.asarray(a_pal, np.float32)
         )
-        if ef:
-            for rr, rp in zip(r_ref, r_pal):
-                np.testing.assert_array_equal(np.asarray(rr), np.asarray(rp))
         slots = list(zip(offsets, sizes))
         shapes = [p.shape for p in parts]
         dts = [p.dtype for p in parts]
@@ -116,33 +101,6 @@ class TestPackUnpack:
                              interpret=True, chunk=2048)
         for r, p in zip(o_ref, o_pal):
             np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
-
-    def test_error_feedback_matches_compression_oracle(self):
-        parts, offsets, sizes, total = self._setup()
-        rng = np.random.default_rng(1)
-        res = [jnp.asarray(rng.standard_normal(p.shape) * 1e-3, jnp.float32)
-               for p in parts]
-        for kw in ({"use_pallas": False}, {"interpret": True}):
-            arena, new_res = pack_arena(
-                parts, offsets, total, jnp.bfloat16, residuals=res, **kw
-            )
-            for p, r0, r1, off, n in zip(parts, res, new_res, offsets, sizes):
-                wire_want, res_want = bf16_ef_encode(p, r0)
-                np.testing.assert_array_equal(
-                    np.asarray(arena[off : off + n], np.float32),
-                    np.asarray(wire_want, np.float32).reshape(-1),
-                )
-                assert r1.shape == p.shape
-                np.testing.assert_allclose(
-                    np.asarray(r1), np.asarray(res_want), atol=1e-7
-                )
-                # EF identity: wire + residual reconstructs the accumulator
-                np.testing.assert_allclose(
-                    np.asarray(arena[off : off + n], np.float32).reshape(p.shape)
-                    + np.asarray(r1),
-                    np.asarray(p) + np.asarray(r0),
-                    atol=1e-7,
-                )
 
 
 def _toy_layout(n_stages=4):
@@ -225,8 +183,8 @@ ARENA_LOWERING_SCRIPT = textwrap.dedent("""
 
     from repro.launch.mesh import make_mesh
     from repro.core import (
-        AllReduceModel, SyncConfig, count_expected_allreduces,
-        make_gradient_sync, parse_collectives, stacked_lm_layout,
+        AllReduceModel, SyncConfig, make_gradient_sync, parse_collectives,
+        stacked_lm_layout,
     )
     from repro.planning import build_schedule
 
@@ -250,94 +208,57 @@ ARENA_LOWERING_SCRIPT = textwrap.dedent("""
     for policy in ("per_tensor", "single", "bucketed"):
         sched = build_schedule(policy, costs, AllReduceModel(a=1e-3, b=1e-9))
         rec = {"policy": policy, "n_groups": len(sched.groups)}
-        for fuse in ("concat", "variadic", "arena"):
-            for comp in (None, "bf16"):
-                cfgs = SyncConfig(fuse=fuse, compression=comp)
-                sync = make_gradient_sync(layout, sched, ("data",), cfgs)
+        for comp, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
+            sync = make_gradient_sync(layout, sched, ("data",), SyncConfig(comm_dtype=dtype))
 
-                def body(g):
-                    r = jax.lax.axis_index("data").astype(jnp.float32)
-                    return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
+            def body(g):
+                r = jax.lax.axis_index("data").astype(jnp.float32)
+                return sync(jax.tree.map(lambda x: x * (r + 1.0), g))
 
-                f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                                      axis_names={"data"}, check_vma=False))
-                stats = parse_collectives(f.lower(grads).as_text())
-                got = f(grads)
-                expect = jax.tree.map(lambda x: 4.5 * x, grads)
-                diff = max(jax.tree.leaves(jax.tree.map(
-                    lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect)))
-                rec[f"{fuse}_{comp or 'f32'}"] = {
-                    "allreduce_ops": stats.counts.get("all-reduce", 0),
-                    "expected": count_expected_allreduces(sched, cfgs, layout),
-                    "wire_bytes": stats.bytes_by_kind.get("all-reduce", 0),
-                    "concat_ops": stats.concat_ops,
-                    "max_diff": diff,
-                }
+            f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                                  axis_names={"data"}, check_vma=False))
+            stats = parse_collectives(f.lower(grads).as_text())
+            got = f(grads)
+            expect = jax.tree.map(lambda x: 4.5 * x, grads)
+            diff = max(jax.tree.leaves(jax.tree.map(
+                lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect)))
+            rec[comp] = {
+                "allreduce_ops": stats.counts.get("all-reduce", 0),
+                "wire_bytes": stats.bytes_by_kind.get("all-reduce", 0),
+                "concat_ops": stats.concat_ops,
+                "max_diff": diff,
+            }
         rec["n_elems"] = int(n_elems)
         out.append(rec)
-
-    # stateful error-feedback arena mode
-    sched = build_schedule("bucketed", costs, AllReduceModel(a=1e-3, b=1e-9))
-    cfgs = SyncConfig(fuse="arena", compression="bf16_ef")
-    sync = make_gradient_sync(layout, sched, ("data",), cfgs)
-    res0 = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), grads)
-
-    def body_ef(g, r):
-        return sync(g, r)
-
-    f = jax.jit(jax.shard_map(body_ef, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=(P(), P()), axis_names={"data"}, check_vma=False))
-    stats = parse_collectives(f.lower(grads, res0).as_text())
-    o, r1 = f(grads, res0)
-    # identical ranks: avg == bf16 value, so out + residual == grads exactly
-    rec_ef = {
-        "allreduce_ops": stats.counts.get("all-reduce", 0),
-        "n_groups": len(sched.groups),
-        "concat_ops": stats.concat_ops,
-        "recon_diff": max(jax.tree.leaves(jax.tree.map(
-            lambda a, b, c: float(jnp.max(jnp.abs(a + b - c))), o, r1, grads))),
-    }
-    print(json.dumps({"cases": out, "ef": rec_ef}))
+    print(json.dumps(out))
 """)
 
 
 def test_arena_lowering_op_and_byte_counts():
-    """Acceptance: ``fuse='arena'`` lowers to exactly one all-reduce HLO op
-    per schedule group with ZERO concatenate ops, at exactly the concat
-    layout's wire bytes (half of them under bf16) — per policy, via
-    ``profiler.parse_collectives``."""
+    """Acceptance: the arena wire lowers to exactly one all-reduce HLO op
+    per schedule group with ZERO concatenate ops, at exactly the group's
+    gradient elements in wire bytes (half of them under bf16) — per
+    policy, via ``profiler.parse_collectives``."""
     out = subprocess.run(
         [sys.executable, "-c", ARENA_LOWERING_SCRIPT],
         capture_output=True, text=True, timeout=900,
         env=SUBPROC_ENV, cwd=REPO_ROOT,
     )
     assert out.returncode == 0, out.stderr[-3000:]
-    payload = json.loads(out.stdout.strip().splitlines()[-1])
-    for rec in payload["cases"]:
+    cases = json.loads(out.stdout.strip().splitlines()[-1])
+    assert {r["policy"] for r in cases} == {"per_tensor", "single", "bucketed"}
+    for rec in cases:
         n_groups, n_elems = rec["n_groups"], rec["n_elems"]
         for comp, itemsize in (("f32", 4), ("bf16", 2)):
-            arena = rec[f"arena_{comp}"]
-            concat = rec[f"concat_{comp}"]
-            variadic = rec[f"variadic_{comp}"]
-            # arena: one op per group, zero concatenates, exact bytes
+            arena = rec[comp]
+            # one op per group, zero concatenates, exact bytes
             assert arena["allreduce_ops"] == n_groups, rec
-            assert arena["expected"] == n_groups, rec
             assert arena["concat_ops"] == 0, rec
             assert arena["wire_bytes"] == n_elems * itemsize, rec
-            # byte parity with concat, and bf16 halves the wire exactly
-            assert arena["wire_bytes"] <= concat["wire_bytes"], rec
-            assert concat["allreduce_ops"] == n_groups, rec
-            # variadic stays zero-copy but op counts are version-dependent
-            assert variadic["concat_ops"] == 0, rec
-            assert variadic["allreduce_ops"] == variadic["expected"], rec
-            for fuse in ("arena", "concat", "variadic"):
-                tol = 1e-4 if comp == "f32" else 0.1
-                assert rec[f"{fuse}_{comp}"]["max_diff"] < tol, (fuse, comp, rec)
-        assert rec["arena_bf16"]["wire_bytes"] * 2 == rec["concat_f32"]["wire_bytes"], rec
-    ef = payload["ef"]
-    assert ef["allreduce_ops"] == ef["n_groups"]
-    assert ef["concat_ops"] == 0
-    assert ef["recon_diff"] == pytest.approx(0.0, abs=1e-6)
+            tol = 1e-4 if comp == "f32" else 0.1
+            assert arena["max_diff"] < tol, (comp, rec)
+        # bf16 halves the wire exactly
+        assert rec["bf16"]["wire_bytes"] * 2 == rec["f32"]["wire_bytes"], rec
 
 
 class TestMeasuredComm:
@@ -422,23 +343,55 @@ class TestPlanAwareCheckpoint:
         assert load_plan(tmp_path, 5) == plan
 
 
-class TestCompatProbe:
-    def test_variadic_probe_cached_and_consistent(self):
-        from repro.core.sync import variadic_psum_is_single_op
+def _cell_argvs():
+    """Each benchmark cell's launcher command line, as the harness builds
+    it: ``--arch`` from the cell's config file, then the cell's flags."""
+    manifest = json.loads((REPO_ROOT / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in manifest["configs"]}
+    out = {}
+    for w in manifest["workloads"]:
+        arch = json.loads((REPO_ROOT / files[w["config"]]).read_text())["arch"]
+        spec = json.loads((REPO_ROOT / "bench" / "cells" / f"{w['name']}.json").read_text())
+        out[w["name"]] = ["--arch", arch, *spec["launcher"]]
+    return out
 
-        first = variadic_psum_is_single_op()
-        assert variadic_psum_is_single_op() is first  # functools.cache
-        assert variadic_psum_is_single_op.cache_info().hits >= 1
-        assert isinstance(first, bool)
 
-    def test_sync_rejects_bad_modes(self):
-        shapes, layout = _toy_layout()
-        costs = layout.layer_costs(1024, None)
-        sched = build_schedule("single", costs, AllReduceModel(a=1e-3, b=1e-9))
-        with pytest.raises(ValueError, match="unknown fuse"):
-            make_gradient_sync(layout, sched, ("data",), SyncConfig(fuse="nope"))
-        with pytest.raises(ValueError, match="requires fuse='arena'"):
-            make_gradient_sync(
-                layout, sched, ("data",),
-                SyncConfig(fuse="concat", compression="bf16_ef"),
-            )
+class TestLauncherWire:
+    @pytest.mark.parametrize("cell", sorted(_cell_argvs()))
+    def test_cell_command_line_parses_to_the_f32_arena(self, cell):
+        from repro.core.sync import SyncConfig
+        from repro.launch import train
+
+        args = train.parse_args(_cell_argvs()[cell])
+        assert args.comm_dtype == "f32"
+        assert train.setup(args).sync_cfg == SyncConfig(comm_dtype=jnp.float32)
+
+    @pytest.mark.parametrize("flags", [
+        ["--fuse", "concat"], ["--fuse", "variadic"], ["--compression", "bf16"],
+    ])
+    def test_removed_wire_options_are_refused(self, flags, capsys):
+        from repro.launch import train
+
+        with pytest.raises(SystemExit) as exc:
+            train.parse_args(["--arch", "tinyllama-1.1b", *flags])
+        assert exc.value.code == 2  # argparse's usage error
+        assert flags[0] in capsys.readouterr().err
+
+    def test_autotune_run_scores_the_arena_at_the_wire_dtype(self, tmp_path, monkeypatch):
+        """``--autotune`` builds its Tuner from the launcher's SyncConfig and
+        trains under the plan it picks."""
+        from repro.launch import train
+
+        seen = []
+
+        class Recording(train.Tuner):
+            def __init__(self, **kw):
+                seen.append(kw["wire_dtype"])
+                super().__init__(**kw)
+
+        monkeypatch.setattr(train, "Tuner", Recording)
+        res = train.main(["--arch", "tinyllama-1.1b", "--reduced", "--steps", "2",
+                          "--batch", "2", "--seq", "64", "--autotune",
+                          "--ckpt-dir", str(tmp_path)])
+        assert seen == ["float32"]
+        assert len(res.losses) == 2 and all(np.isfinite(res.losses))

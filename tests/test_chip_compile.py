@@ -85,18 +85,9 @@ def groups():
     return {"largest": parts(arenas[-1]), "smallest": parts(arenas[0]), "ragged": ragged}
 
 
-@pytest.mark.parametrize(
-    "group,wire,ef",
-    [
-        ("largest", jnp.float32, False),
-        ("largest", jnp.bfloat16, False),
-        ("smallest", jnp.float32, False),
-        ("smallest", jnp.bfloat16, False),
-        ("ragged", jnp.bfloat16, False),
-        ("ragged", jnp.bfloat16, True),
-    ],
-)
-def test_arena_kernels_compile_for_v5e(one_chip, groups, group, wire, ef):
+@pytest.mark.parametrize("group", ["largest", "smallest", "ragged"])
+@pytest.mark.parametrize("wire", [jnp.float32, jnp.bfloat16])
+def test_arena_kernels_compile_for_v5e(one_chip, groups, group, wire):
     sizes = [n for n, _ in groups[group]]
     dtypes = [dt for _, dt in groups[group]]
     offsets = [sum(sizes[:i]) for i in range(len(sizes))]
@@ -106,13 +97,8 @@ def test_arena_kernels_compile_for_v5e(one_chip, groups, group, wire, ef):
         return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
 
     parts = [spec(n, dt) for n, dt in groups[group]]
-    if ef:
-        res = [spec(n, jnp.float32) for n in sizes]
-        pack = jax.jit(lambda p, r: pack_arena_pallas(p, offsets, total, wire, r))
-        compiled = pack.lower(parts, res).compile()
-    else:
-        pack = jax.jit(lambda p: pack_arena_pallas(p, offsets, total, wire)[0])
-        compiled = pack.lower(parts).compile()
+    pack = jax.jit(lambda p: pack_arena_pallas(p, offsets, total, wire))
+    compiled = pack.lower(parts).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
     unpack = jax.jit(lambda a, s: unpack_arena_pallas(
@@ -131,7 +117,7 @@ def test_kernel_payload_independent_of_call_site(one_chip, tmp_path, monkeypatch
     parts = [jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip) for n in (8192, 4096)]
 
     def lower():
-        return jax.jit(lambda p: pack_arena_pallas(p, [0, 8192], 12288, jnp.bfloat16)[0]
+        return jax.jit(lambda p: pack_arena_pallas(p, [0, 8192], 12288, jnp.bfloat16)
                        ).lower(parts).as_text()
 
     was = jax.config.jax_traceback_in_locations_limit
@@ -164,7 +150,7 @@ def test_dp_step_keeps_one_allreduce_per_group_on_four_chips(four_chips, monkeyp
     cfg = get_reduced("tinyllama-1.1b")
     eng = trainer.MGWFBPEngine.build(
         cfg, param_specs(cfg), dp_axes=("data",), ar_model=AllReduceModel(a=5e-5, b=1e-9),
-        tokens_per_device=1024, method="wfbp", sync_config=SyncConfig(fuse="arena"))
+        tokens_per_device=1024, method="wfbp", sync_config=SyncConfig())
     opt = make_optimizer("sgd", momentum=0.9)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     rep = NamedSharding(mesh, P())

@@ -40,7 +40,7 @@ SCRIPT = textwrap.dedent("""
         cfg, param_specs(cfg), dp_axes=("data",),
         ar_model=AllReduceModel(a=5e-5, b=1e-9),
         tokens_per_device=1024, method="wfbp",
-        sync_config=SyncConfig(fuse="arena"),
+        sync_config=SyncConfig(),
     )
     opt = make_optimizer("sgd", momentum=0.9)
 

@@ -329,10 +329,7 @@ SYNC_LOWERING_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_mesh
-    from repro.core import (
-        AllReduceModel, SyncConfig, count_expected_allreduces,
-        make_gradient_sync, stacked_lm_layout,
-    )
+    from repro.core import AllReduceModel, make_gradient_sync, stacked_lm_layout
     from repro.planning import build_schedule
 
     n_stages = 4
@@ -362,45 +359,42 @@ SYNC_LOWERING_SCRIPT = textwrap.dedent("""
     for policy, ar in CASES:
         opts = {"bucket_bytes": 3000} if policy == "fixed" else {}
         sched = build_schedule(policy, costs, ar, **opts)
-        rec = {"policy": policy, "n_groups": len(sched.groups)}
-        for fuse in ("concat", "variadic"):
-            cfgs = SyncConfig(fuse=fuse)
-            sync = make_gradient_sync(layout, sched, ("data",), cfgs)
+        sync = make_gradient_sync(layout, sched, ("data",))
 
-            def body(g):
-                # distinct per-device values: rank r contributes (r+1)*g,
-                # so the averaged result must equal 4.5*g exactly.
-                r = jax.lax.axis_index("data").astype(jnp.float32)
-                scaled = jax.tree.map(lambda x: x * (r + 1.0), g)
-                return sync(scaled)
+        def body(g):
+            # distinct per-device values: rank r contributes (r+1)*g,
+            # so the averaged result must equal 4.5*g exactly.
+            r = jax.lax.axis_index("data").astype(jnp.float32)
+            scaled = jax.tree.map(lambda x: x * (r + 1.0), g)
+            return sync(scaled)
 
-            f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          axis_names={"data"}, check_vma=False)
-            lowered = jax.jit(f).lower(grads)
-            n_ar = len(re.findall(r"stablehlo\\.all_reduce", lowered.as_text()))
-            got = jax.jit(f)(grads)
-            expect = jax.tree.map(lambda x: 4.5 * x, grads)
-            diff = max(
-                jax.tree.leaves(
-                    jax.tree.map(
-                        lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect
-                    )
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                      axis_names={"data"}, check_vma=False)
+        text = jax.jit(f).lower(grads).as_text()
+        got = jax.jit(f)(grads)
+        expect = jax.tree.map(lambda x: 4.5 * x, grads)
+        diff = max(
+            jax.tree.leaves(
+                jax.tree.map(
+                    lambda a, b: float(jnp.max(jnp.abs(a - b))), got, expect
                 )
             )
-            rec[fuse] = {
-                "hlo_allreduces": n_ar,
-                "expected": count_expected_allreduces(sched, cfgs, layout),
-                "max_diff": diff,
-            }
-        out.append(rec)
+        )
+        out.append({
+            "policy": policy,
+            "n_groups": len(sched.groups),
+            "hlo_allreduces": len(re.findall(r"stablehlo\\.all_reduce", text)),
+            "hlo_concats": len(re.findall(r"stablehlo\\.concatenate", text)),
+            "max_diff": diff,
+        })
     print(json.dumps(out))
 """)
 
 
 def test_sync_lowering_allreduce_counts():
-    """Satellite: the unified sync under shard_map lowers to exactly
-    len(schedule.groups) all-reduce ops per policy (concat wire layout),
-    and count_expected_allreduces is exact for both wire layouts."""
+    """Satellite: the sync under shard_map lowers to exactly
+    len(schedule.groups) all-reduce ops per policy — the merged message
+    of Definition 1 — with zero concatenates, and averages exactly."""
     out = subprocess.run(
         [sys.executable, "-c", SYNC_LOWERING_SCRIPT],
         capture_output=True, text=True, timeout=600,
@@ -417,11 +411,6 @@ def test_sync_lowering_allreduce_counts():
     assert by["single"]["n_groups"] == 1
     assert 1 < by["fixed"]["n_groups"] < 6  # genuinely intermediate
     for r in recs:
-        # concat: the merged message of Definition 1 — exactly one
-        # all-reduce HLO op per schedule group.
-        assert r["concat"]["hlo_allreduces"] == r["n_groups"], r
-        assert r["concat"]["expected"] == r["n_groups"], r
-        # variadic: one op per wire leaf on this jax; the counter knows.
-        assert r["variadic"]["hlo_allreduces"] == r["variadic"]["expected"], r
-        for fuse in ("concat", "variadic"):
-            assert r[fuse]["max_diff"] < 1e-4, r
+        assert r["hlo_allreduces"] == r["n_groups"], r
+        assert r["hlo_concats"] == 0, r
+        assert r["max_diff"] < 1e-4, r

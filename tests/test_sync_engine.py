@@ -1,4 +1,4 @@
-"""MG-WFBP sync engine: schedule groups -> exactly that many variadic
+"""MG-WFBP sync engine: schedule groups -> exactly that many merged (arena)
 all-reduces in the compiled HLO, with numerics identical to unbucketed DP.
 
 Multi-device cases run in a subprocess so the main pytest process keeps a

@@ -2,13 +2,13 @@
 
 ``tests/data/overlap_trace.json`` is a committed Chrome trace recorded by
 a real ``--dryrun 2 --issue-order dag`` launcher run (tinyllama-1.1b
-reduced, wfbp policy, fuse=arena, 8 virtual devices).  The suite pins:
+reduced, wfbp policy, arena wire, 8 virtual devices).  The suite pins:
 
   * span parsing (dict / JSON string / path / gzip round-trips);
   * ``wfbp_group{gi}_l{lo}_{hi}`` attribution: group indices, layer
     ranges, per-device counts;
   * per-group wire bytes in the trace == ``sync.group_wire_bytes`` of
-    the same (arch, policy, fuse) rebuilt from the planning stack — the
+    the same (arch, policy) rebuilt from the planning stack — the
     trace's payload accounting must stay tied to the arena layout;
   * the overlap-report arithmetic, to the float (the fixture is static,
     so the report is a pure function with golden outputs).
@@ -95,7 +95,7 @@ class TestWireBytes:
         costs = layout.layer_costs(8 * 64 // 8, None)
         sched = build_schedule("wfbp", costs, AllReduceModel(a=5e-5, b=1e-9))
         sync = make_gradient_sync(
-            layout, sched, ("data",), SyncConfig(fuse="arena")
+            layout, sched, ("data",), SyncConfig()
         )
         assert list(sync.group_wire_bytes) == GROUP_BYTES
         for s in spans:
