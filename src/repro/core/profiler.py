@@ -577,7 +577,7 @@ def is_attention(op_name: str) -> bool:
 
 def is_flash(op_name: str) -> bool:
     """An op of one of the flash attention kernels (``flash_fwd``,
-    ``flash_dq``, ``flash_dkv``: the ``name=`` of their ``pallas_call``)."""
+    ``flash_bwd``: the ``name=`` of their ``pallas_call``)."""
     return any(_core(c) in scopes.FLASH_KERNELS for c in op_name.split("/")) if op_name else False
 
 

@@ -2,9 +2,10 @@
 
 ``flash_attention_train`` is a custom VJP whose forward saves only the
 kernels' operands, (o, lse) — no S×S residuals in HBM — and whose
-backward runs the Pallas dQ and dKV kernels (kernel_bwd.py).  Both rules
-work in the kernels' (B·H, S, hd) layout and run the kernels once per
-device where the mesh leaves axes to the compiler (``per_device``).
+backward runs the one Pallas kernel that computes dQ, dK and dV
+(kernel_bwd.py).  Both rules work in the kernels' (B·H, S, hd) layout
+and run the kernels once per device where the mesh leaves axes to the
+compiler (``per_device``).
 """
 
 from __future__ import annotations

@@ -41,12 +41,11 @@ OPTIMIZER = "optimizer"
 #: (not the Q/K/V/O projections): what a flash kernel replaces.  Training
 #: and serving alike.
 ATTENTION = "attention"
-#: ``name=`` of the flash attention kernels' three ``pallas_call``s
-#: (forward; backward dQ and dK/dV), under ``attention``.
+#: ``name=`` of the flash attention kernels' two ``pallas_call``s
+#: (forward; backward, dQ, dK and dV in one), under ``attention``.
 FLASH_FWD = "flash_fwd"
-FLASH_DQ = "flash_dq"
-FLASH_DKV = "flash_dkv"
-FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+FLASH_BWD = "flash_bwd"
+FLASH_KERNELS = (FLASH_FWD, FLASH_BWD)
 #: The held-expert MoE layer of ``models/moe`` and its four parts: routing
 #: over all experts, sorting the (token, choice) rows into the held
 #: experts' groups, the experts' grouped matmuls, and the gated scatter

@@ -1,6 +1,8 @@
 """Pallas kernel validation in interpret mode: shape/dtype sweeps and
 hypothesis property tests against the pure-jnp oracles."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -181,7 +183,7 @@ def test_rglru_associative_scan_fallback_matches_ref(seed):
 
 
 # ---------------------------------------------------------------------------
-# flash attention backward (dQ/dK/dV Pallas kernels)
+# flash attention backward (the one Pallas kernel for dQ, dK and dV)
 # ---------------------------------------------------------------------------
 
 
@@ -193,6 +195,9 @@ def test_rglru_associative_scan_fallback_matches_ref(seed):
         (1, 256, 2, 1, 64, True, 128, None),
         (1, 256, 2, 2, 64, True, None, 50.0),
         (1, 384, 6, 2, 128, True, 256, 30.0),
+        # G = 8 over 4 x 8 blocks of 512 x 256 (256-wide heads): skipped
+        # tiles, diagonal tiles and the sum over 8 query heads
+        (1, 2048, 8, 1, 256, True, None, None),
     ],
 )
 def test_flash_attention_bwd_matches_ref_grads(B, S, Hq, Hkv, hd, causal, window, softcap):
@@ -218,6 +223,28 @@ def test_flash_attention_bwd_matches_ref_grads(B, S, Hq, Hkv, hd, causal, window
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=name
         )
+
+
+@pytest.mark.parametrize(
+    "S,hd,fits",
+    [(4096, 128, True), (8192, 128, True), (26624, 128, True), (27648, 128, False),
+     (15360, 256, True), (16384, 256, False)],
+)
+def test_flash_attention_bwd_takes_a_length_its_vmem_holds(S, hd, fits):
+    """The backward kernel keeps a head's dQ, dK and dV in VMEM: a length
+    whose accumulators exceed the budget is refused by its shape, before
+    anything is built (the shapes alone, through ``eval_shape``)."""
+    from repro.kernels.flash_attention.kernel_bwd import VMEM_BUDGET, bwd_heads, vmem_bytes
+
+    assert (vmem_bytes(S, S, hd) <= VMEM_BUDGET) == fits
+    x = jax.ShapeDtypeStruct((2, S, hd), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, S, 128), jnp.float32)
+    bwd = functools.partial(bwd_heads, causal=True, window=None, softcap=None)
+    if fits:
+        assert [g.shape for g in jax.eval_shape(bwd, x, x, x, x, lse, x)] == [(2, S, hd)] * 3
+    else:
+        with pytest.raises(ValueError, match="VMEM"):
+            jax.eval_shape(bwd, x, x, x, x, lse, x)
 
 
 def test_flash_attention_fwd_lse():
@@ -287,6 +314,7 @@ def test_flash_attention_fwd_bf16(S, Hq, Hkv, hd, causal, window, bq, bk):
         (1536, 4, 2, 64, True, 256, None),  # and the window skips tile (2, 0)
         (1024, 6, 2, 128, True, 700, 30.0),  # G = 3, window edge and softcap
         (1024, 2, 1, 64, False, None, None),  # nothing skipped, GQA sum
+        (2048, 8, 1, 256, True, None, None),  # G = 8, 4 x 8 blocks of 512 x 256
     ],
 )
 def test_flash_attention_train_bf16_grads(S, Hq, Hkv, hd, causal, window, softcap):
